@@ -23,8 +23,6 @@ is XLA-scheduled and unobservable from the host).
 CPU smoke (tests/test_overlap.py): tiny config, dp2 x pp2 on the forced
 8-device mesh, validates the row's accounting fields and the bitwise
 gates; absolute times and the >= 0.9x scaling gate are TPU-only claims.
-TP inside the pipeline needs partial-auto shard_map (jax >= 0.5) — on
-older jax the row demotes mp into dp and records the demotion.
 """
 from __future__ import annotations
 
@@ -59,13 +57,6 @@ def _measure_gpt_3d(cfg, dp=2, pp=2, mp=1, batch_per_dp=2, seq=64,
     if ndev < need:
         raise RuntimeError(f"gpt_3d wants {need} devices, have {ndev}")
     tp_axis = "mp" if mp > 1 else None
-    demoted = False
-    from paddle_tpu.core.meshutil import legacy_manual_vjp
-    if tp_axis and legacy_manual_vjp():
-        # partial-auto shard_map (TP under GSPMD inside the manual
-        # pipeline) does not exist before jax 0.5 — fold mp into dp so
-        # the row still measures the full device set
-        dp, mp, tp_axis, demoted = dp * mp, 1, None, True
     hcg = HybridCommunicateGroup(dp_degree=dp, pp_degree=pp,
                                  mp_degree=mp)
     mesh = hcg.process_mesh()
@@ -229,7 +220,6 @@ def _measure_gpt_3d(cfg, dp=2, pp=2, mp=1, batch_per_dp=2, seq=64,
         "value": round(tok_s, 1),
         "unit": "tokens/sec",
         "topology": {"dp": dp, "pp": pp, "mp": mp,
-                     "tp_demoted_to_dp": demoted,
                      "num_microbatches": num_microbatches},
         "chips": chips,
         "batch": batch, "seq_len": seq,

@@ -260,7 +260,7 @@ class _IrCtx:
 def _eqn_site(eqn):
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             return frame.file_name, frame.start_line
     except Exception:

@@ -49,7 +49,7 @@ def _aval_str(aval) -> str:
 import jax
 import jax.numpy as jnp
 
-with jax.experimental.enable_x64():
+with jax.enable_x64(True):
     JAXPR = jax.make_jaxpr(
         lambda x: x.astype(jnp.float64) * 2.0)(jnp.ones((4,), jnp.float32))
 """,
@@ -57,7 +57,7 @@ with jax.experimental.enable_x64():
 import jax
 import jax.numpy as jnp
 
-with jax.experimental.enable_x64():
+with jax.enable_x64(True):
     JAXPR = jax.make_jaxpr(lambda x: x * 2.0)(jnp.ones((4,), jnp.float32))
 """)
 def check_f64_promotion(closed, ctx):

@@ -2,82 +2,25 @@
 (ring attention, pipeline, MoE, DP grad sync)."""
 from __future__ import annotations
 
+import jax
 from jax import lax
 
 
 def pvary(xs, axes):
     """Mark values as varying over the given manual mesh axes (shard_map's
-    vma type system; the API name differs across jax versions — and the
-    type system does not exist at all before jax 0.5, where this is a
-    no-op)."""
+    vma type system)."""
     axes = tuple(axes)
     if not axes:
         return xs
-    if hasattr(lax, "pcast"):
-        return lax.pcast(xs, axes, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(xs, axes)
-    return xs  # jax < 0.5: no varying-manual-axes type system
+    return lax.pcast(xs, axes, to="varying")
 
 
-def axis_size(axis):
-    """``lax.axis_size`` across jax versions (pre-0.5 lacks it; the size
-    of a manual mesh axis is the psum of 1 over it — a compile-time
-    constant, not a runtime collective)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
-def partial_auto_supported() -> bool:
-    """True when ``shard_map`` can leave some mesh axes to GSPMD
-    (``axis_names`` a strict subset).  The legacy experimental
-    shard_map (jax < 0.5) cannot: its eager impl raises
-    ``NotImplementedError`` outright when ``auto`` is non-empty, and
-    even under jit the old SPMD partitioner hard-crashes on
-    ``ppermute``/``all_gather`` inside a partial-auto region (a
-    ``PartitionId``/manual-subgroup CHECK failure) — so callers that
-    mix manual collectives with a GSPMD-owned TP axis must demote on
-    the legacy path instead of splitting the program."""
-    import jax
-    return hasattr(jax, "shard_map")
-
-
-def legacy_manual_vjp() -> bool:
-    """True on the legacy experimental shard_map (jax < 0.5): its AD has
-    no varying-axes (vma) type system, so a ``jax.vjp`` taken INSIDE the
-    body produces purely LOCAL cotangents — callers must psum cotangents
-    of replicated inputs over the axes they are invariant on themselves
-    (the modern path inserts those psums automatically when the seed is
-    ``pvary``-marked)."""
-    import jax
-    return not hasattr(jax, "shard_map")
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.5 spells it ``jax.shard_map(f, mesh=..., in_specs=...,
-    out_specs=..., axis_names=...)``; before that it lives at
-    ``jax.experimental.shard_map.shard_map`` with ``auto=`` (the
-    COMPLEMENT of ``axis_names`` — axes left to GSPMD) instead of
-    ``axis_names`` and a ``check_rep`` flag whose replication checker
-    predates the vma type system and rejects valid psum/where patterns
-    the modern checker accepts — so it is disabled on the legacy path.
-    """
-    import jax
-    smap = getattr(jax, "shard_map", None)
-    if smap is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw)
-    from jax.experimental.shard_map import shard_map as legacy
+def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
+              check_vma=True):
+    """``jax.shard_map`` with ``axis_names`` given as any iterable (the
+    manual axes; the rest are left to GSPMD)."""
     kw = {}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return legacy(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False, **kw)
+        kw["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)

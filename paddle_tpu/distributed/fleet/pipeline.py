@@ -208,26 +208,6 @@ class PipelinedBlocks(Layer):
                 "replicated")
         if tp_rules and tp_axis is None:
             raise ValueError("tp_rules given without tp_axis")
-        if tp_axis is not None:
-            from ...core.meshutil import partial_auto_supported
-            if not partial_auto_supported():
-                # jax < 0.5: shard_map cannot leave the TP axis to
-                # GSPMD (partial-auto is NotImplemented eagerly and the
-                # old partitioner crashes on ppermute inside it) —
-                # demote to replicated compute over tp_axis: leaves
-                # stay pp-sharded only, the axis joins the manual set
-                # as one more replicated dim (like dp with no batch
-                # shard), and every value is mathematically identical,
-                # just computed redundantly per tp shard.  The modern
-                # path keeps real Megatron TP.
-                import warnings
-                warnings.warn(
-                    f"PipelinedBlocks.shard: tp_axis={tp_axis!r} "
-                    "demoted to replicated compute — this jax's legacy "
-                    "shard_map cannot run a partial-auto (GSPMD TP) "
-                    "region; upgrade to jax >= 0.5 for in-pipeline "
-                    "tensor parallelism", RuntimeWarning, stacklevel=2)
-                tp_axis, tp_rules = None, None
         self._tp_axis = tp_axis
         dim = mesh.dim_names.index(pp_axis)
         for n in self._names:
@@ -639,20 +619,6 @@ class PipelinedBlocks(Layer):
                     carry, _ = lax.scan(tick, carry0,
                                         jnp.arange(M + 2 * pp - 1))
                     _, _, _, dacc, dpacc, loss_acc, dx = carry
-                    from ...core.meshutil import legacy_manual_vjp
-                    if legacy_manual_vjp():
-                        # jax<0.5 shard_map: the in-body vjp cannot
-                        # auto-psum cotangents of replicated inputs —
-                        # fold the cross-dp leaf contributions and the
-                        # cross-stage (+dp) post contributions here
-                        # (mid stages contribute exact zeros to dpacc,
-                        # so the pp psum is the identity fold)
-                        if batch_tuple:
-                            dacc = tuple(lax.psum(da, batch_tuple)
-                                         for da in dacc)
-                        dpacc = tuple(
-                            lax.psum(dp_, (ax,) + batch_tuple)
-                            for dp_ in dpacc)
                     # loss lives on the last stage; grads of x on stage 0
                     loss_out = lax.psum(
                         jnp.where(is_last, loss_acc, 0.0), ax)

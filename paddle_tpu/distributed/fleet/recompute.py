@@ -47,6 +47,16 @@ class _SubstituteTracker:
             return self.outer.on_read(t)
         return t._data
 
+    def substituted(self, t):
+        """True when this tracker (or an enclosing one) holds a value
+        of its own for ``t`` — a fused-optimizer member view must then
+        read through the tracker, not slice its flat storage
+        (optimizer/flat.py ``member_read``)."""
+        if id(t) in self.map or id(t) in self.writes:
+            return True
+        outer = getattr(self.outer, "substituted", None)
+        return outer is not None and outer(t)
+
     def on_write(self, t, val):
         # swallowed: values born inside jax.checkpoint must not escape the
         # trace through framework state (they would be leaked tracers)

@@ -510,7 +510,8 @@ class Model:
         # stall watchdog (ISSUE 14): with the watchdog_stall_ms flag
         # set, this fit is armed and each completed step heartbeats it
         # at the SAME sites the StepTimer records — a training loop
-        # wedged past the deadline (hung collective, dead tunnel)
+        # wedged past the deadline (hung collective, a device that
+        # stopped answering)
         # gets thread stacks + a flight record instead of silence.
         # Size the deadline to cover eval/checkpoint gaps and (for
         # fit(window=K)) one whole scanned window.  No interrupt: a

@@ -109,8 +109,8 @@ default off):
   ``models.generation.ragged_paged_step`` / ``paged_slot_attention``
   (each token's bytes a pure function of its own K/V vector — page
   content is write-path-independent), reads dequantize inside the
-  ragged kernel's DMA loop.  KV bytes per resident sequence drop to
-  ``(D + 4) / 4D`` of fp32 (< 0.5 for every real head dim;
+  ragged kernel, on the fetched pages.  KV bytes per resident sequence
+  drop to ``(D + 4) / 4D`` of fp32 (< 0.5 for every real head dim;
   ``stats["kv_page_bytes"]``), which halves the HBM roofline term and
   doubles the sequences a fixed pool can hold.
 * Because the scale pools ride the SAME block tables and page ids, the
@@ -258,7 +258,7 @@ import numpy as np
 
 from ..core.errors import (CacheIntegrityError, EngineStallError,
                            MigrationError, PageBudgetError,
-                           QueueFullError)
+                           QueueFullError, UnimplementedError)
 from ..core.tensor import Tensor
 from ..observability import Registry as _ObsRegistry
 from ..observability import flight as _flight
@@ -582,6 +582,15 @@ class ContinuousBatchingEngine:
                     f"{_state.MEGAKERNEL_ON_SPELLINGS} or "
                     f"{_state.MEGAKERNEL_OFF_SPELLINGS}")
         self.megakernel = bool(mk)
+        from ..ops import pallas as _pallas
+        if not _pallas.use_interpret():
+            for name in ("kv_quant", "megakernel"):
+                if getattr(self, name):
+                    raise UnimplementedError(
+                        f"ContinuousBatchingEngine({name}=True) on a "
+                        f"TPU: its kernel does not compile there — "
+                        f"{_pallas.TPU_REFUSED[name]} "
+                        f"[{UnimplementedError.error_code}]")
         n_kv = getattr(cfg, "num_kv_heads", cfg.num_heads)
         shape = (n_kv, self.total_pages, self.page_size, cfg.head_dim)
         # int8 KV (ISSUE 7): data pools go int8 and per-page scale

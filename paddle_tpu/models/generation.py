@@ -160,7 +160,7 @@ def paged_slot_attention(q, k_new, v_new, k_pages, v_pages, positions,
     ``k_scales``/``v_scales`` [Hk, P, page_size] switch on the int8 KV
     path: the new K/V quantize on write (``quantization.kv_quantize``,
     one absmax scale per head per token slot — path-independent bytes),
-    the kernel dequantizes in its DMA loop, and the updated scale pools
+    the kernel dequantizes the fetched pages, and the updated scale pools
     return alongside the data pools.
     """
     from ..ops.pallas.paged_attention import paged_decode_attention
@@ -208,7 +208,7 @@ def ragged_paged_step(q, k_new, v_new, k_pages, v_pages, tok_pos,
     chunks or token-by-token decode holds identical bytes and prefix-
     cache reuse stays exact), the scale vectors land in side-pools
     indexed by the same block tables, and the ragged kernel dequantizes
-    inside its DMA loop.  The updated scale pools return after the data
+    on the fetched pages.  The updated scale pools return after the data
     pools.
     """
     from ..ops.pallas.paged_attention import ragged_paged_attention
@@ -1234,8 +1234,8 @@ def _run_decode_windows(exe, out, t, remaining, decode_window,
 # along heads instead of along its interleaved flat output dim), KV
 # page pools sharded by kv-head, block tables / lengths / packing
 # vectors replicated.  The program body runs under a fully-MANUAL
-# ``core.meshutil.shard_map`` (partial-auto is broken on legacy jax and
-# the Pallas ragged kernel cannot be GSPMD-partitioned anyway) with
+# ``core.meshutil.shard_map`` (the Pallas ragged kernel cannot be
+# GSPMD-partitioned) with
 # exactly ONE ``psum`` at the attention output projection and one at
 # the MLP down-projection per layer — the textbook Megatron cut.
 #
@@ -2084,8 +2084,12 @@ def make_tp_window(model, tpp, jmesh, ppb, n_caches, K,
 
     in_specs = (rep,) * 8 + tuple(tpp.specs) + (cspec,) * n_caches
     out_specs = (rep,) * 6 + (cspec,) * n_caches
+    # The fused kernels' bodies run at their top level, and interpret
+    # mode binds a body primitive by primitive: check_vma (JAX 0.9)
+    # then refuses a body literal that meets a tp-varying operand.  The
+    # ragged kernel escapes it only because its body sits in pl.when.
     fn = shard_map(window, jmesh, in_specs=in_specs,
-                   out_specs=out_specs)
+                   out_specs=out_specs, check_vma=not use_mk)
     # donate the cache pools (the last n_caches positional args)
     donate = tuple(range(8 + n_p, 8 + n_p + n_caches))
     return _jax.jit(fn, donate_argnums=donate)

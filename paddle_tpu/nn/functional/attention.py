@@ -19,13 +19,7 @@ from ...core.dispatch import apply
 
 
 def _use_pallas(q):
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        from ...ops.pallas import flash_attention  # noqa: F401
-        return True
-    except ImportError:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _dropout_probs(probs, dropout, key):

@@ -40,8 +40,7 @@ def _ring_attention_local(q, k, v, axis, causal, scale, remat=True,
                           mesh_axes=()):
     """Runs INSIDE shard_map: q/k/v are the local blocks [B, S_loc, H, D]
     (kv heads may be fewer — GQA repeats them)."""
-    from ...core.meshutil import axis_size as _axis_size
-    n = _axis_size(axis)
+    n = lax.axis_size(axis)
     i = lax.axis_index(axis)
     s_loc = q.shape[1]
     hq, hk = q.shape[2], k.shape[2]

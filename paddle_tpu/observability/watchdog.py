@@ -3,7 +3,7 @@ capture (ISSUE 14 tentpole, part 2).
 
 A hung dispatch is the one failure the rest of the observability stack
 cannot see: no event fires, no metric moves, the caller just never
-returns — and on a network-attached TPU a wedged tunnel looks exactly
+returns — and a device that has stopped answering looks exactly
 like a long compile.  The watchdog turns silence into evidence:
 
 * Callers :func:`arm` an operation with a deadline (engine dispatches,

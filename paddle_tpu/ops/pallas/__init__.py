@@ -22,6 +22,36 @@ def use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """``out_shape`` entry of a ``pallas_call`` that a ``jax.shard_map``
+    body may reach: under ``check_vma`` the output has to name the mesh
+    axes it varies over — those its operands vary over (none outside a
+    shard_map)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+#: Serving options whose kernels the TPU's compiler refuses today, with
+#: its words.  ``tests/test_chip_compile.py`` holds every refusal as a
+#: strict xfail: repairing a kernel flips its case, and its entry here
+#: goes in the same change.  The engine raises ``UnimplementedError``
+#: for a listed option on a TPU instead of failing inside a compile.
+TPU_REFUSED = {
+    "kv_quant": (
+        "the int8 KV pools' scale side-pools [Hk, P, page_size] are "
+        "windowed one page at a time, and the Pallas TPU lowering "
+        "requires the last two dimensions of a block to be divisible "
+        "by 8 and 128 or equal to the array's: block (1, page_size) "
+        "is neither"),
+    "megakernel": (
+        "fused_decode_qkv: Mosaic infer-vector-layout: unsupported "
+        "shape cast (8,768)->(8,12,64); fused_decode_epilogue: the "
+        "whole [50304,768] head is one VMEM window, 154 MB against "
+        "128 MiB; fused_decode_mlp with f32 weights: 20.41M of "
+        "scoped VMEM against a 16.00M limit"),
+}
+
+
 from . import flash_attention  # noqa: E402
 from . import fused_decode_mlp  # noqa: E402
 from . import fused_decode_qkv  # noqa: E402
@@ -32,4 +62,4 @@ from . import rope  # noqa: E402
 
 __all__ = ["flash_attention", "fused_decode_mlp", "fused_decode_qkv",
            "fused_optimizer", "fused_residual_norm", "norms", "rope",
-           "use_interpret"]
+           "out_struct", "use_interpret", "TPU_REFUSED"]

@@ -32,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fused_decode_qkv import _norm_block, _row_candidates, \
+from . import out_struct
+
+from .fused_decode_qkv import _mm, _norm_block, _row_candidates, \
     default_rows
 
 
@@ -40,13 +42,13 @@ def _mlp_tail(h, w1, b1, w2, b2, wu, arch):
     """fc1 -> activation -> fc2 (GPT) or gate/up -> SwiGLU -> down
     (LLaMA), matching GPTMLP/LlamaMLP op order."""
     if arch == "gpt":
-        f = jnp.matmul(h, w1)
+        f = _mm(h, w1)
         if b1 is not None:
             f = f + b1
         f = jax.nn.gelu(f, approximate=True)
     else:
-        f = jax.nn.silu(jnp.matmul(h, w1)) * jnp.matmul(h, wu)
-    f2 = jnp.matmul(f, w2)
+        f = jax.nn.silu(_mm(h, w1)) * _mm(h, wu)
+    f2 = _mm(f, w2)
     if b2 is not None:
         f2 = f2 + b2
     return f2
@@ -57,7 +59,7 @@ def _mlp_block(xv, av, wo, bo, nw, nb, w1, b1, w2, b2, wu, *, arch,
     """One row-block of the fused egress math.  Residual operand order
     matches the decode bodies (``x = x + proj(att)`` then
     ``x = x + mlp(norm(x))``)."""
-    prj = jnp.matmul(av, wo)
+    prj = _mm(av, wo)
     if bo is not None:
         prj = prj + bo
     y1 = xv + prj
@@ -79,9 +81,9 @@ def _epilogue_block(xv, nw, nb, wlm, blm, poisonv, *, norm, eps,
     nxt [rows] i32, bad [rows] bool)."""
     h = _norm_block(xv, nw, nb, norm, eps)
     if transpose_lm:
-        lg0 = jnp.matmul(h, jnp.swapaxes(wlm, -1, -2))
+        lg0 = _mm(h, jnp.swapaxes(wlm, -1, -2))
     else:
-        lg0 = jnp.matmul(h, wlm)
+        lg0 = _mm(h, wlm)
         if blm is not None:
             lg0 = lg0 + blm
     lg = lg0.astype(jnp.float32) + poisonv
@@ -145,7 +147,7 @@ def _blocked_call(block_fn, row_args, full_args, n_valid, rows,
         shp = (bp,) + o.shape[1:]
         if len(shp) == 1:
             shp = (bp, 1)
-        out_shape.append(jax.ShapeDtypeStruct(shp, dt))
+        out_shape.append(out_struct(shp, dt, *row_p, *present))
         out_specs.append(blk(shp))
 
     outs = pl.pallas_call(

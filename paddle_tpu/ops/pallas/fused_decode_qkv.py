@@ -41,6 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import out_struct
+
 
 def _rotate_half(x, cos, sin):
     """models.llama rope application (generation._apply_rope body)."""
@@ -70,6 +72,14 @@ def _norm_block(xv, nw, nb, norm, eps):
     return out
 
 
+def _mm(a, b):
+    """``a @ b`` accumulated in f32 and returned in ``a``'s dtype: Mosaic
+    takes no matmul whose accumulator is narrower than 32 bits (the
+    same bytes as ``jnp.matmul`` for f32 operands)."""
+    return jnp.matmul(a, b, preferred_element_type=jnp.float32).astype(
+        a.dtype)
+
+
 def _qkv_block(xv, posv, nw, nb, ws, bs, *, norm, eps, n_heads,
                n_kv_heads, head_dim, rope_theta):
     """One row-block of the fused ingress math: norm -> QKV projection
@@ -81,7 +91,7 @@ def _qkv_block(xv, posv, nw, nb, ws, bs, *, norm, eps, n_heads,
     h = _norm_block(xv, nw, nb, norm, eps)
     nq, nk = n_heads * head_dim, n_kv_heads * head_dim
     if len(ws) == 1:
-        qkv = jnp.matmul(h, ws[0])
+        qkv = _mm(h, ws[0])
         if bs:
             qkv = qkv + bs[0]
         # row-major column slices == reshape([rows, 3, nh, hd]) unbind
@@ -89,9 +99,9 @@ def _qkv_block(xv, posv, nw, nb, ws, bs, *, norm, eps, n_heads,
         k = qkv[:, nq:nq + nk]
         v = qkv[:, nq + nk:]
     else:
-        q = jnp.matmul(h, ws[0])
-        k = jnp.matmul(h, ws[1])
-        v = jnp.matmul(h, ws[2])
+        q = _mm(h, ws[0])
+        k = _mm(h, ws[1])
+        v = _mm(h, ws[2])
         if bs:
             q = q + bs[0]
             k = k + bs[1]
@@ -231,7 +241,7 @@ def fused_decode_qkv(x, norm_w, norm_b, weights, biases, positions,
     one_spec = pl.BlockSpec((1, h), lambda i, *_: (0, 0))
     full = functools.partial(pl.BlockSpec,
                              index_map=lambda i, *_: (0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     args = [xp, posp[:, None], nw]
     in_specs = [row_spec, pl.BlockSpec((rows_c, 1), lambda i, *_: (i, 0)),
                 one_spec]
@@ -257,9 +267,8 @@ def fused_decode_qkv(x, norm_w, norm_b, weights, biases, positions,
     in_specs += [any_spec] * len(pools)
     n_in = 2 + len(args)
 
-    out_shape = [jax.ShapeDtypeStruct((bp, n_heads, head_dim),
-                                      q_abs.dtype)]
-    out_shape += [jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools]
+    out_shape = [out_struct((bp, n_heads, head_dim), q_abs.dtype, *args)]
+    out_shape += [out_struct(p.shape, p.dtype, *args) for p in pools]
     out_specs = [pl.BlockSpec((rows_c, n_heads, head_dim),
                               lambda i, *_: (i, 0, 0))]
     out_specs += [any_spec] * len(pools)

@@ -236,6 +236,7 @@ def _pallas_call(spec, br, interpret, lr, scale, w2, g2, mw2, m2, v2,
         out_shape=outs,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="fused_optimizer",
     )(scal, *ins)
 
 

@@ -14,11 +14,14 @@ Capability analog of the reference's paged/block KV serving kernels
   counts and flattens the real (sequence, q-block, kv-block) work items
   onto one grid axis — programs exist only for blocks inside each
   sequence's true length (plus a static-budget tail that exits
-  immediately).  The previous kernel's ``pl.when`` skipped *compute*
-  past the length but its BlockSpec still DMA'd every page slot of
-  every sequence; here the page fetches are issued inside the kernel
-  (``pltpu.make_async_copy`` from the HBM-resident pool), so a skipped
-  block moves no bytes at all;
+  immediately).  The pools stay in HBM: every page slot of a work
+  item is one ``(page_size, head_dim)`` BlockSpec window whose page id
+  comes from the scalar-prefetched plan, so the pipeline fetches only
+  the pages a live item names (the budget tail repeats the last live
+  item's pages, and a repeated window moves no bytes).  A whole-page
+  window spans the pool's full minor dims, which Mosaic accepts at any
+  head_dim — a hand-issued DMA of the same page is refused below 128
+  lanes ("slice shape ... must be aligned to tiling (128)");
 * each program walks ``pages_per_block`` pages, amortizing the
   sublane-padded q block across ``pages_per_block * page_size`` KV
   tokens per grid step (the one-page-per-program version re-fetched the
@@ -37,8 +40,8 @@ Capability analog of the reference's paged/block KV serving kernels
 
 * INT8 KV pages (ISSUE 7): when the pools are int8, per-page scale
   side-pools [Hk, P, page_size] (``quantization.kv_quantize``) are
-  DMA'd alongside each data page and the dequant happens in VMEM right
-  after the copy completes — attention reads a QUARTER of the fp32 KV
+  windowed alongside each data page and the dequant happens in VMEM
+  on the fetched block — attention reads a QUARTER of the fp32 KV
   bytes per step, which is the serving roofline term
   (benchmarks/serving_bench.py), and no float page ever exists in HBM.
 
@@ -57,6 +60,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import out_struct
 
 NEG_INF = -1e30
 _LANE = 128    # lane width for per-row stats kept in VMEM scratch
@@ -81,8 +86,8 @@ def _row_pad(q_block, rep):
 # work-item planning (grid compaction)
 # --------------------------------------------------------------------------
 
-def _plan_items(kv_lens, q_lens, *, q_block, blk_tokens, nqb_total,
-                item_budget):
+def _plan_items(kv_lens, q_lens, block_tables, *, q_block, page_size,
+                pages_per_block, num_pages, nqb_total, item_budget):
     """Flatten the ragged (sequence, q-block, kv-block) work triples onto
     one grid axis.  Pure jnp — runs on concrete arrays (eager call) and
     on tracers (inside a jitted serving step; the arrays ride the
@@ -97,7 +102,13 @@ def _plan_items(kv_lens, q_lens, *, q_block, blk_tokens, nqb_total,
       first[i]/last[i]— 1 on the first/last kv block of a q block
                         (accumulator init / output flush), 0 on the tail
       nitems          — [1] live item count
+      pid[i*ppb + p]  — pool page behind slot ``p`` of item ``i`` (the
+                        k/v window index maps read it).  Slots past
+                        the sequence's last page repeat that page (the
+                        kernel masks their tokens), and the budget tail
+                        repeats the last live item's pages.
     """
+    blk_tokens = pages_per_block * page_size
     kv_lens = kv_lens.astype(jnp.int32)
     q_lens = q_lens.astype(jnp.int32)
     nseq = q_lens.shape[0]
@@ -130,9 +141,18 @@ def _plan_items(kv_lens, q_lens, *, q_block, blk_tokens, nqb_total,
     qbg_i = jnp.where(live, qbg_i, last_qbg)
     first_i = (live & (kb_i == 0)).astype(jnp.int32)
     last_i = (live & (kb_i == nk_j[j_i] - 1)).astype(jnp.int32)
+    i_pg = jnp.where(live, i, jnp.maximum(nitems - 1, 0))
+    last_pg = jnp.maximum(_cdiv(kv_lens[seq_i[i_pg]], page_size) - 1, 0)
+    slot = jnp.minimum(
+        kb_i[i_pg][:, None] * pages_per_block
+        + jnp.arange(pages_per_block, dtype=jnp.int32)[None, :],
+        last_pg[:, None])
+    pid = block_tables.astype(jnp.int32)[seq_i[i_pg][:, None], slot]
+    pid = jnp.clip(pid, 0, num_pages - 1)   # a window must name a page
     return (seq_i, qb_j[j_i].astype(jnp.int32), kb_i.astype(jnp.int32),
             qbg_i.astype(jnp.int32), first_i, last_i,
-            jnp.reshape(nitems, (1,)).astype(jnp.int32))
+            jnp.reshape(nitems, (1,)).astype(jnp.int32),
+            pid.reshape(-1))
 
 
 def _count_items(kv_lens, q_lens, q_block, blk_tokens):
@@ -153,31 +173,31 @@ def _count_items(kv_lens, q_lens, q_block, blk_tokens):
 # --------------------------------------------------------------------------
 
 def _ragged_kernel(seq_ref, qb_ref, kb_ref, qbg_ref, first_ref, last_ref,
-                   nitems_ref, bt_ref, kvl_ref, ql_ref,
+                   nitems_ref, pid_ref, kvl_ref, ql_ref,
                    q_ref, *refs,
                    scale, page_size, pages_per_block, q_block, rep_p,
                    quant):
-    """One compacted work item: walk ``pages_per_block`` pages of one
+    """One compacted work item: ``pages_per_block`` pages of one
     sequence's kv block against one q block.  Scalars (prefetched):
-    item maps + block tables [B, NP] + kv/q lengths [B].  q/o blocks:
-    [1, 1, q_block*rep_p, D].  k/v pools stay in HBM; pages are DMA'd
-    into VMEM scratch only for live items.
+    item maps + per-slot page ids + kv/q lengths [B].  q/o blocks:
+    [1, 1, q_block*rep_p, D].  The k/v pools stay in HBM; ``refs``
+    opens with one [page_size, D] window per page slot of k, then of v
+    (``_ragged_call``'s index maps point each at its page).
 
     ``quant``: the pools are int8 and two per-page scale side-pools
-    [Hk, P, page_size] ride along — each page's scale vector is DMA'd
-    with its data page and the dequant (one VPU multiply per token row)
-    happens right here in VMEM, so quantized attention reads a QUARTER
-    of the fp32 KV bytes per step and never materializes a float page
-    in HBM (PAPERS.md #3's fuse-dequant-into-the-consumer argument
-    applied to the DMA loop)."""
+    [Hk, P, page_size] ride along — each page's scale vector is
+    windowed with its data page and the dequant (one VPU multiply per
+    token row) happens right here in VMEM, so quantized attention reads
+    a QUARTER of the fp32 KV bytes per step and never materializes a
+    float page in HBM (PAPERS.md #3's fuse-dequant-into-the-consumer
+    argument applied to the page fetch)."""
+    del qbg_ref, pid_ref                        # index maps only
+    n = pages_per_block
+    k_refs, v_refs, refs = refs[:n], refs[n:2 * n], refs[2 * n:]
     if quant:
-        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, m_s, l_s, acc_s,
-         kbuf, vbuf, ksbuf, vsbuf, ksem, vsem, kssem, vssem) = refs
-    else:
-        (k_hbm, v_hbm, o_ref, m_s, l_s, acc_s,
-         kbuf, vbuf, ksem, vsem) = refs
+        ks_refs, vs_refs, refs = refs[:n], refs[n:2 * n], refs[2 * n:]
+    o_ref, m_s, l_s, acc_s = refs
     i = pl.program_id(1)
-    ih = pl.program_id(0)
     live = i < nitems_ref[0]
     blk_tokens = pages_per_block * page_size
 
@@ -187,49 +207,25 @@ def _ragged_kernel(seq_ref, qb_ref, kb_ref, qbg_ref, first_ref, last_ref,
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
+    def _block(page_refs):
+        return jnp.concatenate([r[...] for r in page_refs], axis=0)
+
     @pl.when(live)
-    def _fetch_and_accumulate():
+    def _accumulate():
         b = seq_ref[i]
         kb = kb_ref[i]
         kv_len = kvl_ref[b]
-        npg = _cdiv(kv_len, page_size)          # pages this seq occupies
-        page0 = kb * pages_per_block
-
-        def _copies(p, pid):
-            cps = [pltpu.make_async_copy(k_hbm.at[ih, pid], kbuf.at[p],
-                                         ksem.at[p]),
-                   pltpu.make_async_copy(v_hbm.at[ih, pid], vbuf.at[p],
-                                         vsem.at[p])]
-            if quant:
-                cps.append(pltpu.make_async_copy(
-                    ks_hbm.at[ih, pid], ksbuf.at[p], kssem.at[p]))
-                cps.append(pltpu.make_async_copy(
-                    vs_hbm.at[ih, pid], vsbuf.at[p], vssem.at[p]))
-            return cps
-
-        for p in range(pages_per_block):        # static unroll
-            @pl.when(page0 + p < npg)
-            def _start(p=p):
-                pid = bt_ref[b, page0 + p]
-                for c in _copies(p, pid):
-                    c.start()
-        for p in range(pages_per_block):
-            @pl.when(page0 + p < npg)
-            def _wait(p=p):
-                pid = bt_ref[b, page0 + p]
-                for c in _copies(p, pid):
-                    c.wait()
 
         q = q_ref[0, 0].astype(jnp.float32) * scale      # [rows, D]
-        kblk = kbuf[...].reshape(blk_tokens, -1).astype(jnp.float32)
-        vblk = vbuf[...].reshape(blk_tokens, -1).astype(jnp.float32)
-        if quant:   # in-DMA-loop dequant: int8 row * its per-slot scale
-            kblk = kblk * ksbuf[...].reshape(blk_tokens, 1)
-            vblk = vblk * vsbuf[...].reshape(blk_tokens, 1)
-        # tokens past kv_len sit in pages never fetched this item —
-        # uninitialized VMEM. Zero them BEFORE the dots: the softmax
-        # mask alone is not enough (0-weight x NaN garbage = NaN in the
-        # p@v accumulation).
+        kblk = _block(k_refs).astype(jnp.float32)        # [blk_tokens, D]
+        vblk = _block(v_refs).astype(jnp.float32)
+        if quant:   # in-VMEM dequant: int8 row * its per-slot scale
+            kblk = kblk * _block(ks_refs).reshape(blk_tokens, 1)
+            vblk = vblk * _block(vs_refs).reshape(blk_tokens, 1)
+        # slots past the sequence's last page repeat that page, and the
+        # last page's tail was never written. Zero both BEFORE the
+        # dots: the softmax mask alone is not enough (0-weight x NaN
+        # garbage = NaN in the p@v accumulation).
         tok_valid = (kb * blk_tokens + jax.lax.broadcasted_iota(
             jnp.int32, (blk_tokens, 1), 0)) < kv_len
         kblk = jnp.where(tok_valid, kblk, 0.0)
@@ -239,7 +235,6 @@ def _ragged_kernel(seq_ref, qb_ref, kb_ref, qbg_ref, first_ref, last_ref,
         # causal/ragged mask: q row r is token qb*q_block + r // rep_p
         # of its sequence, sitting at absolute position kv_len - q_len
         # + that index; kv column c is absolute position kb*blk + c.
-        # (stale scratch rows from pages past npg mask out here too.)
         kv_pos = kb * blk_tokens + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // rep_p
@@ -265,7 +260,7 @@ def _ragged_kernel(seq_ref, qb_ref, kb_ref, qbg_ref, first_ref, last_ref,
         o_ref[0, 0] = (acc_s[...] / l).astype(o_ref.dtype)
 
 
-def _ragged_call(qx, k_pages, v_pages, bt, kv_lens, q_lens, plan,
+def _ragged_call(qx, k_pages, v_pages, kv_lens, q_lens, plan,
                  item_budget, *, scale, q_block, rep_p, pages_per_block,
                  interpret, k_scales=None, v_scales=None):
     """Shared pallas_call: ``qx`` is the blocked q layout
@@ -280,37 +275,33 @@ def _ragged_call(qx, k_pages, v_pages, bt, kv_lens, q_lens, plan,
         _ragged_kernel, scale=float(scale), page_size=page_size,
         pages_per_block=pages_per_block, q_block=q_block, rep_p=rep_p,
         quant=quant)
-    kv_dt = k_pages.dtype
 
-    def q_index(ih, i, seq, qb, kb, qbg, first, last, nitems, btm, kvl,
+    def q_index(ih, i, seq, qb, kb, qbg, first, last, nitems, pid, kvl,
                 ql):
         return (ih, qbg[i], 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, rows, d), q_index),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # k page pool
-        pl.BlockSpec(memory_space=pltpu.ANY),   # v page pool
-    ]
+    def page_specs(block, tail):
+        """One window per page slot: slot ``p`` of item ``i`` is page
+        ``pid[i * pages_per_block + p]`` of kv head ``ih``."""
+        def spec(p):
+            def index(ih, i, seq, qb, kb, qbg, first, last, nitems, pid,
+                      kvl, ql):
+                return (ih, pid[i * pages_per_block + p]) + tail
+            return pl.BlockSpec(block, index)
+        return [spec(p) for p in range(pages_per_block)]
+
+    page = page_specs((None, None, page_size, d), (0, 0))
+    in_specs = [pl.BlockSpec((1, 1, rows, d), q_index)] + page + page
+    pools = [k_pages] * pages_per_block + [v_pages] * pages_per_block
+    if quant:
+        in_specs += 2 * page_specs((None, 1, page_size), (0,))
+        pools += ([k_scales.astype(jnp.float32)] * pages_per_block
+                  + [v_scales.astype(jnp.float32)] * pages_per_block)
     scratch = [
         pltpu.VMEM((rows, _LANE), jnp.float32),
         pltpu.VMEM((rows, _LANE), jnp.float32),
         pltpu.VMEM((rows, d), jnp.float32),
-        pltpu.VMEM((pages_per_block, page_size, d), kv_dt),
-        pltpu.VMEM((pages_per_block, page_size, d), kv_dt),
     ]
-    extra = []
-    if quant:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),  # k scales
-                     pl.BlockSpec(memory_space=pltpu.ANY)]  # v scales
-        scratch += [pltpu.VMEM((pages_per_block, page_size), jnp.float32),
-                    pltpu.VMEM((pages_per_block, page_size), jnp.float32)]
-        extra = [k_scales.astype(jnp.float32),
-                 v_scales.astype(jnp.float32)]
-    scratch += [pltpu.SemaphoreType.DMA((pages_per_block,)),
-                pltpu.SemaphoreType.DMA((pages_per_block,))]
-    if quant:
-        scratch += [pltpu.SemaphoreType.DMA((pages_per_block,)),
-                    pltpu.SemaphoreType.DMA((pages_per_block,))]
 
     return pl.pallas_call(
         kernel,
@@ -321,10 +312,11 @@ def _ragged_call(qx, k_pages, v_pages, bt, kv_lens, q_lens, plan,
             out_specs=pl.BlockSpec((1, 1, rows, d), q_index),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct(qx.shape, qx.dtype),
+        out_shape=out_struct(qx.shape, qx.dtype, qx, *pools),
         interpret=interpret,
-    )(*plan, bt.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q_lens.astype(jnp.int32), qx, k_pages, v_pages, *extra)
+        name="ragged_paged_attention",
+    )(*plan, kv_lens.astype(jnp.int32), q_lens.astype(jnp.int32), qx,
+      *pools)
 
 
 # --------------------------------------------------------------------------
@@ -397,7 +389,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
         else:
             item_budget = nqb_total * _cdiv(npages, pages_per_block)
     plan = _plan_items(jnp.asarray(kv_lens), jnp.asarray(q_lens),
-                       q_block=q_block, blk_tokens=blk_tokens,
+                       jnp.asarray(block_tables), q_block=q_block,
+                       page_size=page_size,
+                       pages_per_block=pages_per_block,
+                       num_pages=k_pages.shape[1],
                        nqb_total=nqb_total, item_budget=item_budget)
 
     qg = q.reshape(tp, hk, rep, d)
@@ -406,8 +401,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
     qx = jnp.transpose(qg, (1, 0, 2, 3)).reshape(
         hk, nqb_total, q_block * rep_p, d)
 
-    out = _ragged_call(qx, k_pages, v_pages,
-                       jnp.asarray(block_tables), jnp.asarray(kv_lens),
+    out = _ragged_call(qx, k_pages, v_pages, jnp.asarray(kv_lens),
                        jnp.asarray(q_lens), plan, item_budget,
                        scale=scale, q_block=q_block, rep_p=rep_p,
                        pages_per_block=pages_per_block,

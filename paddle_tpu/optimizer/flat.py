@@ -174,6 +174,15 @@ class FlatStore:
                 self.sync()
             return tr.on_read(t) if tr is not None else t._data
         if tr is not None:
+            # a tracker that maps THIS member to a value of its own
+            # (recompute threads a block's params through
+            # jax.checkpoint as explicit inputs; a cond branch hoists
+            # its operands) must see the read: a slice of the storage
+            # would cut the member out of that trace, and its grad
+            # would be zero
+            substituted = getattr(tr, "substituted", None)
+            if substituted is not None and substituted(t):
+                return tr.on_read(t)
             if self.kind == "grad":
                 # under capture a grad view is a plain tensor: the trace
                 # must consume the member's own (possibly accumulated)

@@ -306,6 +306,18 @@ class Optimizer:
                 # (and fuse-or-not would depend on accumulator history)
                 continue
             by_dtype.setdefault(dt, []).append((p, v))
+        # A Mosaic kernel cannot sit in a program that XLA partitions
+        # over a mesh ("Mosaic kernels cannot be automatically
+        # partitioned"), and with any mesh-placed param the step is
+        # such a program: the buckets of its unplaced params then take
+        # the jnp update (what every bucket takes off the TPU).
+        def on_mesh(p):
+            sh = getattr(p._read(), "sharding", None)
+            return p._dist is not None or (
+                sh is not None and len(sh.device_set) > 1)
+
+        self._flat_impl = "jnp" if any(
+            on_mesh(p) for p, _g in pairs) else None
         # ---- validation pass (no mutation) ----
         betas = {}
         for dt, pv in by_dtype.items():
@@ -458,7 +470,13 @@ class Optimizer:
                 and all(st.owns(g, i) for i, g in enumerate(gts)):
             return
         vals = [g._read() for g in gts]
-        flat = grp.flatten(vals, vals[0].dtype)
+        # one bucket can hold grads of two dtypes: under AMP O2 an f32
+        # norm weight whose block ran under recompute gets a bf16 grad
+        # (the whole block is one op, cast at its boundary) while the
+        # final norm's stays f32 — widen to the common dtype (exact)
+        dt = jnp.result_type(*{v.dtype for v in vals})
+        flat = grp.flatten(
+            [v if v.dtype == dt else v.astype(dt) for v in vals], dt)
         if st is None:
             st = grp.grad_store = _flat.FlatStore(grp, "grad", flat)
         else:
@@ -547,7 +565,7 @@ class Optimizer:
             new_w, new_master, nm, nv, nb1, nb2 = fo.fused_update(
                 spec, w=grp.param_store.flat_value(),
                 g=grp.grad_store.storage._read(), lr=lr,
-                clip_scale=clip_scale, **kw)
+                clip_scale=clip_scale, impl=self._flat_impl, **kw)
             grp.param_store.set_flat(new_w)
             if new_master is not None:
                 grp.master_store.set_flat(new_master)

@@ -51,7 +51,8 @@ Serving fault sites (``resilience.faults`` spec grammar):
   prefill+decode is deterministic), only ``requeues`` moves. Key =
   the request id.
 * ``engine_stall`` — one engine dispatch HANGS (a bounded Python
-  spin standing in for a wedged device tunnel), drilling the stall
+  spin standing in for a device that stopped answering), drilling
+  the stall
   watchdog (``observability/watchdog.py``): past ``watchdog_ms`` the
   watchdog captures thread stacks, dumps the flight record + Chrome
   trace and injects ``EngineStallError`` (PDT-E020) into the spinning

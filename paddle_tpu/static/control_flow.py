@@ -84,6 +84,11 @@ class _BranchTracker:
         if self.base is not None:
             self.base.on_create(t)
 
+    def substituted(self, t):
+        """A fused-optimizer member view reads through this tracker
+        when it holds a value for it (optimizer/flat.py)."""
+        return id(t) in self.subs or id(t) in self.local_env
+
     def on_read(self, t):
         tid = id(t)
         if tid in self.subs:

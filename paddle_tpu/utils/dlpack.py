@@ -14,7 +14,7 @@ def to_dlpack(x: Tensor):
             return v.__dlpack__()
         return jax.dlpack.to_dlpack(v)
     except Exception:
-        # remote/tunnel device buffers can't be externally referenced:
+        # device buffers that can't be externally referenced:
         # export a host copy's capsule (zero-copy only host-side)
         return np.asarray(v).__dlpack__()
 

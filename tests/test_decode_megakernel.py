@@ -199,6 +199,16 @@ def test_engine_megakernel_slot_contention_bitwise(serving_gpt):
     scheduler behaved identically both ways."""
     prompts, new = _workload()
     refs = _paged_refs(serving_gpt, prompts, new)
+    # An engine's FIRST decode dispatch on a geometry is a single
+    # scalar step that compiles the step program; an engine that finds
+    # the program in the cache on the (session-shared) model goes
+    # straight to windows.  Whichever suite ran earlier in this worker
+    # may have left the unfused program there and not the fused one, so
+    # the two counts below differed by that one bootstrap dispatch
+    # under --dist loadfile while agreeing when this file ran alone.
+    # Put both programs in the cache before counting.
+    for mk in (False, True):
+        _run(serving_gpt, prompts[:1], new[:1], mk)
     off, e_off = _run(serving_gpt, prompts, new, False)
     on, e_on = _run(serving_gpt, prompts, new, True)
     for a, b, r in zip(off, on, refs):

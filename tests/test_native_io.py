@@ -87,3 +87,44 @@ def test_batch_normalize_transform():
     with pytest.raises(ValueError):
         BatchNormalize([0.0], [1.0])(src.astype("float32"))
 
+
+
+def test_native_build_decides_by_source_hash(tmp_path):
+    """The libraries are git-ignored, so a copy of the tree can carry a
+    stale one with any file time: a rebuild is decided by the hash of
+    the source kept beside the library, never by mtimes."""
+    import ctypes
+    import os
+    import shutil
+
+    from paddle_tpu.utils import native_build as nb
+    if shutil.which("g++") is None:
+        pytest.skip("no g++")
+    src, so = str(tmp_path / "v.cc"), str(tmp_path / "libv.so")
+
+    def build(value):
+        with open(src, "w") as f:
+            f.write(f'extern "C" int v() {{ return {value}; }}\n')
+        nb._cache.pop(so, None)
+        return nb.build_and_load(src, so)
+
+    assert build(1).v() == 1
+    # a source edit with an OLDER file time than the library still
+    # rebuilds (dlopen caches by path: check the stamp, then a fresh
+    # handle on a copy)
+    old = os.path.getmtime(so) - 1000
+    with open(src, "w") as f:
+        f.write('extern "C" int v() { return 2; }\n')
+    os.utime(src, (old, old))
+    nb._cache.pop(so, None)
+    assert nb.build_and_load(src, so) is not None
+    copy = str(tmp_path / "libv2.so")
+    shutil.copy(so, copy)
+    assert ctypes.CDLL(copy).v() == 2
+    # a library without its stamp (a stale binary carried along by a
+    # copy of the tree) is rebuilt as well
+    os.remove(so + ".sha256")
+    with open(so, "wb") as f:
+        f.write(b"not a library")
+    nb._cache.pop(so, None)
+    assert nb.build_and_load(src, so) is not None

@@ -311,14 +311,29 @@ def test_spec_engine_draft_nan_drill(gpt):
 
 def test_spec_engine_draft_mismatch_drill(gpt):
     """engine_draft_mismatch corrupts every proposal: verify rejects
-    all drafts (0-accept steps), outputs stay BITWISE — the acceptance
-    rule is correct for arbitrary garbage drafts."""
+    the drafts, outputs stay BITWISE — the acceptance rule is correct
+    for arbitrary garbage drafts.
+
+    The corruption is ``draft + 1 mod vocab``, made before the target
+    has spoken, so it can land ON the target's token: on this workload
+    the n-gram proposer offers 73 where the target picks 74, and the
+    corrupted 74 is accepted — rightly, it is the token plain decode
+    emits.  So the drill cannot promise zero accepts.  It promises what
+    the site is for: every token emitted is the target's, and the
+    accepts fall far below the clean run's (6 of 7 there, 1 of 12
+    here) — what is still accepted is a coincidence of that kind."""
     from paddle_tpu.resilience import faults
 
     prompts, new = _workload(0)
     refs = _paged_refs(gpt, prompts, new)
     faults.clear()
     try:
+        clean = _spec_engine(gpt)
+        for p, n in zip(prompts, new):
+            clean.add_request(p, n)
+        clean.run()
+        clean_st = clean.stats
+
         eng = _spec_engine(gpt)
         rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
         faults.inject("engine_draft_mismatch", times=0)  # every step
@@ -327,8 +342,8 @@ def test_spec_engine_draft_mismatch_drill(gpt):
             np.testing.assert_array_equal(done[rid].sequence, ref)
         st = eng.stats
         assert st["spec_proposed"] > 0
-        assert st["spec_accepted"] == 0       # forced 0-accept steps
-        assert st["spec_accept_rate"] == 0.0
+        assert st["spec_accepted"] < clean_st["spec_accepted"]
+        assert st["spec_accept_rate"] < clean_st["spec_accept_rate"] / 2
     finally:
         faults.clear()
 
@@ -358,8 +373,9 @@ def test_spec_rejection_sampling_deterministic(gpt):
 
 def test_spec_stats_appended_backward_compat(gpt):
     """The spec counters APPEND to stats: every pre-existing key keeps
-    its exact position (the PR5-PR8 contract), the three new keys come
-    last, and spec_accept_rate is the only non-int besides kv_quant."""
+    its exact position (the PR5-PR8 contract), the three spec keys
+    follow, then the migration counters PR 20 appended after them, and
+    spec_accept_rate is the only non-int besides kv_quant."""
     _OLD_KEYS = [
         "admitted", "retired", "steps", "mixed_steps",
         "decode_dispatches", "tokens_generated", "pages_allocated",
@@ -373,7 +389,8 @@ def test_spec_stats_appended_backward_compat(gpt):
     eng = _engine(gpt)
     st = eng.stats
     assert list(st) == _OLD_KEYS + ["spec_proposed", "spec_accepted",
-                                    "spec_accept_rate"]
+                                    "spec_accept_rate", "migrated_in",
+                                    "migrated_out"]
     assert st["spec_proposed"] == 0 and st["spec_accepted"] == 0
     assert st["spec_accept_rate"] == 0.0
     assert isinstance(st["spec_proposed"], int)
