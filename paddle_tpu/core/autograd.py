@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import scope as _scope
 from . import state
 
 
@@ -28,7 +29,7 @@ class Node:
     so grad-enabled forwards that never backward pay no jax.vjp cost."""
 
     __slots__ = ("name", "vjp_fn", "inputs", "out_ids", "out_avals",
-                 "consumed", "pure", "seq_type", "diff_vals")
+                 "consumed", "pure", "seq_type", "diff_vals", "scope")
 
     def __init__(self, name, vjp_fn, inputs, out_ids, out_avals, pure=None,
                  seq_type=None, diff_vals=None):
@@ -42,6 +43,9 @@ class Node:
         self.seq_type = seq_type    # None | tuple | list: primal output pytree
         self.diff_vals = diff_vals  # input values for lazy linearization
         self.consumed = False
+        # the phase-scope path live when the op was recorded (None
+        # outside a capture): backward re-enters it under "backward"
+        self.scope = _scope.current()
 
     def pack_cots(self, cots):
         if self.seq_type is None:
@@ -217,8 +221,7 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False, accumulate=True
             if node_wait[id(prod)] == 0:
                 queue.append(prod)
 
-    while queue:
-        n = queue.pop()
+    def process(n):
         out_grads = []
         for oid, aval in zip(n.out_ids, n.out_avals):
             g = grad_buf.get(oid)
@@ -252,10 +255,18 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False, accumulate=True
             if consumer_count[ti._uid] == 0:
                 finalize(ti._uid)
 
-    # Seed tensors with no reachable consumers are final too (leaf seeds).
-    for t in tensors:
-        if consumer_count.get(t._uid, 0) == 0:
-            finalize(t._uid)
+    # a backward op reads backward/<the path its forward was recorded
+    # under>, whether its linearisation is built here or was eager
+    with _scope.phase("backward"):
+        while queue:
+            n = queue.pop()
+            with _scope.phase(n.scope):
+                process(n)
+        # Seed tensors with no reachable consumers are final too (leaf
+        # seeds).
+        for t in tensors:
+            if consumer_count.get(t._uid, 0) == 0:
+                finalize(t._uid)
 
     if not retain_graph:
         for n in processed:

@@ -154,15 +154,22 @@ Observability (ISSUE 8; ``paddle_tpu.observability``):
   coded ``EngineStallError`` (PDT-E020) surfaces from ``step()``
   instead of a hang — drilled by the ``engine_stall`` fault site.
   Both are metrics-flag-gated no-ops when off.
-* DISTRIBUTED TRACING (ISSUE 12; ``observability/tracing.py``) — every
-  dispatch runs under a ``serving.dispatch`` span whose begin/end pair
-  lands in the event ring, and the timeline's ``serving.dispatch``
-  event carries the active ``trace_id``/``parent_id`` — a trace
-  propagated in over ``distributed/rpc`` (disaggregated
-  prefill/decode handoff) threads through to the dispatches that
-  served it.  ``observability.export_trace(path)`` renders the ring
-  (lifecycle events per slot, dispatch spans, faults) as a Perfetto
-  trace, one track per engine slot.
+* TRACING (ISSUE 12, ISSUE 26; ``observability/tracing.py``, the
+  program's one span API) — a step runs under an ``engine.step`` span
+  and its host time is split inside it: ``engine.retire``,
+  ``engine.sweep``, ``engine.admit``, ``engine.stage`` (packing the
+  step's host arrays and handing them to the device),
+  ``serving.dispatch`` (the program call) and ``engine.readback`` (the
+  tokens brought back).  While a profiler session is live each span is
+  a profiler annotation on the device trace's clock; under
+  ``PDTPU_METRICS`` its begin/end pair also lands in the event ring,
+  and the timeline's ``serving.dispatch`` event carries the active
+  ``trace_id``/``parent_id`` — a trace propagated in over
+  ``distributed/rpc`` (disaggregated prefill/decode handoff) threads
+  through to the dispatches that served it.
+  ``observability.export_trace(path)`` renders the ring (lifecycle
+  events per slot, spans, faults) as a Perfetto trace, one track per
+  engine slot.
 
 Speculative decoding (ISSUE 9; ``inference/speculative.py``,
 ``spec_decode`` kwarg / ``serving_spec_*`` flags, default off):
@@ -1636,24 +1643,33 @@ class ContinuousBatchingEngine:
         (``CacheIntegrityError``, PDT-E019 — an allocator bug, never a
         user error) dumps a flight record before propagating."""
         try:
-            return self._step_inner()
+            with _tracing.span("engine.step"):
+                return self._step_inner()
         except CacheIntegrityError as e:
             _flight.dump("cache_integrity", error=e)
             raise
 
     def _step_inner(self):
-        completed = self._retire()
-        if self._early:
-            completed.extend(self._early)
-            self._early.clear()
-        now = self._clock()
-        completed.extend(self._sweep(now))
-        # SLO judgment rides the step boundary (throttled to the
-        # evaluation interval — one float compare most steps, never a
-        # per-token host sync)
-        if self._slo is not None:
-            self._slo.maybe_evaluate(now)
-        self._admit()
+        # the step's host time is split by spans on the profiler's
+        # clock (observability/tracing.py): engine.retire, engine.sweep,
+        # engine.admit here; engine.stage, serving.dispatch and
+        # engine.readback in the _run_* that the step takes
+        span = _tracing.span
+        with span("engine.retire"):
+            completed = self._retire()
+            if self._early:
+                completed.extend(self._early)
+                self._early.clear()
+        with span("engine.sweep"):
+            now = self._clock()
+            completed.extend(self._sweep(now))
+            # SLO judgment rides the step boundary (throttled to the
+            # evaluation interval — one float compare most steps, never
+            # a per-token host sync)
+            if self._slo is not None:
+                self._slo.maybe_evaluate(now)
+        with span("engine.admit"):
+            self._admit()
         self._stats["steps"] += 1
         if self.spec_decode and any(
                 s.phase in ("prefill", "decode") for s in self._slots):
@@ -1999,23 +2015,26 @@ class ContinuousBatchingEngine:
         self._grow_plan(plan)
         if not plan:
             return
-        (tok, tpos, tslot, tvalid, kv_lens, q_lens, last_idx,
-         _row0) = self._pack_plan(plan)
-        poison = self._guard.poison(
-            [self._slots[b].req.rid if b in plan else None
-             for b in range(B)])
-        fn = self._get_mixed_fn()
-        args = [Tensor(jnp.asarray(tok[None, :])),
-                Tensor(jnp.asarray(tpos)), Tensor(jnp.asarray(tslot)),
-                Tensor(jnp.asarray(tvalid)),
-                Tensor(jnp.asarray(kv_lens)),
-                Tensor(jnp.asarray(q_lens)),
-                Tensor(jnp.asarray(last_idx)),
-                Tensor(jnp.asarray(poison)),
-                Tensor(jnp.asarray(self._bt))]
+        with _tracing.span("engine.stage", op="mixed"):
+            (tok, tpos, tslot, tvalid, kv_lens, q_lens, last_idx,
+             _row0) = self._pack_plan(plan)
+            poison = self._guard.poison(
+                [self._slots[b].req.rid if b in plan else None
+                 for b in range(B)])
+            fn = self._get_mixed_fn()
+            args = [Tensor(jnp.asarray(tok[None, :])),
+                    Tensor(jnp.asarray(tpos)),
+                    Tensor(jnp.asarray(tslot)),
+                    Tensor(jnp.asarray(tvalid)),
+                    Tensor(jnp.asarray(kv_lens)),
+                    Tensor(jnp.asarray(q_lens)),
+                    Tensor(jnp.asarray(last_idx)),
+                    Tensor(jnp.asarray(poison)),
+                    Tensor(jnp.asarray(self._bt))]
         res = self._dispatch("mixed", lambda: fn(*args, *self._caches))
-        nxt = np.asarray(res[0]._read()).reshape(-1)
-        bad = np.asarray(res[1]._read()).reshape(-1)
+        with _tracing.span("engine.readback", op="mixed"):
+            nxt = np.asarray(res[0]._read()).reshape(-1)
+            bad = np.asarray(res[1]._read()).reshape(-1)
         self._caches = list(res[2:])
         self._stats["mixed_steps"] += 1
         self._stats["decode_dispatches"] += 1
@@ -2141,52 +2160,54 @@ class ContinuousBatchingEngine:
         self._grow_plan(plan)
         if not plan:
             return
-        (tok, tpos, tslot, tvalid, kv_lens, q_lens, _last_idx,
-         row0) = self._pack_plan(plan)
-        # the standing nan drill arms on every dispatch a slot rides;
-        # engine_draft_nan arms ONLY on slots with a verify segment
-        # this dispatch (the site's documented scope)
-        poison = self._guard.poison(
-            [self._slots[b].req.rid if b in plan else None
-             for b in range(B)])
-        poison = poison + self._guard.poison(
-            [self._slots[b].req.rid
-             if b in plan and plan[b][2] is None else None
-             for b in range(B)], sites=(SITE_DRAFT_NAN,))
-        need_lg = self.spec_temperature > 0
-        W = self.spec_k + 1            # gathered rows per slot
-        fn = self._get_spec_fn()
-        args = [Tensor(jnp.asarray(tok[None, :])),
-                Tensor(jnp.asarray(tpos)), Tensor(jnp.asarray(tslot)),
-                Tensor(jnp.asarray(tvalid)),
-                Tensor(jnp.asarray(kv_lens)),
-                Tensor(jnp.asarray(q_lens)),
-                Tensor(jnp.asarray(poison))]
-        if need_lg:
-            # sampling needs logits rows: slot b's W-row window holds
-            # its verify rows (padded by repetition) — or, for a
-            # prefill slot, its LAST chunk row at window position 0
-            # (the first-token sample when the chunk completes prefill)
-            gather_idx = np.zeros(B * W, np.int32)
-            for b, (seg, _pos0, take, _d) in plan.items():
-                if take is None:
-                    n = len(seg)
-                    idx = row0[b] + np.minimum(np.arange(W), n - 1)
-                else:
-                    idx = np.full(W, row0[b] + take - 1)
-                gather_idx[b * W:(b + 1) * W] = idx
-            args.append(Tensor(jnp.asarray(gather_idx)))
-        args.append(Tensor(jnp.asarray(self._bt)))
+        with _tracing.span("engine.stage", op="verify"):
+            (tok, tpos, tslot, tvalid, kv_lens, q_lens, _last_idx,
+             row0) = self._pack_plan(plan)
+            # the standing nan drill arms on every dispatch a slot rides;
+            # engine_draft_nan arms ONLY on slots with a verify segment
+            # this dispatch (the site's documented scope)
+            poison = self._guard.poison(
+                [self._slots[b].req.rid if b in plan else None
+                 for b in range(B)])
+            poison = poison + self._guard.poison(
+                [self._slots[b].req.rid
+                 if b in plan and plan[b][2] is None else None
+                 for b in range(B)], sites=(SITE_DRAFT_NAN,))
+            need_lg = self.spec_temperature > 0
+            W = self.spec_k + 1            # gathered rows per slot
+            fn = self._get_spec_fn()
+            args = [Tensor(jnp.asarray(tok[None, :])),
+                    Tensor(jnp.asarray(tpos)), Tensor(jnp.asarray(tslot)),
+                    Tensor(jnp.asarray(tvalid)),
+                    Tensor(jnp.asarray(kv_lens)),
+                    Tensor(jnp.asarray(q_lens)),
+                    Tensor(jnp.asarray(poison))]
+            if need_lg:
+                # sampling needs logits rows: slot b's W-row window holds
+                # its verify rows (padded by repetition) — or, for a
+                # prefill slot, its LAST chunk row at window position 0
+                # (the first-token sample when the chunk completes prefill)
+                gather_idx = np.zeros(B * W, np.int32)
+                for b, (seg, _pos0, take, _d) in plan.items():
+                    if take is None:
+                        n = len(seg)
+                        idx = row0[b] + np.minimum(np.arange(W), n - 1)
+                    else:
+                        idx = np.full(W, row0[b] + take - 1)
+                    gather_idx[b * W:(b + 1) * W] = idx
+                args.append(Tensor(jnp.asarray(gather_idx)))
+            args.append(Tensor(jnp.asarray(self._bt)))
         res = self._dispatch("verify",
                              lambda: fn(*args, *self._caches))
-        toks = np.asarray(res[0]._read()).reshape(-1)
-        bad = np.asarray(res[1]._read()).reshape(-1)
-        n_head = 2
-        logits = None
-        if need_lg:
-            logits = np.asarray(res[2]._read()).astype(
-                np.float32).reshape(B * W, -1)
-            n_head = 3
+        with _tracing.span("engine.readback", op="verify"):
+            toks = np.asarray(res[0]._read()).reshape(-1)
+            bad = np.asarray(res[1]._read()).reshape(-1)
+            n_head = 2
+            logits = None
+            if need_lg:
+                logits = np.asarray(res[2]._read()).astype(
+                    np.float32).reshape(B * W, -1)
+                n_head = 3
         self._caches = list(res[n_head:])
         self._stats["decode_dispatches"] += 1
         if any(p[2] is not None for p in plan.values()):
@@ -2331,7 +2352,8 @@ class ContinuousBatchingEngine:
         self._grow_decode_slots()
         if not any(s.phase == "decode" for s in self._slots):
             return                      # everyone got preempted
-        tok, pos, fin, eos, stop, rids = self._slot_vectors()
+        with _tracing.span("engine.stage", op="decode"):
+            tok, pos, fin, eos, stop, rids = self._slot_vectors()
         if self._tpp is not None:
             # TP path: the scanned window program is self-contained
             # (explicit sharded params, no captured executable state),
@@ -2361,14 +2383,16 @@ class ContinuousBatchingEngine:
                     Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(pos)),
                     Tensor(jnp.asarray(self._bt)),
                     Tensor(jnp.asarray(poison)), *self._caches))
-                nxt = np.asarray(res[1]._read()).astype(np.int32)
-                bad = np.asarray(res[2]._read()).astype(bool)
+                with _tracing.span("engine.readback", op="decode"):
+                    nxt = np.asarray(res[1]._read()).astype(np.int32)
+                    bad = np.asarray(res[2]._read()).astype(bool)
                 self._caches = list(res[3:])
             else:
                 res = self._dispatch("decode", lambda: step_fn(
                     Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(pos)),
                     Tensor(jnp.asarray(self._bt)), *self._caches))
-                lg = np.asarray(res[0]._read()).astype(np.float32)
+                with _tracing.span("engine.readback", op="decode"):
+                    lg = np.asarray(res[0]._read()).astype(np.float32)
                 self._caches = list(res[1:])
                 lg = lg + self._guard.poison(rids)[:, None]
                 bad = ~np.isfinite(lg).all(-1)
@@ -2411,21 +2435,22 @@ class ContinuousBatchingEngine:
         non-finite freezes in-graph and is failed host-side."""
         exe = self._decode_exe
         K = self.decode_window
-        for sync in exe.discovery.host_syncs:
-            sync()
-        capt = exe.capt_state
-        carry_idx, const_idx = exe.state_split()
-        cache_vals = [c._read() for c in self._caches]
-        cstate = [capt[i]._read() for i in carry_idx]
-        const_state = [capt[i]._read() for i in const_idx]
-        poison = self._guard.poison(rids)
-        runner = self._get_window_runner(K)
-        self._audit_program(
-            ("window", K), runner,
-            (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(fin),
-             jnp.asarray(np.zeros(self.max_slots, bool)),
-             jnp.asarray(eos), jnp.asarray(stop), jnp.asarray(poison),
-             jnp.asarray(self._bt), cache_vals, cstate, const_state))
+        with _tracing.span("engine.stage", op="window"):
+            for sync in exe.discovery.host_syncs:
+                sync()
+            capt = exe.capt_state
+            carry_idx, const_idx = exe.state_split()
+            cache_vals = [c._read() for c in self._caches]
+            cstate = [capt[i]._read() for i in carry_idx]
+            const_state = [capt[i]._read() for i in const_idx]
+            poison = self._guard.poison(rids)
+            runner = self._get_window_runner(K)
+            self._audit_program(
+                ("window", K), runner,
+                (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(fin),
+                 jnp.asarray(np.zeros(self.max_slots, bool)),
+                 jnp.asarray(eos), jnp.asarray(stop), jnp.asarray(poison),
+                 jnp.asarray(self._bt), cache_vals, cstate, const_state))
         donated = cache_vals + cstate    # runner donate_argnums=(8, 9)
 
         def _window_call():
@@ -2458,7 +2483,9 @@ class ContinuousBatchingEngine:
             t._data = v
             t._node = None
         self._stats["decode_dispatches"] += 1
-        self._apply_window(np.asarray(toks), np.asarray(bads), fin, K)
+        with _tracing.span("engine.readback", op="window"):
+            toks, bads = np.asarray(toks), np.asarray(bads)
+        self._apply_window(toks, bads, fin, K)
 
     def _apply_window(self, toks, bads, fin, K):
         """Host replay of the device stop rule over one decode
@@ -2504,14 +2531,16 @@ class ContinuousBatchingEngine:
         donated-cache retry contract, same host replay)."""
         K = self.decode_window
         runner = self._get_tp_window(K)
-        cache_vals = [c._read() for c in self._caches]
-        poison = self._guard.poison(rids)
-        self._audit_program(
-            ("tpwin", K), runner,
-            (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(fin),
-             jnp.asarray(np.zeros(self.max_slots, bool)),
-             jnp.asarray(eos), jnp.asarray(stop), jnp.asarray(poison),
-             jnp.asarray(self._bt), *self._tpp.vals, *cache_vals))
+        with _tracing.span("engine.stage", op="window"):
+            cache_vals = [c._read() for c in self._caches]
+            poison = self._guard.poison(rids)
+            self._audit_program(
+                ("tpwin", K), runner,
+                (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(fin),
+                 jnp.asarray(np.zeros(self.max_slots, bool)),
+                 jnp.asarray(eos), jnp.asarray(stop),
+                 jnp.asarray(poison), jnp.asarray(self._bt),
+                 *self._tpp.vals, *cache_vals))
 
         def _window_call():
             if any(getattr(v, "is_deleted", lambda: False)()
@@ -2534,7 +2563,9 @@ class ContinuousBatchingEngine:
             t._data = v
             t._node = None
         self._stats["decode_dispatches"] += 1
-        self._apply_window(np.asarray(toks), np.asarray(bads), fin, K)
+        with _tracing.span("engine.readback", op="window"):
+            toks, bads = np.asarray(toks), np.asarray(bads)
+        self._apply_window(toks, bads, fin, K)
 
 
 def _make_slot_window(exe, K):

@@ -45,9 +45,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import scope as _scope
 from ..core import state
 from ..core import tensor as tensor_mod
 from ..core.tensor import Tensor
+from ..observability import tracing as _obs_tracing
 
 logger = logging.getLogger("paddle_tpu.jit")
 
@@ -330,6 +332,7 @@ class _Executable:
         self.jaxpr = None            # ClosedJaxpr, kept for the IR lint
         self.donate_idx: tuple = ()  # donated invar positions
         self.n_explicit_args = 0     # leading caller-owned inputs
+        self._fn_name = getattr(fn, "__name__", "step")
 
     def state_split(self):
         """(carry_idx, const_idx) into ``capt_state``: which captured
@@ -386,7 +389,10 @@ class _Executable:
             tr = _ReplayTracker(pos, vals)
             old = tensor_mod.set_tracker(tr)
             try:
-                out = fn(*call_args, **call_kwargs)
+                # phase scopes (core/scope.py) are entered only here,
+                # while the program is being captured
+                with _scope.capture():
+                    out = fn(*call_args, **call_kwargs)
             finally:
                 tensor_mod.set_tracker(old)
             ret_vals = []
@@ -413,6 +419,13 @@ class _Executable:
         n_args = len(arg_tensors)
         donate = tuple(i for i, t in enumerate(ordered)
                        if i >= n_args and id(t) in written_ids)
+        # the program is named after the user's function
+        # (``jit_train_step`` in the trace's "XLA Modules" line and in
+        # every op_name), not ``jit_pure``.  The module's name is part
+        # of the persistent cache's key where op_name metadata is not,
+        # so no run is served an executable compiled before the phase
+        # scopes existed.
+        pure.__name__ = pure.__qualname__ = self._fn_name
         self._pure = pure  # re-used by jit.multi_step's scanned window
         self.compiled = jax.jit(pure, donate_argnums=donate)
         self.donate_idx = donate
@@ -426,8 +439,6 @@ class _Executable:
         # train.compile_ms histogram — the single-process blind spot
         # that made recompiles invisible in step timelines.
         from ..observability import metrics as _obs_metrics
-        from ..observability import tracing as _obs_tracing
-        self._fn_name = getattr(self.fn, "__name__", "step")
         saved_grads = [(t, t._grad) for t in grad_owners]
         t0 = time.perf_counter()
         try:
@@ -453,11 +464,19 @@ class _Executable:
             _register_hbm_gauges(self._fn_name)
 
     def __call__(self, arg_tensors):
-        for sync in self.discovery.host_syncs:
-            sync()
-        vals = [t._read() for t in arg_tensors] + \
-            [t._read() for t in self.capt_state]
-        outs = self.compiled(*vals)
+        span = _obs_tracing.span
+        with span("to_static.call", fn=self._fn_name):
+            with span("to_static.read_state"):
+                for sync in self.discovery.host_syncs:
+                    sync()
+                vals = [t._read() for t in arg_tensors] + \
+                    [t._read() for t in self.capt_state]
+            with span("to_static.launch"):
+                outs = self.compiled(*vals)
+            with span("to_static.write_state"):
+                return self._write_state(arg_tensors, outs)
+
+    def _write_state(self, arg_tensors, outs):
         n_ret = self.n_ret
         n_state = len(self.state_out_tensors)
         n_arg_out = len(self.arg_out_pos)
@@ -596,6 +615,7 @@ class StaticFunction:
 
             def _remat_fn(*args, **kw):
                 return recompute(fn, *args, policy=pol, **kw)
+            _remat_fn.__name__ = self.__name__
             self._remat_fn = _remat_fn
         return self._remat_fn
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
+from ..core import scope as _scope
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
@@ -242,7 +243,8 @@ class GPTForCausalLM(Layer):
         h = self.gpt(input_ids)
         if self.lm_head is not None:
             return self.lm_head(h)
-        return ops.matmul(h, self.gpt.wte.weight, transpose_y=True)
+        with _scope.phase("lm_head"):
+            return ops.matmul(h, self.gpt.wte.weight, transpose_y=True)
 
     def forward(self, input_ids, labels=None):
         logits = self.logits(input_ids)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as np
 
+from ...core import scope as _scope
 from ...core.dispatch import apply
 from ...core.tensor import Tensor
 
@@ -130,7 +131,8 @@ def cross_entropy(input, label, weight=None, ignore_index=-100,
             return jnp.sum(loss) / jnp.maximum(n_valid, 1.0)
         return _reduce(loss, reduction)
 
-    return apply("cross_entropy", impl, *args)
+    with _scope.phase("loss"):
+        return apply("cross_entropy", impl, *args)
 
 
 def softmax_with_cross_entropy(logits, label, soft_label=False,

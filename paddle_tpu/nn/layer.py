@@ -15,6 +15,7 @@ from typing import Iterator, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import scope as _scope
 from ..core.dtype import convert_dtype
 from ..core.tensor import Parameter, Tensor
 from . import initializer as I
@@ -103,6 +104,8 @@ class Layer:
         if sublayer is not None and not isinstance(sublayer, Layer):
             raise TypeError(f"expected Layer, got {type(sublayer)}")
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            sublayer._scope_name = str(name)
         return sublayer
 
     def register_buffer(self, name, tensor, persistable=True):
@@ -132,6 +135,7 @@ class Layer:
                 if d is not None:
                     d.pop(name, None)
             sublayers[name] = value
+            value._scope_name = name
         elif buffers is not None and name in buffers:
             if value is not None and not isinstance(value, Tensor):
                 value = Tensor(value)
@@ -355,17 +359,23 @@ class Layer:
     def forward(self, *inputs, **kwargs):
         raise NotImplementedError
 
+    # the key under which the parent registered this layer: its name in
+    # the compiled program's phase scopes (``gpt/block_3/attn``).  Not
+    # ``_full_name``, whose counter differs from process to process.
+    _scope_name = None
+
     def __call__(self, *inputs, **kwargs):
-        for hook in list(self._forward_pre_hooks.values()):
-            out = hook(self, inputs)
-            if out is not None:
-                inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
-        for hook in list(self._forward_post_hooks.values()):
-            res = hook(self, inputs, outputs)
-            if res is not None:
-                outputs = res
-        return outputs
+        with _scope.phase(self._scope_name or type(self).__name__):
+            for hook in list(self._forward_pre_hooks.values()):
+                out = hook(self, inputs)
+                if out is not None:
+                    inputs = out if isinstance(out, tuple) else (out,)
+            outputs = self.forward(*inputs, **kwargs)
+            for hook in list(self._forward_post_hooks.values()):
+                res = hook(self, inputs, outputs)
+                if res is not None:
+                    outputs = res
+            return outputs
 
     def full_name(self):
         return self._full_name

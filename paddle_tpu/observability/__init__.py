@@ -95,15 +95,25 @@ Every event is one flat JSON-able dict::
     guard.step_skip       streak                        (StepGuard)
     fault.fired           site, key                     (faults.check)
     preempt.signal        signum                        (preempt handler)
-    span                  name, dur_us                  (RecordEvent)
+    span                  name, dur_us   (profiler.RecordEvent: the
+                          same code as tracing.span, Paddle's record)
     op                    name, dur_us                  (dispatch hook,
                                                          while profiling)
     flight.dump           reason, path                  (flight recorder)
     span.begin            name, span_id, trace_id, tname,
-                          parent_id?, ...attrs          (tracing.span)
+                          parent_id?, ...attrs          (tracing.span:
+                          the one span API; every span is also a
+                          jax.profiler annotation (a TraceMe), so it lies in a
+                          live profiler session's .xplane.pb)
     span.end              name, span_id, trace_id, dur_us, error?
-    compile.begin/end     (as span.begin/end, name="compile": fn,
-                          n_inputs, n_state, n_donated) (jit build)
+    spans of the program  (as span.begin/end) compile: fn, n_inputs,
+                          n_state, n_donated (jit build); to_static.call
+                          (fn) > to_static.read_state / .launch /
+                          .write_state (jit._Executable.__call__);
+                          engine.step > engine.retire / .sweep / .admit
+                          / .stage / serving.dispatch / engine.readback
+                          (inference/engine.py); router.*, dp.*, pp.*,
+                          collective.*, serving.handoff
     compile.retrace       fn, count, cause          (jit._Executable)
     rpc.client/rpc.server (as spans: fn, to/rank)   (distributed/rpc)
     slo.breach            slo, metric, value, target, burn_fast,

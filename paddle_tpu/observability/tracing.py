@@ -25,11 +25,17 @@ that served it.
 
 Gating
 ------
-Everything here is gated on the ``PDTPU_METRICS`` flag: with it off,
-``span()`` returns after one dict lookup and emits nothing, ``inject``
-returns ``None``, rpc payloads go out UNWRAPPED (bitwise
-pre-observability wire behavior) and ``export_trace`` writes nothing —
-the cheap-no-op contract the flag promises everywhere else.
+There is one span API and no knob of its own.  Every :func:`span`
+opens a ``jax.profiler.TraceAnnotation``, which records only while a
+profiler session is live (``jax.profiler.start_trace`` or
+``profiler.Profiler(targets=[TPU])``): the program's spans then lie in
+the ``.xplane.pb`` on the device's clock.  The event ring is gated on
+the ``PDTPU_METRICS`` flag: with it off ``span()`` emits nothing,
+``inject`` returns ``None``, rpc payloads go out UNWRAPPED (bitwise
+pre-observability wire behavior) and ``export_trace`` writes nothing.
+"Tracing off" is no session and the flag off; a span then costs the
+TraceMe's enter and exit (some 0.3-0.5 us).  ``profiler.RecordEvent``
+is a thin caller of the same code.
 
 Event kinds (see the package docstring for the full schema)::
 
@@ -54,6 +60,8 @@ import json
 import os
 import threading
 import time
+
+import jax
 
 from . import events as _events
 from .metrics import LATENCY_BUCKETS_MS, enabled
@@ -154,10 +162,20 @@ class attach:
         return False
 
 
+# fed by ``profiler.Profiler`` while a record window is open (the one
+# place its host buffer is filled): fn(name, t0_ns, dur_us, category)
+_host_sink = None
+
+
 class span:
-    """``with span("compile", fn="step"): ...`` — one begin/end pair in
-    the event ring, exception-safe (the end event records the error
-    type and still pops the stack), near-no-op when metrics are off.
+    """``with span("compile", fn="step"): ...`` — THE span of the
+    program.  On entry it opens a ``jax.profiler.TraceAnnotation`` (a
+    TraceMe: it records only while a profiler session is live, so the
+    span lies on the device trace's clock in the ``.xplane.pb``), and
+    under ``PDTPU_METRICS`` writes one begin/end pair to the event
+    ring, exception-safe (the end event records the error type and
+    still pops the stack).  With no session and metrics off it costs
+    the TraceMe's enter+exit and nothing else.
 
     The FIRST span on a thread starts a new trace (fresh ``trace_id``);
     nested spans inherit it and point ``parent_id`` at the enclosing
@@ -166,23 +184,37 @@ class span:
     ``ts``/``name``/``span_id``/``trace_id``/``parent_id``/``tname``).
     """
 
-    __slots__ = ("name", "attrs", "span_id", "_t0", "_on", "_root")
+    __slots__ = ("name", "attrs", "span_id", "_t0", "_on", "_root",
+                 "_ann", "_sink")
+
+    # ring record: a ``span.begin``/``span.end`` pair in the trace
+    # context.  ``profiler.RecordEvent`` (``_UserSpan``) writes Paddle's
+    # single ``span`` record at close instead.
+    _paired = True
+    _cat = "span"
 
     def __init__(self, name, **attrs):
         self.name = name
         self.attrs = attrs
         self._on = False
+        self._sink = None
 
     def __enter__(self):
-        if not enabled():
+        name = str(self.name)
+        self._ann = jax.profiler.TraceAnnotation(name, **self.attrs)
+        self._ann.__enter__()
+        self._sink = _host_sink
+        self._on = enabled()
+        if self._on or self._sink is not None:
+            self._t0 = time.perf_counter_ns()
+        if not (self._on and self._paired):
             return self
-        self._on = True
         self._root = not _ctx.stack
         if self._root:
             _ctx.trace_id = _new_id()
         parent = _ctx.stack[-1] if _ctx.stack else None
         self.span_id = _new_id()
-        ev = {"name": str(self.name), "span_id": self.span_id,
+        ev = {"name": name, "span_id": self.span_id,
               "trace_id": _ctx.trace_id,
               "tname": threading.current_thread().name}
         if parent is not None:
@@ -190,28 +222,48 @@ class span:
         ev.update(self.attrs)
         _events.emit("span.begin", **ev)
         _ctx.stack.append(self.span_id)
-        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, etype, exc, tb):
-        if not self._on:
+        self._ann.__exit__(etype, exc, tb)
+        on, sink = self._on, self._sink
+        if not on and sink is None:
             return False
-        self._on = False
+        self._on, self._sink = False, None
+        name = str(self.name)
+        dur_ns = time.perf_counter_ns() - self._t0
+        if sink is not None:
+            sink(name, self._t0, dur_ns // 1000, self._cat)
+        if not on:
+            return False
+        if not self._paired:
+            _events.emit("span", name=self.name,
+                         dur_us=int(dur_ns // 1000))
+            return False
         # pop OUR id even if an attach/reset raced the scope
         if _ctx.stack and _ctx.stack[-1] == self.span_id:
             _ctx.stack.pop()
         elif self.span_id in _ctx.stack:
             _ctx.stack.remove(self.span_id)
-        fields = {"name": str(self.name), "span_id": self.span_id,
+        fields = {"name": name, "span_id": self.span_id,
                   "trace_id": _ctx.trace_id,
-                  "dur_us": round((time.perf_counter() - self._t0) * 1e6,
-                                  1)}
+                  "dur_us": round(dur_ns / 1e3, 1)}
         if etype is not None:
             fields["error"] = etype.__name__
         _events.emit("span.end", **fields)
         if self._root and not _ctx.stack:
             _ctx.trace_id = None
         return False
+
+
+class _UserSpan(span):
+    """``profiler.RecordEvent``'s span: the same TraceAnnotation and
+    host-buffer feed, Paddle's one ``span`` ring record at close, and
+    no part in the trace context."""
+
+    __slots__ = ()
+    _paired = False
+    _cat = "user"
 
 
 def traced(name=None, **attrs):
@@ -224,8 +276,6 @@ def traced(name=None, **attrs):
 
         @functools.wraps(fn)
         def wrapper(*a, **k):
-            if not enabled():        # zero-overhead off path
-                return fn(*a, **k)
             with span(sname, **attrs):
                 return fn(*a, **k)
         return wrapper

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import scope as _scope
 from ..core import state as _state
 from ..core import tensor as _tm
 from ..core.tensor import Parameter, Tensor
@@ -191,6 +192,10 @@ class Optimizer:
         return self._lr_var._read()
 
     def step(self):
+        with _scope.phase("optimizer"):
+            self._step()
+
+    def _step(self):
         self._step_count += 1
         pairs = self._collect()
         # step telemetry (ISSUE 8): eager-only wall time + fused bucket
@@ -220,7 +225,8 @@ class Optimizer:
             # the per-param path lazily re-creates them at 1.0
             self._defuse("fused path disabled", count=False)
         if self._grad_clip is not None:
-            pairs = self._grad_clip(pairs)
+            with _scope.phase("clip"):
+                pairs = self._grad_clip(pairs)
         self._apply_pairs(pairs, self._live_lr())
         if t0 is not None:
             note_optimizer_step((_time.perf_counter() - t0) * 1e3)
@@ -535,17 +541,18 @@ class Optimizer:
             self._gather_grads(grp, gmap)
         lr = self._live_lr()
         clip_scale = None
-        if clip_active:
-            sq = [jnp.sum(jnp.square(
-                grp.grad_store.storage._read().astype(jnp.float32)))
-                for grp in fl]
-            for p, g in leftover:
-                if g is None or getattr(p, "need_clip", True) is False:
-                    continue
-                sq.append(jnp.sum(jnp.square(
-                    g._read().astype(jnp.float32))))
-            if sq:
-                clip_scale = self._grad_clip._flat_scale(sq)
+        with _scope.phase("clip"):
+            if clip_active:
+                sq = [jnp.sum(jnp.square(
+                    grp.grad_store.storage._read().astype(jnp.float32)))
+                    for grp in fl]
+                for p, g in leftover:
+                    if g is None or getattr(p, "need_clip", True) is False:
+                        continue
+                    sq.append(jnp.sum(jnp.square(
+                        g._read().astype(jnp.float32))))
+                if sq:
+                    clip_scale = self._grad_clip._flat_scale(sq)
         if clip_scale is not None and leftover:
             leftover = ClipGradByGlobalNorm._apply_scale(leftover,
                                                          clip_scale)
@@ -654,6 +661,10 @@ class Optimizer:
         raise NotImplementedError
 
     def clear_grad(self, set_to_zero=False):
+        with _scope.phase("clear_grad"):
+            self._clear_grad(set_to_zero)
+
+    def _clear_grad(self, set_to_zero):
         # NOTE: the reference defaults set_to_zero=True (zero in place);
         # we default to dropping the buffer — zeroing is opt-in for
         # jit-captured gradient accumulation (hapi accumulate_grad_batches).

@@ -5,9 +5,10 @@ Capability analog of SURVEY C29 + the Python profiler API
 ``utils.py`` RecordEvent, ``profiler_statistic.py`` summaries,
 ``chrometracing_logger.cc`` export). TPU-native split:
 
-- HOST tracing is framework-owned: ``RecordEvent`` spans + automatic
-  per-op dispatch events (a hook in ``core.dispatch``) land in a
-  process-local buffer exported as chrome ``trace.json`` (load in
+- HOST tracing is framework-owned: the program's spans
+  (``observability.tracing.span``, of which ``RecordEvent`` is a thin
+  caller) + automatic per-op dispatch events (a hook in
+  ``core.dispatch``) land in a process-local buffer exported as chrome ``trace.json`` (load in
   ``chrome://tracing`` / Perfetto — same workflow as the reference).
 - DEVICE tracing delegates to ``jax.profiler`` (XLA's tracer): when a
   device target is enabled the Profiler brackets the record window with
@@ -26,6 +27,7 @@ from enum import Enum
 from typing import Callable, Iterable, Optional
 
 from ..core import dispatch as _dispatch
+from ..observability import tracing as _tracing
 
 
 class ProfilerTarget(Enum):
@@ -107,40 +109,26 @@ _active_profiler: Optional["Profiler"] = None
 
 
 class RecordEvent:
-    """User-scope span (reference ``profiler/utils.py RecordEvent``); also
-    forwards to jax.profiler's TraceAnnotation so the span shows up inside
-    device traces."""
+    """User-scope span (reference ``profiler/utils.py RecordEvent``): a
+    thin caller of the program's one span implementation
+    (``observability.tracing``), so it shows up inside device traces
+    (a ``jax.profiler`` annotation), in this Profiler's host buffer
+    while a record window is open, and in the event ring as one
+    ``span`` record."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._t0 = None
-        self._jax_ctx = None
+        self._span = None
 
     def begin(self):
-        self._t0 = time.perf_counter_ns()
-        try:
-            import jax
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-            self._jax_ctx.__enter__()
-        except Exception:
-            self._jax_ctx = None
+        self._span = _tracing._UserSpan(self.name)
+        self._span.__enter__()
 
     def end(self):
-        if self._t0 is None:
+        if self._span is None:
             return
-        dur_us = (time.perf_counter_ns() - self._t0) // 1000
-        if _active_profiler is not None and _active_profiler._recording:
-            _buffer.add(self.name, self._t0 // 1000, dur_us,
-                        threading.get_ident(), "user")
-        # same stream as everything else (ISSUE 8): user spans land in
-        # the observability event ring too, so chrome traces and flight
-        # records tell one story
-        from ..observability import events as _obs_events
-        _obs_events.emit("span", name=self.name, dur_us=int(dur_us))
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(None, None, None)
-            self._jax_ctx = None
-        self._t0 = None
+        span, self._span = self._span, None
+        span.__exit__(None, None, None)
 
     def __enter__(self):
         self.begin()
@@ -148,6 +136,10 @@ class RecordEvent:
 
     def __exit__(self, *exc):
         self.end()
+
+
+def _span_sink(name, t0_ns, dur_us, cat):
+    _buffer.add(name, t0_ns // 1000, dur_us, threading.get_ident(), cat)
 
 
 def _op_profile_hook(name: str, t0_ns: int, t1_ns: int):
@@ -265,6 +257,7 @@ class Profiler:
         hook and the device tracer can never outlive a failed start."""
         self._recording = True
         try:
+            _tracing._host_sink = _span_sink
             if not self.timer_only:
                 _dispatch._profile_hook = _op_profile_hook
             if any(t in (ProfilerTarget.GPU, ProfilerTarget.TPU,
@@ -291,6 +284,7 @@ class Profiler:
         its own net for the same reason."""
         self._recording = False
         _dispatch._profile_hook = None
+        _tracing._host_sink = None
         if self._device_tracing:
             self._device_tracing = False
             try:
