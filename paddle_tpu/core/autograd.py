@@ -24,9 +24,12 @@ from . import state
 class Node:
     """One recorded op in the grad graph. Analog of ``egr::GradNodeBase``.
 
-    ``vjp_fn`` may be None: the linearization is built LAZILY at backward
-    time from ``pure`` + ``diff_vals`` (the forward-time input snapshot),
-    so grad-enabled forwards that never backward pay no jax.vjp cost."""
+    ``vjp_fn`` is None on a node recorded in eager: the linearization is
+    built LAZILY at backward time from ``pure`` + ``diff_vals`` (the
+    forward-time input snapshot), so grad-enabled forwards that never
+    backward pay no jax.vjp cost.  A node recorded while a program is
+    captured carries the ``vjp_fn`` of its one traced forward and no
+    ``diff_vals`` (``core/dispatch.py`` says why)."""
 
     __slots__ = ("name", "vjp_fn", "inputs", "out_ids", "out_avals",
                  "consumed", "pure", "seq_type", "diff_vals", "scope")
@@ -232,10 +235,14 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False, accumulate=True
                 g = _cast(g, aval.dtype)
             out_grads.append(g)
         if create_graph and n.pure is not None:
+            # re-linearised here whatever the node holds: the backward
+            # op itself goes on the tape
+            _scope.tape().backward += 1
             cots = _vjp_through_dispatch(n, out_grads)
         else:
             out_grads = [_val(g) for g in out_grads]
             if n.vjp_fn is None:  # lazy: linearize on first backward
+                _scope.tape().backward += 1
                 try:
                     _, n.vjp_fn = jax.vjp(n.pure, *n.diff_vals)
                 except Exception as e:

@@ -4,7 +4,9 @@ Capability analog of the PHI kernel dispatch + eager ad-function codegen
 (SURVEY C9/C15/C16; reference ``paddle/phi/core/kernel_factory.h:316``
 SelectKernelOrThrowError and the generated ``*_ad_func`` forward functions of
 ``eager_gen.py``): unwrap tensors, run the XLA-lowered compute, and — when any
-differentiable input requires grad — record a jax.vjp node on the tape.
+differentiable input requires grad — record a jax.vjp node on the tape
+(linearised at backward time in eager, as it is recorded under a
+``jit.to_static`` capture).
 
 There is no KernelKey{backend,layout,dtype} selection: XLA owns backend and
 layout; dtype promotion is jnp's. That whole reference subsystem collapses
@@ -19,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import scope as _scope
 from . import state
 from .autograd import Node
 from .tensor import Tensor
@@ -156,22 +159,42 @@ def _apply(name: str, fn: Callable, *args, **kwargs):
             merged[i] = dv
         return fn(*_rebuild(spec, merged), **kwargs)
 
-    # LAZY linearization: run the plain forward now; jax.vjp happens at
-    # backward time from the saved input values (autograd.run_backward).
+    # WHERE the op is linearised follows from whether a program is being
+    # captured (core/scope.py: jit's replay of a to_static function).
+    #
+    # Under capture: jax.vjp here, as the op is recorded.  The forward
+    # is traced once and its residuals belong to the forward pass.  The
+    # other order (plain forward now, jax.vjp from the saved inputs at
+    # backward time) traces every op twice into the one program and
+    # leaves XLA to merge the copies.  On the v5e it did not merge the
+    # Pallas flash forward, fc1 + GELU or attn/proj: 35.9 ms of a
+    # 230.7 ms GPT-2-medium step was forward work run again inside the
+    # backward (PERF.md, PR 26/27), and a recompute block's policy saved
+    # nothing past its own backward, because the block's residual-saving
+    # forward was traced there.
+    #
+    # In eager: run the plain forward now; jax.vjp happens at backward
+    # time from the saved input values (autograd.run_backward).
     # Measured (benchmarks/eager_bench.py): eager jax.vjp-per-op costs
     # ~10x a plain dispatch, so grad-enabled forwards that never reach a
     # backward (eval loops, branch probes) must not pay it. The trade: a
     # backwarded op re-runs its primal inside jax.vjp (fwd executes
     # twice); measured fwd+bwd cost moves ~4.7ms -> ~5.5ms per 256x256
-    # linear on CPU — eager is dispatch-bound, and the jit path (where
-    # throughput lives) traces identically either way.
+    # linear on CPU, and eager is dispatch-bound.  A capture that records
+    # nodes and never runs a backward traces linearisations XLA deletes.
+    capturing = _scope.current() is not None
+    diff_vals = [vals[i] for i in diff_idx]
     try:
-        out_vals = fn(*_rebuild(spec, vals), **kwargs)
+        if capturing:
+            out_vals, vjp_fn = jax.vjp(pure, *diff_vals)
+            _scope.tape().record += 1
+        else:
+            out_vals, vjp_fn = fn(*_rebuild(spec, vals), **kwargs), None
     except Exception as e:
         _reraise_with_op_context(name, vals, e)
     out, node_outs = _wrap_outputs(name, out_vals, node=..., any_grad=True)
     node = Node(
-        name, None,
+        name, vjp_fn,
         inputs=[tensors[i] for i in diff_idx],
         out_ids=[o._uid for o in node_outs],
         out_avals=[jax.ShapeDtypeStruct(o._data.shape, o._data.dtype)
@@ -179,7 +202,7 @@ def _apply(name: str, fn: Callable, *args, **kwargs):
         pure=pure,
         seq_type=(tuple if isinstance(out_vals, tuple)
                   else list if isinstance(out_vals, list) else None),
-        diff_vals=[vals[i] for i in diff_idx])
+        diff_vals=None if capturing else diff_vals)
     for o in node_outs:
         o._node = node
     return out

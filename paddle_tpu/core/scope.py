@@ -11,6 +11,11 @@ no scope.
 The live path is kept per thread beside JAX's own name stack so that a
 grad node can remember where it was recorded (``current()``) and
 ``run_backward`` can re-enter that path under ``backward``.
+
+Whether a program is being captured also decides WHERE the tape
+linearises an op (``core/dispatch.py``): as it is recorded under a
+capture, at backward time in eager.  ``tape()`` is the tally of both,
+one per capture and one per thread for eager work.
 """
 from __future__ import annotations
 
@@ -20,9 +25,22 @@ import threading
 import jax
 
 
+class TapeCounts:
+    """Grad nodes linearised (``jax.vjp`` of the op's primal) as they
+    were recorded, and at backward time."""
+
+    __slots__ = ("record", "backward")
+
+    def __init__(self):
+        self.record = self.backward = 0
+
+
 class _State(threading.local):
     capturing = False
     path = ""       # "/"-joined scopes open on this thread
+
+    def __init__(self):
+        self.tape = TapeCounts()    # this thread's eager tally
 
 
 _state = _State()
@@ -32,19 +50,25 @@ _NO_SCOPE = contextlib.nullcontext()
 @contextlib.contextmanager
 def capture():
     """Set round the replay of a function whose program is being
-    captured (``jit._Executable``'s ``pure``)."""
-    old = _state.capturing, _state.path
-    _state.capturing, _state.path = True, ""
+    captured (``jit._Executable``'s ``pure``).  Yields the capture's
+    own ``TapeCounts``."""
+    old = _state.capturing, _state.path, _state.tape
+    _state.capturing, _state.path, _state.tape = True, "", TapeCounts()
     try:
-        yield
+        yield _state.tape
     finally:
-        _state.capturing, _state.path = old
+        _state.capturing, _state.path, _state.tape = old
 
 
 def current():
     """The scope path a grad node recorded now belongs to, or None
     outside a capture."""
     return _state.path if _state.capturing else None
+
+
+def tape():
+    """The live tally: the capture's inside one, else the thread's."""
+    return _state.tape
 
 
 class _Phase:

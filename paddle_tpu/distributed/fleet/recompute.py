@@ -6,8 +6,9 @@ re-running a block's forward during backward. TPU-native mechanism: the
 reference re-executes the Python block under a preserved RNG state; here the
 block is lifted into one ``jax.checkpoint``-wrapped pure function over
 (tensor args + the block's parameters), so XLA itself rematerializes inside
-the compiled program — in eager it shortens the tape's saved residuals to
-just the block inputs.
+the compiled program, where the tape linearises the block in the forward
+pass and the policy's residuals are what the forward keeps — in eager it
+shortens the tape's saved residuals to just the block inputs.
 
 Limitation: stateful side effects inside the block (BatchNorm running
 stats, RNG-consuming dropout) are not threaded out of the checkpointed
@@ -142,11 +143,21 @@ def _discover_params(function, args, kwargs):
 
 def _dots_and_kernels_saveable(prim, *_, **__):
     """dots_saveable + custom (Pallas) kernel calls: ``dots_saveable``
-    matches only dot_general, so a flash-attention forward inside a
-    checkpointed block gets RE-RUN during backward (~0.4 ms x layers per
-    step on the GPT bench). Marking custom/pallas calls saveable keeps
-    their outputs as residuals instead; the extra HBM is one [B,S,H,D]
-    activation per layer."""
+    matches only dot_general, so under it ``jax.checkpoint`` runs a
+    flash-attention forward inside the block again in the backward.
+    Marking custom/pallas calls saveable keeps their outputs (``o`` and
+    the log-sum-exp) as residuals instead; the extra HBM is one
+    [B,S,H,D] activation per layer.
+
+    A policy decides what ``jax.checkpoint`` keeps from the forward it
+    is linearised on, so it holds for the forward PASS only where the
+    block is linearised there: under a capture, where
+    ``core/dispatch.py`` builds the block's vjp as it is recorded.  (On
+    the v5e the kernel, fc1 + GELU and attn/proj ran twice a step while
+    a captured block was linearised at backward time, whatever this
+    policy named: PERF.md, PR 26/27.)  In eager the tape keeps only
+    the block's inputs and the backward's ``jax.vjp`` runs the block
+    again."""
     import jax as _jax
     if _jax.checkpoint_policies.dots_saveable(prim, *_, **__):
         return True

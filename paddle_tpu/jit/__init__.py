@@ -332,6 +332,11 @@ class _Executable:
         self.jaxpr = None            # ClosedJaxpr, kept for the IR lint
         self.donate_idx: tuple = ()  # donated invar positions
         self.n_explicit_args = 0     # leading caller-owned inputs
+        # grad nodes of the newest trace by where the tape linearised
+        # them (core/scope.TapeCounts): ``backward`` stays 0 unless the
+        # step backwards through a graph recorded outside the capture
+        # or asks for create_graph
+        self.tape_nodes = None
         self._fn_name = getattr(fn, "__name__", "step")
 
     def state_split(self):
@@ -391,7 +396,8 @@ class _Executable:
             try:
                 # phase scopes (core/scope.py) are entered only here,
                 # while the program is being captured
-                with _scope.capture():
+                with _scope.capture() as tape:
+                    self.tape_nodes = tape
                     out = fn(*call_args, **call_kwargs)
             finally:
                 tensor_mod.set_tracker(old)
@@ -445,9 +451,12 @@ class _Executable:
             with _obs_tracing.span("compile", fn=self._fn_name,
                                    n_inputs=len(ordered),
                                    n_state=len(written),
-                                   n_donated=len(donate)):
+                                   n_donated=len(donate)) as compile_span:
                 traced = self.compiled.trace(*[t._data for t in ordered])
                 self.jaxpr = traced.jaxpr
+                tape = self.tape_nodes
+                compile_span.note(tape_nodes_record=tape.record,
+                                  tape_nodes_backward=tape.backward)
                 traced.lower()
         finally:
             _scrub_leaked_tracers(d)
@@ -462,6 +471,12 @@ class _Executable:
                 _obs_metrics.LATENCY_BUCKETS_MS).observe(
                     (time.perf_counter() - t0) * 1e3)
             _register_hbm_gauges(self._fn_name)
+            for where in ("record", "backward"):
+                _obs_metrics.registry().counter(
+                    "train.tape_nodes",
+                    "grad nodes of captured programs by where the tape "
+                    "linearised them (counted as the program is traced)",
+                    labels={"linearised": where}).inc(getattr(tape, where))
 
     def __call__(self, arg_tensors):
         span = _obs_tracing.span
@@ -794,7 +809,10 @@ def aot_lower(fn, *args, donate_state=True, **kwargs):
         d = _DiscoveryTracker()
         old = tensor_mod.set_tracker(d)
         try:
-            out = fn(*args, **kwargs)
+            # the program to_static would compile: phase scopes, and
+            # the tape linearising each op as it is recorded
+            with _scope.capture():
+                out = fn(*args, **kwargs)
             ret_vals = [t._data for t in _flatten_tensors(out, [])]
             written = [t for t in d.written.values()]
             state_vals = [t._data for t in written]

@@ -185,7 +185,7 @@ class span:
     """
 
     __slots__ = ("name", "attrs", "span_id", "_t0", "_on", "_root",
-                 "_ann", "_sink")
+                 "_ann", "_sink", "_late")
 
     # ring record: a ``span.begin``/``span.end`` pair in the trace
     # context.  ``profiler.RecordEvent`` (``_UserSpan``) writes Paddle's
@@ -198,6 +198,7 @@ class span:
         self.attrs = attrs
         self._on = False
         self._sink = None
+        self._late = None
 
     def __enter__(self):
         name = str(self.name)
@@ -223,6 +224,12 @@ class span:
         _events.emit("span.begin", **ev)
         _ctx.stack.append(self.span_id)
         return self
+
+    def note(self, **attrs):
+        """Attributes learned inside the span: they go on its
+        ``span.end`` record (the TraceAnnotation took its own on
+        entry)."""
+        self._late = attrs
 
     def __exit__(self, etype, exc, tb):
         self._ann.__exit__(etype, exc, tb)
@@ -250,6 +257,8 @@ class span:
                   "dur_us": round(dur_ns / 1e3, 1)}
         if etype is not None:
             fields["error"] = etype.__name__
+        if self._late:
+            fields.update(self._late)
         _events.emit("span.end", **fields)
         if self._root and not _ctx.stack:
             _ctx.trace_id = None

@@ -144,6 +144,61 @@ def test_compiled_step_carries_phase_scopes(compiled_step):
     assert phases[phase_reduce.UNATTRIBUTED] < 0.1 * named, phases
 
 
+def test_no_forward_is_traced_again_by_the_backward(compiled_step):
+    """ISSUE 27: under capture the tape linearises each op as it is
+    recorded.  No instruction of the compiled step is what the reader
+    calls forward work re-run by the backward's own linearisation (a
+    ``jvp(...)`` scope with no ``transpose(...)`` under ``backward``):
+    the only recompute left is ``jax.checkpoint``'s own."""
+    step, ids, hlo = compiled_step
+    rerun = [name for _, name in _instructions(hlo) if name
+             and phase_reduce.phase_of_op(name) == "recompute"
+             and phase_reduce.REMAT not in phase_reduce.scopes(name)]
+    assert not rerun, rerun[:5]
+    tape = step.concrete_program(ids, ids).tape_nodes
+    assert tape.record > 0 and tape.backward == 0
+
+    # the same step run eagerly linearises at backward time only
+    eager_step, eager_ids = _tiny_step()
+    eager = scope.tape()
+    record, backward = eager.record, eager.backward
+    assert np.isfinite(float(eager_step.fn(eager_ids, eager_ids)))
+    assert eager.record == record
+    assert eager.backward - backward == tape.record
+
+
+def test_tape_counts_on_the_compile_span_and_in_the_registry(ring):
+    reg = obs.registry()
+
+    def count(where):
+        return reg.counter("train.tape_nodes",
+                           labels={"linearised": where}).value
+
+    before = count("record"), count("backward")
+    net = paddle.nn.Linear(4, 4)
+
+    @paddle.jit.to_static
+    def step(x):
+        loss = (net(x) ** 2).mean()
+        loss.backward()
+        for p in net.parameters():
+            p.clear_grad()
+        return loss
+
+    step(paddle.to_tensor(np.ones((2, 4), "float32")))
+    end = [e for e in obs.tail() if e["kind"] == "span.end"
+           and e["name"] == "compile"][-1]
+    assert end["tape_nodes_record"] > 0
+    assert end["tape_nodes_backward"] == 0
+    assert count("record") - before[0] == end["tape_nodes_record"]
+    assert count("backward") == before[1]
+    # the rendered trace carries them beside the begin's geometry
+    args = [e for e in tracing.render_trace()["traceEvents"]
+            if e["name"] == "compile"][-1]["args"]
+    assert args["tape_nodes_record"] == end["tape_nodes_record"]
+    assert args["fn"] == "step"
+
+
 def test_scope_names_do_not_depend_on_layer_counters():
     """Two models built one after the other get the same scope paths
     (``_full_name``'s process-wide counter is not used)."""
