@@ -15,14 +15,22 @@ Top-k routing with renormalized combine weights, per-expert capacity
 ``C = ceil(k * N / E * capacity_factor)``, overflow tokens dropped
 (GShard/Switch semantics), and the switch-style load-balance auxiliary
 loss ``E * sum(importance * load)``.
+
+``SparseMoEBlock`` beside it is the dropless formulation a chip of an
+expert-parallel deployment runs: a router over ALL the experts, grouped
+matrix products over the tokens routed to the experts held HERE.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ....core import scope as _scope
+from ....core.autograd import no_grad
 from ....core.dispatch import apply
 from ....core.tensor import Parameter, Tensor
 from ....nn.layer import Layer
@@ -160,3 +168,273 @@ class MoELayer(Layer):
 
     def forward(self, x):
         return self.moe(x)
+
+
+# ---------------------------------------------------------------------------
+# Dropless sparse block: one chip's share of an expert-parallel layer
+# ---------------------------------------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_sorted(flat, order, inverse, k):
+    """Slot-major rows in expert order: ``flat[order // k]``.  ``order``
+    is a permutation of the N*k slots and ``inverse`` its inverse, so
+    the transpose is a gather too (a slot's cotangent back at its own
+    place, the k slots of a token summed), not the scatter-add that
+    ``jnp.take`` transposes to: on the v5e at [65536, 2048] bfloat16
+    this gather and ``_unsort`` take 8.9 ms forward + backward, against
+    15.6 ms under plain autodiff (PERF.md, PR 28)."""
+    return jnp.take(flat, order // k, axis=0)
+
+
+def _take_sorted_fwd(flat, order, inverse, k):
+    return _take_sorted(flat, order, inverse, k), inverse
+
+
+def _take_sorted_bwd(k, inverse, g):
+    back = jnp.take(g, inverse, axis=0)
+    return (back.reshape(-1, k, g.shape[-1]).sum(axis=1).astype(g.dtype),
+            None, None)
+
+
+_take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
+
+
+@jax.custom_vjp
+def _unsort(rows, order, inverse):
+    """Rows in expert order back in slot order: ``rows[inverse]``."""
+    return jnp.take(rows, inverse, axis=0)
+
+
+def _unsort_fwd(rows, order, inverse):
+    return _unsort(rows, order, inverse), order
+
+
+def _unsort_bwd(order, g):
+    return jnp.take(g, order, axis=0), None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+_CARRY = 1 << 30    # the tally's low word holds less than this
+_SLOTS_AT_A_TIME = 16384
+_CALLS_KEPT = 1024  # calls whose own tallies a block keeps, a row each
+# layer -> the per-call buffer of the block last built under that name:
+# the buffer, not the block, for the gauges' reason
+_calls_of = {}
+
+
+def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
+               scaling=1.0):
+    """Values in, ``(out, tally)`` out; the math of ``SparseMoEBlock``.
+
+    ``x`` [..., H]; ``gate`` [H, E] over all E experts; ``w1``/``w3``
+    [held, H, I] and ``w2`` [held, I, H] are the experts
+    ``expert_offset .. expert_offset + held`` ; ``bias`` [E] float32.
+    ``tally`` is int32 [held + 1]: the slots routed to each held expert
+    and, last, the slots the router filled (N * top_k)."""
+    held, k = w1.shape[0], top_k
+    flat = x.reshape(-1, x.shape[-1])
+    n = flat.shape[0]
+    with _scope.phase("router"):
+        logits = jnp.dot(flat, gate, preferred_element_type=jnp.float32)
+        scores = jax.nn.sigmoid(logits)
+        # the bias decides WHICH experts, never how much of each
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weight = picked / (picked.sum(-1, keepdims=True) + 1e-6) * scaling
+    with _scope.phase("dispatch"):
+        local = chosen - expert_offset                        # [N, k]
+        here = (local >= 0) & (local < held)
+        # slots of absent experts sort last, behind every group
+        key = jnp.where(here, local, held).reshape(-1)
+        order = jnp.argsort(key)
+        inverse = jnp.argsort(order)
+        counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        routed = jnp.arange(n * k) < counts.sum()
+        # a row past the last group is read by no product, and the
+        # select keeps what the products leave there out of the
+        # gradient of x
+        rows = jnp.where(routed[:, None],
+                         _take_sorted(flat, order, inverse, k), 0)
+    with _scope.phase("expert_mlp"):
+        # ``slots`` sorted rows at a time: all N * k would be needed only
+        # if every token chose all its experts here, so the products'
+        # temporaries are bounded by the chunk, not by that worst case;
+        # a chunk past the last group has empty groups and no work.  The
+        # chunk is recomputed in its own backward: its a, b and their
+        # product are never held for all chunks at once.
+        chunks = -(-n * k // _SLOTS_AT_A_TIME)
+        while n * k % chunks:
+            chunks += 1
+        slots = n * k // chunks
+        ends = jnp.cumsum(counts)
+        lo = (jnp.arange(chunks) * slots)[:, None]
+        sizes = jnp.clip(ends[None], lo, lo + slots) \
+            - jnp.clip((ends - counts)[None], lo, lo + slots)
+
+        @jax.checkpoint
+        def experts(chunk):
+            x, groups = chunk
+            a = jax.lax.ragged_dot(x, w1, groups)
+            b = jax.lax.ragged_dot(x, w3, groups)
+            return jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, groups)
+
+        y = jax.lax.map(experts, (rows.reshape(chunks, slots, -1), sizes))
+        y = y.reshape(n * k, -1)
+    with _scope.phase("combine"):
+        y = _unsort(jnp.where(routed[:, None], y, 0), order, inverse)
+        w = jnp.where(here, weight, 0.0)
+        out = (w[..., None] * y.reshape(n, k, -1)).sum(axis=1)
+    tally = jnp.concatenate([counts, jnp.full((1,), n * k, jnp.int32)])
+    return out.astype(x.dtype).reshape(x.shape), tally
+
+
+def _tally(routed):
+    hi, lo = np.asarray(jax.device_get(routed._read()), np.int64)
+    return [int(v) for v in hi * _CARRY + lo]
+
+
+def _share(tally):
+    *here, filled = tally
+    return sum(here) / filled if filled else 0.0
+
+
+def routed_by_call():
+    """{layer: {call number, from 1: that call's tally}} for the last
+    ``_CALLS_KEPT`` calls each sparse block has counted (one host read
+    a layer).  The gauges say what was routed since construction; this
+    says when, so that a reader can take the calls of the stretch it
+    timed: a router that trains moves its load."""
+    out = {}
+    for layer, kept in _calls_of.items():
+        rows = np.asarray(jax.device_get(kept._read()), np.int64)
+        out[layer] = {int(row[-1]): [int(v) for v in row[:-1]]
+                      for row in rows if row[-1]}
+    return out
+
+
+class SparseMoEBlock(Layer):
+    """Dropless top-k block that is told which experts it holds.
+
+    The router scores ALL ``num_experts`` (sigmoid, float32); selection
+    adds ``expert_bias`` (a float32 buffer no gradient reaches: the
+    trainer's balancing rule owns it), the combine weights are the
+    selected scores normalised to sum to one, times
+    ``routed_scaling_factor``.  The block holds the SwiGLU experts
+    ``expert_offset .. expert_offset + experts_held`` stacked
+    ``[held, ...]`` and returns THEIR part of the layer's result: the
+    slots routed here are gathered in expert order and multiplied group
+    by group (``jax.lax.ragged_dot``), whatever the imbalance; there is
+    no capacity and no dropped token.  What the absent experts would
+    add is left out: under expert parallelism the shares are summed
+    across chips, and on one chip the block runs without that exchange.
+
+    ``tally()`` is what was routed here since construction: the compiled
+    step adds ``held + 1`` integers to a small buffer, and the
+    ``moe.tokens_per_expert{layer,expert}`` /
+    ``moe.routed_here_share{layer}`` gauges of the ``observability``
+    registry read it only when a snapshot is taken.  ``routed_by_call()``
+    is the same by call: the step also writes the call's own tally into
+    one row of a ring."""
+
+    def __init__(self, hidden_size, intermediate_size, num_experts, top_k,
+                 expert_offset=0, experts_held=None,
+                 routed_scaling_factor=1.0, expert_bias=None,
+                 weight_attr=None, down_attr=None, name=None):
+        super().__init__()
+        held = num_experts - expert_offset if experts_held is None \
+            else experts_held
+        if not (0 <= expert_offset and 0 < held
+                and expert_offset + held <= num_experts):
+            raise ValueError(
+                f"experts {expert_offset}..{expert_offset + held} are not "
+                f"among the router's {num_experts}")
+        if not 0 < top_k <= num_experts:
+            raise ValueError(f"top_k {top_k} of {num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.expert_offset, self.experts_held = expert_offset, held
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.gate = Linear(hidden_size, num_experts, bias_attr=False,
+                           weight_attr=weight_attr)
+        h, i = hidden_size, intermediate_size
+        self.w1 = self.create_parameter([held, h, i], attr=weight_attr)
+        self.w3 = self.create_parameter([held, h, i], attr=weight_attr)
+        self.w2 = self.create_parameter([held, i, h],
+                                        attr=down_attr or weight_attr)
+        bias = np.zeros(num_experts, np.float32) if expert_bias is None \
+            else np.asarray(expert_bias, np.float32)
+        if bias.shape != (num_experts,):
+            raise ValueError(f"expert_bias {bias.shape} for a router over "
+                             f"{num_experts}")
+        # a constant of the configuration while this block trains, so
+        # not a leaf of the checkpoint; float32 under AMP O2 because
+        # ``decorate`` casts parameters only
+        self.register_buffer("expert_bias", Tensor(jnp.asarray(bias)),
+                             persistable=False)
+        # [2, held + 1]: high and low words (``_CARRY`` each) of the
+        # slots per held expert and of the slots the router filled
+        self.register_buffer(
+            "routed", Tensor(jnp.zeros((2, held + 1), jnp.int32)),
+            persistable=False)
+        # a ring of the last calls' own tallies, each with its number
+        # (from 1; 0 marks a row not written yet)
+        self.register_buffer(
+            "calls", Tensor(jnp.zeros((_CALLS_KEPT, held + 2), jnp.int32)),
+            persistable=False)
+        layer = name or self._full_name
+        _calls_of[layer] = self.calls
+        self._register_gauges(layer)
+
+    def _register_gauges(self, layer):
+        from ....observability import metrics
+        # the gauges hold the tally's 72-byte buffer, not the block: the
+        # registry outlives the block and must not keep its weights
+        # alive, and a snapshot taken after the model is gone still
+        # reads what was routed
+        reg, routed = metrics.registry(), self.routed
+        for e in range(self.experts_held):
+            reg.gauge(
+                "moe.tokens_per_expert",
+                "token slots routed to an expert held here",
+                labels={"layer": layer, "expert": self.expert_offset + e}
+            ).set_function(lambda e=e: _tally(routed)[e])
+        reg.gauge(
+            "moe.routed_here_share",
+            "share of the router's slots that went to experts held here",
+            labels={"layer": layer}
+        ).set_function(lambda: _share(_tally(routed)))
+
+    def tally(self):
+        """Python ints [held + 1]: slots per held expert, then the
+        slots the router filled, since construction (one host read)."""
+        return _tally(self.routed)
+
+    def routed_here_share(self):
+        return _share(self.tally())
+
+    def count(self, tally):
+        """Add one call's ``tally`` (the second result of ``forward``)
+        to the buffer.  Apart from ``forward`` because a write made
+        inside a ``recompute`` region does not leave it: a block that is
+        recomputed returns the tally and counts it outside."""
+        with no_grad():
+            tally = tally._read()
+            hi, lo = self.routed._read()
+            lo = lo + tally
+            carry = lo // _CARRY
+            self.routed._write(jnp.stack([hi + carry, lo - carry * _CARRY]))
+            kept = self.calls._read()
+            n = kept[:, -1].max()
+            row = jnp.concatenate([tally, (n + 1)[None]])
+            self.calls._write(jax.lax.dynamic_update_slice(
+                kept, row[None], (n % kept.shape[0], jnp.zeros_like(n))))
+
+    def forward(self, x):
+        """(this chip's part of the layer's result, the call's tally)."""
+        return apply(
+            "sparse_moe",
+            functools.partial(sparse_moe, top_k=self.top_k,
+                              expert_offset=self.expert_offset,
+                              scaling=self.routed_scaling_factor),
+            x, self.gate.weight, self.w1, self.w3, self.w2,
+            bias=self.expert_bias)

@@ -15,3 +15,5 @@ from . import llama  # noqa: F401
 from .llama import LlamaConfig, LlamaModel, LlamaForCausalLM  # noqa: F401
 from . import generation  # noqa: F401
 from .generation import generate  # noqa: F401
+from . import lfm2  # noqa: F401
+from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM  # noqa: F401

@@ -6,8 +6,8 @@ lowerable framework primitives; attention routes to Pallas on TPU.
 from .activation import *  # noqa: F401,F403
 from .common import *  # noqa: F401,F403
 from .conv import (  # noqa: F401
-    conv1d, conv2d, conv3d, conv1d_transpose, conv2d_transpose,
-    conv3d_transpose,
+    causal_depthwise_conv1d, conv1d, conv2d, conv3d, conv1d_transpose,
+    conv2d_transpose, conv3d_transpose,
 )
 from .pooling import (  # noqa: F401
     max_unpool1d, max_unpool2d, max_unpool3d,

@@ -206,6 +206,26 @@ def _bwd_block_sizes(sq, sk):
     return min(512, sq), min(512, sk)
 
 
+_SCOPED_VMEM = 16 << 20     # what Mosaic gives a kernel unless told
+_TILE_VMEM = 8 << 20        # room for the k/v tiles, p, ds and dp
+
+
+def _bwd_vmem_limit(sqp, d, itemsize):
+    """``vmem_limit_bytes`` for the fused backward, or None where the
+    default holds it.  The kernel keeps the whole row of q, do, the
+    lane-replicated lse and delta (double-buffered inputs), dq (a
+    double-buffered output) and the dq accumulator resident, each
+    padded to 128 lanes: 4.5 KB a position at head_dim 64 in bfloat16,
+    so 4.7 MB at 1024 positions and 37.7 MB at 8192, which the chip's
+    compiler refuses under the 16 MB default (the v5e has 128 MiB)."""
+    def lanes(n):
+        return -(-n // 128) * 128
+    rows = sqp * (2 * 2 * lanes(d) * itemsize + 2 * 2 * lanes(_LANE) * 4
+                  + 2 * lanes(d) * 4 + lanes(d) * 4)
+    need = rows + _TILE_VMEM
+    return None if need <= _SCOPED_VMEM else need
+
+
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *refs, scale, causal, has_seg, sq, sk, bq, bk,
                       nq, nk):
@@ -370,6 +390,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
         ]
         args += [_pad_to(seg_q.astype(jnp.int32), 1, bq)[:, :, None],
                  _pad_to(seg_k.astype(jnp.int32), 1, bk)[:, None, :]]
+    limit = _bwd_vmem_limit(sqp, d, q.dtype.itemsize)
     dqh, dkh, dvh = pl.pallas_call(
         kernel,
         grid=(b, hq, nk, nq),
@@ -394,6 +415,9 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
         ],
         interpret=interpret,
         name="flash_attention_bwd",
+        **({} if limit is None else {
+            "compiler_params": pltpu.CompilerParams(
+                vmem_limit_bytes=limit)}),
     )(*args)
     if rep > 1:
         dkh = dkh.reshape(b, hk, rep, skp, d).sum(axis=2)
