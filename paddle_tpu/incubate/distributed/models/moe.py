@@ -173,64 +173,140 @@ class MoELayer(Layer):
 # ---------------------------------------------------------------------------
 # Dropless sparse block: one chip's share of an expert-parallel layer
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_sorted(flat, order, inverse, k):
-    """Slot-major rows in expert order: ``flat[order // k]``.  ``order``
-    is a permutation of the N*k slots and ``inverse`` its inverse, so
-    the transpose is a gather too (a slot's cotangent back at its own
-    place, the k slots of a token summed), not the scatter-add that
-    ``jnp.take`` transposes to: on the v5e at [65536, 2048] bfloat16
-    this gather and ``_unsort`` take 8.9 ms forward + backward, against
-    15.6 ms under plain autodiff (PERF.md, PR 28)."""
-    return jnp.take(flat, order // k, axis=0)
-
-
-def _take_sorted_fwd(flat, order, inverse, k):
-    return _take_sorted(flat, order, inverse, k), inverse
-
-
-def _take_sorted_bwd(k, inverse, g):
-    back = jnp.take(g, inverse, axis=0)
-    return (back.reshape(-1, k, g.shape[-1]).sum(axis=1).astype(g.dtype),
-            None, None)
-
-
-_take_sorted.defvjp(_take_sorted_fwd, _take_sorted_bwd)
-
-
-@jax.custom_vjp
-def _unsort(rows, order, inverse):
-    """Rows in expert order back in slot order: ``rows[inverse]``."""
-    return jnp.take(rows, inverse, axis=0)
-
-
-def _unsort_fwd(rows, order, inverse):
-    return _unsort(rows, order, inverse), order
-
-
-def _unsort_bwd(order, g):
-    return jnp.take(g, order, axis=0), None, None
-
-
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
-
 _CARRY = 1 << 30    # the tally's low word holds less than this
-_SLOTS_AT_A_TIME = 16384
+# sorted slot rows a chunk holds: gathered, multiplied, weighted and
+# handed back together, or skipped together (v5e, PR 30: PERF.md)
+_SLOTS_AT_A_TIME = 8192
 _CALLS_KEPT = 1024  # calls whose own tallies a block keeps, a row each
 # layer -> the per-call buffer of the block last built under that name:
 # the buffer, not the block, for the gauges' reason
 _calls_of = {}
 
 
+def _chunk(c, slots, order, weight, routed):
+    """Chunk ``c`` of the ``slots``-long cuts of the sorted slots: its
+    tokens, its combine weights and which of its rows hold a slot
+    routed here (all of them, except in the last chunk that runs)."""
+    lo = c * slots
+    slot = jax.lax.dynamic_slice(order, (lo,), (slots,))
+    return (slot // weight.shape[-1], jnp.take(weight.reshape(-1), slot),
+            lo + jnp.arange(slots) < routed)
+
+
+def _each_live_chunk(live, run, init):
+    """``run(c, carry)`` for every chunk ``c`` that ``live`` [chunks]
+    marks, in order; the others are skipped."""
+    def body(c, carry):
+        return jax.lax.cond(live[c], lambda v: run(c, v), lambda v: v, carry)
+
+    return jax.lax.fori_loop(0, live.shape[0], body, init)
+
+
+def _expert_rows(rows, w, w1, w3, w2, groups, mine):
+    """A chunk's token rows through their experts, group by group, each
+    scaled by its slot's combine weight: float32 [slots, H].  A row past
+    the last group is read by no product, and what the chip's kernel
+    leaves there is not a number to be multiplied, even by zero: the
+    selects take it out of the result, of the rows' gradient and of the
+    weights' before anything is scaled."""
+    with _scope.phase("dispatch"):
+        rows = jnp.where(mine[:, None], rows, 0)
+    with _scope.phase("expert_mlp"):
+        a = jax.lax.ragged_dot(rows, w1, groups)
+        b = jax.lax.ragged_dot(rows, w3, groups)
+        y = jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, groups)
+    with _scope.phase("combine"):
+        return w[:, None] * jnp.where(mine[:, None], y, 0).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _routed_rows(flat, weight, w1, w3, w2, order, inverse, sizes, routed,
+                 live):
+    """float32 [N, H]: per token, the sum over its slots routed here of
+    weight x experts(row).  ``order`` sorts the N * k slots by held
+    expert (absent experts last) and ``inverse`` is its inverse;
+    ``sizes`` [chunks, held] cuts the groups at chunk edges; the first
+    ``routed`` sorted slots are routed here and ``live`` [chunks] says
+    which chunks hold any of them.  The row work is done chunk by
+    chunk under ``live``: a chunk past the routed prefix is skipped,
+    forward and backward, so the gathers, products, selects and sums
+    follow the routed count and not the dropless worst case N * k.
+
+    A chunk's weighted rows are scatter-added to their tokens in one
+    float32 accumulator, and the gradient gathers the same rows back;
+    the chunk's products are recomputed in its own backward, so its
+    temporaries are never held for two chunks at once."""
+    return _routed_rows_fwd(flat, weight, w1, w3, w2, order, inverse, sizes,
+                            routed, live)[0]
+
+
+def _routed_rows_fwd(flat, weight, w1, w3, w2, order, inverse, sizes, routed,
+                     live):
+    slots = order.shape[0] // sizes.shape[0]
+
+    def run(c, out):
+        with _scope.phase("dispatch"):
+            at, w, mine = _chunk(c, slots, order, weight, routed)
+            rows = jnp.take(flat, at, axis=0)
+        y = _expert_rows(rows, w, w1, w3, w2, sizes[c], mine)
+        with _scope.phase("combine"):
+            return out.at[at].add(y, mode="promise_in_bounds")
+
+    out = _each_live_chunk(live, run, jnp.zeros(flat.shape, jnp.float32))
+    return out, (flat, weight, w1, w3, w2, order, inverse, sizes, routed,
+                 live)
+
+
+def _routed_rows_bwd(res, g):
+    flat, weight, w1, w3, w2, order, inverse, sizes, routed, live = res
+    slots = order.shape[0] // sizes.shape[0]
+
+    def run(c, grads):
+        d_flat, d_ws, d1, d3, d2 = grads
+        with _scope.phase("dispatch"):
+            at, w, mine = _chunk(c, slots, order, weight, routed)
+            rows = jnp.take(flat, at, axis=0)
+        with _scope.phase("combine"):
+            g_rows = jnp.take(g, at, axis=0)
+        _, back = jax.vjp(lambda *a: _expert_rows(*a, sizes[c], mine),
+                          rows, w, w1, w3, w2)
+        d_rows, d_w, e1, e3, e2 = back(g_rows)
+        with _scope.phase("dispatch"):
+            d_flat = d_flat.at[at].add(d_rows.astype(jnp.float32),
+                                       mode="promise_in_bounds")
+        with _scope.phase("combine"):
+            d_ws = d_ws.at[c].set(d_w)
+        with _scope.phase("expert_mlp"):
+            return d_flat, d_ws, d1 + e1, d3 + e3, d2 + e2
+
+    d_flat, d_ws, d1, d3, d2 = _each_live_chunk(
+        live, run,
+        (jnp.zeros(flat.shape, jnp.float32),
+         jnp.zeros((sizes.shape[0], slots), weight.dtype),
+         jnp.zeros_like(w1), jnp.zeros_like(w3), jnp.zeros_like(w2)))
+    # a slot's weight gradient back at its own place: ``inverse`` makes
+    # the transpose of the permutation a gather too
+    with _scope.phase("combine"):
+        d_weight = jnp.take(d_ws.reshape(-1), inverse).reshape(weight.shape)
+    return (d_flat.astype(flat.dtype), d_weight, d1, d3, d2,
+            None, None, None, None, None)
+
+
+_routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
+
+
 def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
                scaling=1.0):
-    """Values in, ``(out, tally)`` out; the math of ``SparseMoEBlock``.
+    """Values in, ``(out, tally, chunks)`` out; the math of
+    ``SparseMoEBlock``.
 
     ``x`` [..., H]; ``gate`` [H, E] over all E experts; ``w1``/``w3``
     [held, H, I] and ``w2`` [held, I, H] are the experts
     ``expert_offset .. expert_offset + held`` ; ``bias`` [E] float32.
     ``tally`` is int32 [held + 1]: the slots routed to each held expert
-    and, last, the slots the router filled (N * top_k)."""
+    and, last, the slots the router filled (N * top_k).  ``chunks`` is
+    int32 [2]: the chunks of sorted slots whose rows were worked on, and
+    the chunks there were."""
     held, k = w1.shape[0], top_k
     flat = x.reshape(-1, x.shape[-1])
     n = flat.shape[0]
@@ -250,43 +326,24 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
         order = jnp.argsort(key)
         inverse = jnp.argsort(order)
         counts = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        routed = jnp.arange(n * k) < counts.sum()
-        # a row past the last group is read by no product, and the
-        # select keeps what the products leave there out of the
-        # gradient of x
-        rows = jnp.where(routed[:, None],
-                         _take_sorted(flat, order, inverse, k), 0)
-    with _scope.phase("expert_mlp"):
-        # ``slots`` sorted rows at a time: all N * k would be needed only
-        # if every token chose all its experts here, so the products'
-        # temporaries are bounded by the chunk, not by that worst case;
-        # a chunk past the last group has empty groups and no work.  The
-        # chunk is recomputed in its own backward: its a, b and their
-        # product are never held for all chunks at once.
+        routed = counts.sum()
+        # all N * k sorted slots would be worked on only if every token
+        # chose all its experts here: the chunks, and the conditions
+        # the row work runs under
         chunks = -(-n * k // _SLOTS_AT_A_TIME)
         while n * k % chunks:
             chunks += 1
         slots = n * k // chunks
-        ends = jnp.cumsum(counts)
         lo = (jnp.arange(chunks) * slots)[:, None]
+        live = lo[:, 0] < routed
+        ends = jnp.cumsum(counts)
         sizes = jnp.clip(ends[None], lo, lo + slots) \
             - jnp.clip((ends - counts)[None], lo, lo + slots)
-
-        @jax.checkpoint
-        def experts(chunk):
-            x, groups = chunk
-            a = jax.lax.ragged_dot(x, w1, groups)
-            b = jax.lax.ragged_dot(x, w3, groups)
-            return jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, groups)
-
-        y = jax.lax.map(experts, (rows.reshape(chunks, slots, -1), sizes))
-        y = y.reshape(n * k, -1)
-    with _scope.phase("combine"):
-        y = _unsort(jnp.where(routed[:, None], y, 0), order, inverse)
-        w = jnp.where(here, weight, 0.0)
-        out = (w[..., None] * y.reshape(n, k, -1)).sum(axis=1)
+    out = _routed_rows(flat, weight, w1, w3, w2, order, inverse, sizes,
+                       routed, live)
     tally = jnp.concatenate([counts, jnp.full((1,), n * k, jnp.int32)])
-    return out.astype(x.dtype).reshape(x.shape), tally
+    ran = jnp.stack([live.sum(dtype=jnp.int32), jnp.int32(chunks)])
+    return out.astype(x.dtype).reshape(x.shape), tally, ran
 
 
 def _tally(routed):
@@ -297,6 +354,11 @@ def _tally(routed):
 def _share(tally):
     *here, filled = tally
     return sum(here) / filled if filled else 0.0
+
+
+def _run_share(chunks):
+    ran, there = (int(v) for v in jax.device_get(chunks._read()))
+    return ran / there if there else 0.0
 
 
 def routed_by_call():
@@ -335,7 +397,9 @@ class SparseMoEBlock(Layer):
     ``moe.routed_here_share{layer}`` gauges of the ``observability``
     registry read it only when a snapshot is taken.  ``routed_by_call()``
     is the same by call: the step also writes the call's own tally into
-    one row of a ring."""
+    one row of a ring.  ``moe.slot_rows_run_share{layer}`` is how much
+    of the dropless worst case's row work was done: the chunks of sorted
+    slots that ran over the chunks there were, since construction."""
 
     def __init__(self, hidden_size, intermediate_size, num_experts, top_k,
                  expert_offset=0, experts_held=None,
@@ -381,6 +445,9 @@ class SparseMoEBlock(Layer):
         self.register_buffer(
             "calls", Tensor(jnp.zeros((_CALLS_KEPT, held + 2), jnp.int32)),
             persistable=False)
+        # the chunks of sorted slots that ran, and the chunks there were
+        self.register_buffer("chunks", Tensor(jnp.zeros((2,), jnp.int32)),
+                             persistable=False)
         layer = name or self._full_name
         _calls_of[layer] = self.calls
         self._register_gauges(layer)
@@ -391,7 +458,7 @@ class SparseMoEBlock(Layer):
         # registry outlives the block and must not keep its weights
         # alive, and a snapshot taken after the model is gone still
         # reads what was routed
-        reg, routed = metrics.registry(), self.routed
+        reg, routed, chunks = metrics.registry(), self.routed, self.chunks
         for e in range(self.experts_held):
             reg.gauge(
                 "moe.tokens_per_expert",
@@ -403,6 +470,11 @@ class SparseMoEBlock(Layer):
             "share of the router's slots that went to experts held here",
             labels={"layer": layer}
         ).set_function(lambda: _share(_tally(routed)))
+        reg.gauge(
+            "moe.slot_rows_run_share",
+            "share of the sorted slot rows whose chunk was worked on",
+            labels={"layer": layer}
+        ).set_function(lambda: _run_share(chunks))
 
     def tally(self):
         """Python ints [held + 1]: slots per held expert, then the
@@ -412,12 +484,14 @@ class SparseMoEBlock(Layer):
     def routed_here_share(self):
         return _share(self.tally())
 
-    def count(self, tally):
-        """Add one call's ``tally`` (the second result of ``forward``)
-        to the buffer.  Apart from ``forward`` because a write made
-        inside a ``recompute`` region does not leave it: a block that is
-        recomputed returns the tally and counts it outside."""
+    def count(self, tally, chunks):
+        """Add one call's ``tally`` and ``chunks`` (the second and third
+        result of ``forward``) to the buffers.  Apart from ``forward``
+        because a write made inside a ``recompute`` region does not
+        leave it: a block that is recomputed returns them and counts
+        them outside."""
         with no_grad():
+            self.chunks._write(self.chunks._read() + chunks._read())
             tally = tally._read()
             hi, lo = self.routed._read()
             lo = lo + tally
@@ -430,7 +504,8 @@ class SparseMoEBlock(Layer):
                 kept, row[None], (n % kept.shape[0], jnp.zeros_like(n))))
 
     def forward(self, x):
-        """(this chip's part of the layer's result, the call's tally)."""
+        """(this chip's part of the layer's result, the call's tally,
+        the chunks it ran and had)."""
         return apply(
             "sparse_moe",
             functools.partial(sparse_moe, top_k=self.top_k,

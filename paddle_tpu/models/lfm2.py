@@ -210,7 +210,7 @@ class Lfm2DecoderLayer(Layer):
         x = x + (self.self_attn(a) if self.is_attention else self.conv(a))
         f = self.feed_forward(self.ffn_norm(x))
         if self.is_sparse:
-            return x + f[0], f[1]
+            return (x + f[0], *f[1:])
         return x + f
 
     def forward(self, x):
@@ -221,7 +221,7 @@ class Lfm2DecoderLayer(Layer):
             out = self._inner(x)
         if self.is_sparse:
             # outside the recomputed region, whose writes stay inside it
-            self.feed_forward.count(out[1])
+            self.feed_forward.count(*out[1:])
             return out[0]
         return out
 

@@ -162,15 +162,19 @@ def test_sparse_moe_grouped_products_compile(chip):
     """The dropless block's share of LFM2-24B-A2B (8 of 64 experts,
     2048 -> 1536, top-4) over 16384 tokens, forward and backward: the
     chip's compiler lowers ``ragged_dot`` to its own grouped-product
-    kernel, with no product of every token with every expert."""
+    kernel, with no product of every token with every expert; the row
+    work (gathers, selects, products, weighted sums) is inside
+    conditionals, a chunk of the sorted slots each, and nothing is as
+    large as all 65536 slot rows."""
     import functools
+    import re
 
     from paddle_tpu.incubate.distributed.models.moe import sparse_moe
     n, h, i, held, router = 16384, 2048, 1536, 8, 64
     fn = functools.partial(sparse_moe, top_k=4, expert_offset=0)
 
     def grads(x, gate, w1, w3, w2, bias):
-        return jax.grad(
+        return jax.value_and_grad(
             lambda *a: fn(*a, bias=bias)[0].astype(F32).sum(),
             argnums=(0, 1, 2, 3, 4))(x, gate, w1, w3, w2)
 
@@ -178,11 +182,19 @@ def test_sparse_moe_grouped_products_compile(chip):
         grads, chip((n, h), BF16), chip((h, router), BF16),
         chip((held, h, i), BF16), chip((held, h, i), BF16),
         chip((held, i, h), BF16), chip((router,), F32))
-    assert "ragged-dot" in compiled.as_text()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    # a forward and a backward loop, each chunk under its condition
+    assert len(re.findall(r" conditional\(", text)) >= 2
+    assert not re.search(rf"\[{4 * n},{h}\]|\[{n},4,{h}\]", text)
     # three products forward, the chunk's recompute, six backward, on
     # at most the 4 * n slots: far under one dense product per expert
     flops = compiled.cost_analysis()["flops"]
     assert flops < 0.5 * held * 9 * 2.0 * (4 * n) * h * i
+    # the parent of PR 30 (every gather, select and the weighted sum
+    # over all 65536 slot rows) needed 1,748,259,328 bytes of
+    # temporaries for the same call on the same described chip
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_748_259_328
 
 
 def test_fused_adamw_master_weights_compiles(chip):

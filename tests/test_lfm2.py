@@ -51,6 +51,23 @@ CFG = {
 }
 
 
+@pytest.fixture(autouse=True)
+def _leave_no_block_behind():
+    """A block built here is found by ``moe.routed_by_call()`` and by
+    the registry's ``moe.*`` gauges long after its test: other files'
+    tests, in the same process, read every layer's."""
+    from paddle_tpu.incubate.distributed.models import moe
+    from paddle_tpu.observability import metrics
+    reg = metrics.registry()
+    rings, gauges = dict(moe._calls_of), set(reg._metrics)
+    yield
+    moe._calls_of.clear()
+    moe._calls_of.update(rings)
+    for key in set(reg._metrics) - gauges:
+        if key[0].startswith("moe."):
+            del reg._metrics[key]
+
+
 def close(got, want, tol=TOL):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     assert got.shape == want.shape
@@ -173,7 +190,7 @@ def test_every_token_to_one_held_expert_and_none_dropped():
     bias[6] = 10.0               # every token's first choice, held here
     block, full = _block_and_leaves(4, HELD, bias)
     f = _tokens()
-    out, tally = block(paddle.to_tensor(f))
+    out, tally, _ = block(paddle.to_tensor(f))
     counts = np.asarray(tally._read())
     assert counts[2] == len(f) and counts[-1] == TOP_K * len(f)
     assert counts[:HELD].sum() >= len(f)
@@ -196,7 +213,7 @@ def test_the_shares_sum_to_the_uncut_layer(held):
     total, slots = 0.0, 0
     for offset in range(0, ROUTER, held):
         block, full = _block_and_leaves(offset, held, bias)
-        out, tally = block(paddle.to_tensor(f))
+        out, tally, _ = block(paddle.to_tensor(f))
         total = total + np.asarray(out._read(), np.float64)
         slots += int(np.asarray(tally._read())[:held].sum())
     assert slots == TOP_K * len(f)          # every slot on one chip
@@ -206,41 +223,130 @@ def test_the_shares_sum_to_the_uncut_layer(held):
     close(total, want)
 
 
-@pytest.mark.parametrize("slots_at_a_time", [None, 16])
+def _one_held_choice():
+    """Every token's first choice is held expert 6 and its second an
+    absent one: exactly one slot a token is routed here."""
+    bias = np.full(ROUTER, -10.0, np.float32)
+    bias[[6, 12]] = 10.0, 5.0
+    return bias
+
+
+def _absent_choices():
+    bias = np.zeros(ROUTER, np.float32)
+    bias[[0, 13]] = 10.0        # neither among experts 4..8
+    return bias
+
+
+# chunk size (None: the module's), experts held, the selection bias
+# (None: a random one), chunks that run of the chunks there are
+ROUTINGS = {
+    "one_chunk": (None, HELD, None, (1, 1)),
+    "groups_cut_at_chunk_edges": (16, HELD, None, None),
+    "nothing_routed_here": (16, HELD, _absent_choices, (0, 5)),
+    "routed_ends_on_a_chunk_edge": (8, HELD, _one_held_choice, (5, 10)),
+    "partial_last_chunk": (16, HELD, _one_held_choice, (3, 5)),
+    "every_slot_routed_here": (16, ROUTER, None, (5, 5)),
+    "one_slot_a_chunk": (1, HELD, _one_held_choice, (40, 80)),
+    # the same, on a kernel that leaves what it likes past the last group
+    "garbage_past_the_last_group": (16, HELD, _one_held_choice, (3, 5)),
+}
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
 def test_block_gradients_reach_router_and_experts_not_the_bias(
-        slots_at_a_time, monkeypatch):
-    """With the sorted slots taken 16 at a time too (80 slots: five
-    chunks, groups cut at chunk edges, chunks past the last group
-    empty), as the products take 16384 at a time at the real size."""
+        routing, monkeypatch):
+    """40 tokens, 80 sorted slots, taken ``slots_at_a_time`` at a time
+    as the real size takes thousands: the chunks past the slots routed
+    here are skipped, the last that runs may be partly filled, and the
+    result and every gradient are the reference's whatever the cut."""
     from paddle_tpu.incubate.distributed.models import moe
+    slots_at_a_time, held_n, bias, chunks = ROUTINGS[routing]
     if slots_at_a_time:
         monkeypatch.setattr(moe, "_SLOTS_AT_A_TIME", slots_at_a_time)
-    bias = 0.3 * np.random.default_rng(9).standard_normal(ROUTER).astype("f4")
-    block, full = _block_and_leaves(4, HELD, bias)
+    if routing.startswith("garbage"):
+        # the CPU's ragged_dot writes zeros past the last group; the
+        # chip's kernel writes nothing there, and what is left is not
+        # always a number (my chip run, PR 30: NaN gradients)
+        plain = jax.lax.ragged_dot
+
+        def ragged_dot(lhs, rhs, group_sizes):
+            out = plain(lhs, rhs, group_sizes)
+            past = jnp.arange(out.shape[0]) >= group_sizes.sum()
+            return jnp.where(past[:, None], jnp.nan, out)
+
+        monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
+    bias = bias() if bias else \
+        0.3 * np.random.default_rng(9).standard_normal(ROUTER).astype("f4")
+    offset = 4 if held_n == HELD else 0
+    block, full = _block_and_leaves(offset, held_n, bias)
     f = _tokens()
     x = paddle.to_tensor(f)
     x.stop_gradient = False
-    out, _ = block(x)
+    out, tally, ran = block(x)
     (out * out).sum().backward()
-    held = {k: (w[4:4 + HELD] if k != "moe.router" else w)
+    held = {k: (w[offset:offset + held_n] if k != "moe.router" else w)
             for k, w in full.items()}
 
     def ref(fv, w):
-        y = R.sparse_ffn(fv, w, jnp.asarray(bias), TOP_K, 1.0, 4, C.Matmul())
-        return jnp.sum(y * y)
+        return R.sparse_ffn(fv, w, jnp.asarray(bias), TOP_K, 1.0, offset,
+                            C.Matmul())
 
     with jax.default_matmul_precision("highest"):
-        want_x, want_w = jax.grad(ref, argnums=(0, 1))(jnp.asarray(f), held)
-    close(x.grad._read(), want_x)
-    close(block.gate.weight.grad._read(), want_w["moe.router"])
-    for name in ("w1", "w3", "w2"):
-        close(getattr(block, name).grad._read(), want_w[f"moe.{name}"])
+        want = ref(jnp.asarray(f), held)
+        want_x, want_w = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2),
+                                  argnums=(0, 1))(jnp.asarray(f), held)
+    grads = {"x": x.grad._read(), "moe.router": block.gate.weight.grad._read(),
+             **{f"moe.{n}": getattr(block, n).grad._read()
+                for n in ("w1", "w3", "w2")}}
+    close(out._read(), want)
+    for name, got in grads.items():
+        close(got, want_x if name == "x" else want_w[name])
     assert block.expert_bias.stop_gradient
+    # the chunks that ran are those that hold a slot routed here
+    routed = int(np.asarray(tally._read())[:-1].sum())
+    ran, there = (int(v) for v in np.asarray(ran._read()))
+    size = TOP_K * len(f) // there
+    assert ran == -(-routed // size)
+    if chunks:
+        assert (ran, there) == chunks
+    if not routed:
+        for got in (out._read(), *grads.values()):
+            assert np.isfinite(np.asarray(got)).all()
+            assert not np.asarray(got).any()
 
 
-def test_one_compiled_step_linearises_as_it_records_and_feeds_the_tally():
-    from paddle_tpu import amp
+@pytest.mark.parametrize("pull", [-10.0, 0.0, 0.6])
+def test_run_share_is_the_chunks_that_hold_a_slot_routed_here(
+        pull, monkeypatch):
+    """``moe.slot_rows_run_share`` is ceil(R / S) * S of the N * k
+    sorted slots, R from the call's own tally, at three routed shares
+    (the held experts pushed away, left alone, pulled)."""
+    from paddle_tpu.incubate.distributed.models import moe
     from paddle_tpu.observability import metrics
+    monkeypatch.setattr(moe, "_SLOTS_AT_A_TIME", 16)
+    bias = 0.3 * np.random.default_rng(9).standard_normal(ROUTER).astype("f4")
+    bias[4:4 + HELD] += pull
+    block, _ = _block_and_leaves(4, HELD, bias)
+    f = _tokens()
+    _, tally, ran = block(paddle.to_tensor(f))
+    block.count(tally, ran)
+    routed = int(np.asarray(tally._read())[:-1].sum())
+    assert (routed == 0) == (pull == -10.0)
+    got = metrics.snapshot()["moe"]["slot_rows_run_share"]["layer=share_4"]
+    assert got == pytest.approx(-(-routed // 16) * 16 / (TOP_K * len(f)))
+    block.count(tally, ran)     # a second call: the same share of twice
+    assert metrics.snapshot()["moe"]["slot_rows_run_share"][
+        "layer=share_4"] == pytest.approx(got)
+    assert [int(v) for v in block.chunks._read()] == [
+        2 * -(-routed // 16), 2 * 5]
+
+
+def test_one_compiled_step_linearises_as_it_records_and_feeds_the_tally(
+        monkeypatch):
+    from paddle_tpu import amp
+    from paddle_tpu.incubate.distributed.models import moe
+    from paddle_tpu.observability import metrics
+    monkeypatch.setattr(moe, "_SLOTS_AT_A_TIME", 16)    # 6 chunks a call
     model, _ = seeded(True)
     opt = paddle.optimizer.AdamW(learning_rate=1e-3,
                                  parameters=model.parameters())
@@ -271,8 +377,15 @@ def test_one_compiled_step_linearises_as_it_records_and_feeds_the_tally():
         calls = by_call[layer]
         assert sorted(calls) == [1, 2, 3]
         assert [sum(c) for c in zip(*calls.values())] == [*here, filled]
+        # and the chunks that ran, call by call: the recomputed block
+        # counts each call once
+        ran = sum(-(-sum(c[:-1]) // 16) for c in calls.values())
+        assert [int(v) for v in block.chunks._read()] == [ran, 3 * 6]
+        assert 0 < ran < 3 * 6
     want = {layer: (block.tally()[:HELD], block.routed_here_share())
             for layer, block in model.sparse_blocks().items()}
+    run_share = {layer: int(block.chunks._read()[0]) / 18
+                 for layer, block in model.sparse_blocks().items()}
     # the registry reads the tally's buffer, not the block: a snapshot
     # taken when the model is gone still says what was routed
     del model, opt, train_step, exe, block
@@ -286,6 +399,8 @@ def test_one_compiled_step_linearises_as_it_records_and_feeds_the_tally():
         assert [sum(c) for c in zip(*by_call[layer].values())][:HELD] == held
         assert snap["tokens_per_expert"][
             f"expert={CFG['expert_offset']},layer={layer}"] == held[0]
+        assert snap["slot_rows_run_share"][f"layer={layer}"] == \
+            pytest.approx(run_share[layer])
 
 
 def test_tally_carries_past_a_32_bit_word_and_the_ring_keeps_the_last_calls(
@@ -296,7 +411,8 @@ def test_tally_carries_past_a_32_bit_word_and_the_ring_keeps_the_last_calls(
     big = (1 << 30) - 5
     for n in range(1, 7):
         block.count(paddle.to_tensor(
-            jnp.full((HELD + 1,), big - n, jnp.int32)))
+            jnp.full((HELD + 1,), big - n, jnp.int32)),
+            paddle.to_tensor(jnp.asarray([1, 2], jnp.int32)))
     assert block.tally() == [6 * big - 21] * (HELD + 1)
     assert moe.routed_by_call()["share_4"] == {
         n: [big - n] * (HELD + 1) for n in (3, 4, 5, 6)}
