@@ -224,87 +224,6 @@ def measure_fused_optimizer(n, r1=8, r2=48):
     return {"ms": round(per_op * 1e3, 4), "elements": n}
 
 
-def measure_decode_dispatches(hidden=32, heads=4, vocab=96,
-                              max_len=64, page_size=8, batch=2):
-    """Per-layer op-dispatch count of ONE serving decode step, unfused
-    vs megakernel (ISSUE 18) — counted EXACTLY by the profiler op-hook
-    (``core.dispatch._profile_hook``, the ISSUE-12 instrumentation
-    point) over an eager replay of the engine's step body at L=1 and
-    L=2 tiny-GPT configs; the difference isolates the per-layer chain
-    from the embedding/epilogue constants.  This is a COUNT, not a
-    timing, so the tiny config is exact for any model depth/width: the
-    number of dispatches per decode layer is shape-independent.  The
-    megakernel target is ≤4/layer (ingress, paged attention, one
-    reshape, egress) vs ~12 unfused."""
-    import paddle_tpu as pp
-    from paddle_tpu.core import dispatch as _dispatch
-    from paddle_tpu.core.tensor import Tensor
-    from paddle_tpu.models.generation import (_gpt_decode,
-                                              _gpt_decode_fused,
-                                              _zero_pool,
-                                              guarded_argmax,
-                                              paged_slot_attention)
-    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
-
-    def count_ops(layers):
-        pp.seed(0)
-        cfg = GPTConfig(vocab_size=vocab, hidden_size=hidden,
-                        num_layers=layers, num_heads=heads,
-                        max_seq_len=max_len, dropout=0.0)
-        model = GPTForCausalLM(cfg)
-        model.eval()
-        np_per = max_len // page_size
-        bt = np.arange(1, 1 + batch * np_per, dtype=np.int32).reshape(
-            batch, np_per)
-        shape = (heads, 1 + batch * np_per, page_size,
-                 hidden // heads)
-        tok = Tensor(np.zeros((batch, 1), np.int32))
-        pos = Tensor(np.zeros((batch,), np.int32))
-        poison = Tensor(np.zeros((batch,), np.float32))
-        btt = Tensor(bt)
-
-        def run(fn):
-            caches = [Tensor(a) for a in _zero_pool(shape, 2 * layers)]
-            n = [0]
-
-            def hook(name, t0, t1):
-                n[0] += 1
-
-            _dispatch._profile_hook = hook
-            try:
-                with pp.no_grad():
-                    fn(caches)
-            finally:
-                _dispatch._profile_hook = None
-            return n[0]
-
-        def unfused(caches):
-            def attend(q, k, v, kc, vc, p, ks=None, vs=None):
-                return paged_slot_attention(q, k, v, kc, vc, p, btt)
-            lg, _ = _gpt_decode(model, tok, pos, caches, attend=attend)
-            guarded_argmax(lg, poison)
-
-        def fused(caches):
-            _gpt_decode_fused(model, tok, pos, btt, caches, poison)
-
-        return run(unfused), run(fused)
-
-    u1, m1 = count_ops(1)
-    u2, m2 = count_ops(2)
-    out = {
-        "method": "op-hook dispatch count of one eager decode step "
-                  "(L=2 minus L=1 isolates the per-layer chain)",
-        "unfused_per_layer": u2 - u1,
-        "megakernel_per_layer": m2 - m1,
-        "unfused_other": 2 * u1 - u2,       # embedding + lm head/argmax
-        "megakernel_other": 2 * m1 - m2,
-    }
-    _log(f"decode dispatches/layer: unfused {out['unfused_per_layer']}"
-         f" -> megakernel {out['megakernel_per_layer']} (constants "
-         f"{out['unfused_other']} -> {out['megakernel_other']})")
-    return out
-
-
 def _scanned_glue(rows, hidden, reps, bwd, fused):
     """One jit program running ``reps`` residual-add+layer-norm glue
     chains (optionally + input/weight/bias grads), index-perturbed like
@@ -372,7 +291,7 @@ def measure_train_glue_dispatches(hidden=32, heads=4, vocab=96, seq=16,
                                   batch=2):
     """Per-layer TRAINING-forward dispatch count of the GPT block
     chain, glue fusion off vs on (ISSUE 19) — counted exactly by the
-    profiler op-hook at L=1/L=2 like ``measure_decode_dispatches``; the
+    profiler op-hook at L=1 and L=2 tiny-GPT configs; the
     difference isolates the per-layer chain from embedding/final-norm
     constants. Forward-only by construction: the backward replays
     inside ``jax.vjp`` and never re-enters the dispatcher, so its cost
@@ -590,10 +509,6 @@ def kernel_breakdown(batch=8, seq=1024, hidden=768, heads=12, layers=12,
         "layernorm": dict(measure_norm(batch * seq, hidden),
                           shape=[batch * seq, hidden]),
         "fused_optimizer": measure_fused_optimizer(n_params),
-        # decode megakernel (ISSUE 18): exact dispatch counts per
-        # decode layer, unfused vs fused — the serving-latency lever
-        # the serving_bench launch_share column prices out
-        "decode_dispatches": measure_decode_dispatches(),
         # training glue share (ISSUE 19): norm/residual dispatch count
         # per TRAIN layer (fused vs unfused) plus the fused-vs-unfused
         # glue chain ms, fwd and bwd — the per-step glue budget the

@@ -311,10 +311,8 @@ def measure():
         row["roofline_x"] = round(row["ms_per_token"] / rl, 1)
         row["launch_ms"] = round(lm, 3)
         row["launch_share"] = round(lm / row["ms_per_token"], 3)
-        # host dispatches amortized per generated token (ISSUE 18:
-        # the decode megakernel's target metric — fewer fused kernels
-        # per compiled step shrink launch_share, this column tracks
-        # the program-boundary count the windows amortize)
+        # host dispatches amortized per generated token: the
+        # program-boundary count the windows amortize
         row["dispatches_per_token"] = round(n_dispatch / new_tokens, 3)
         rows[name] = row
         print(f"{name}: {row['ms_per_token']} ms/token "
@@ -384,12 +382,6 @@ def measure():
     # (engine caches, decode windows, TP wrappers); the regression
     # sentinel judges PDT* leaves lower-is-better
     rows["analysis"] = {"findings": _analysis.audit_counts()}
-    # decode megakernel calibration (ISSUE 18): exact per-layer
-    # dispatch counts, unfused vs fused — a count, not a timing, so it
-    # rides every serving measurement regardless of device
-    import calibrate as _calibrate
-    rows["_calibration"] = {
-        "decode_dispatches": _calibrate.measure_decode_dispatches()}
     return rows
 
 
@@ -1416,12 +1408,6 @@ FILES = ["benchmarks/serving_bench.py",
          "paddle_tpu/ops/pallas/paged_attention.py",
          "paddle_tpu/ops/pallas/flash_attention.py",
          "paddle_tpu/ops/pallas/quant_matmul.py",
-         # decode megakernel (ISSUE 18): the fused per-layer decode
-         # chain every paged/continuous row will run once the
-         # serving_megakernel flag defaults on — kernel edits must
-         # re-measure the serving rows
-         "paddle_tpu/ops/pallas/fused_decode_qkv.py",
-         "paddle_tpu/ops/pallas/fused_decode_mlp.py",
          "paddle_tpu/quantization/__init__.py",
          # the observability runtime rides the serving hot loop (event
          # emission + timeline observes per dispatch/token): edits to

@@ -16,8 +16,7 @@ from __future__ import annotations
 import ast
 import copy
 
-from ..core.state import MEGAKERNEL_OFF_SPELLINGS, \
-    PREFIX_CACHE_OFF_SPELLINGS
+from ..core.state import PREFIX_CACHE_OFF_SPELLINGS
 from .registry import Severity, decorator_name, register
 
 _HOST_SYNC_METHODS = {"numpy", "item", "tolist"}
@@ -1342,95 +1341,6 @@ def check_unrouted_replica_pool(fndef, ctx):
                 "requests instead of re-serving them bitwise from "
                 "survivors under a coded PDT-E024 record) — wrap "
                 "the pool in FleetRouter(replicas=[...])")
-
-# constant values that off-spell the engine's decode megakernel — the
-# string spellings are the engine's strict case-insensitive parse set
-# (an unparseable spelling raises in the ctor, so the linter only ever
-# sees these or on-spellings)
-_MEGAKERNEL_OFF = (False, 0) + MEGAKERNEL_OFF_SPELLINGS
-
-
-def _megakernel_off_or_absent(call) -> bool:
-    for kw in call.keywords:
-        if kw.arg == "megakernel":
-            v = kw.value
-            if not isinstance(v, ast.Constant):
-                return False      # computed value: can't prove it's off
-            val = v.value
-            if val is None:       # None defers to the flag default: off
-                return True
-            if isinstance(val, str):
-                val = val.lower()
-            return val in _MEGAKERNEL_OFF
-    return True                   # absent: serving_megakernel defaults off
-
-
-@register(
-    "PDT120", "unfused-decode-serving", Severity.NOTE, "ast",
-    scope="eager",
-    example="""
-import paddle_tpu as paddle
-from paddle_tpu.inference import ContinuousBatchingEngine
-
-def serve(model, prompts):
-    eng = ContinuousBatchingEngine(model, max_slots=8, max_queue=64,
-                                   queue_policy="reject",
-                                   default_deadline_ms=500.0,
-                                   slo="ttft_p95_ms=500,goodput=0.99",
-                                   watchdog_ms=2000.0)
-    for p in prompts:
-        eng.add_request(p, 32)
-    return eng.run()
-""",
-    near_miss="""
-import paddle_tpu as paddle
-from paddle_tpu.inference import ContinuousBatchingEngine
-
-def serve(model, prompts):
-    eng = ContinuousBatchingEngine(model, max_slots=8, max_queue=64,
-                                   queue_policy="reject",
-                                   default_deadline_ms=500.0,
-                                   slo="ttft_p95_ms=500,goodput=0.99",
-                                   watchdog_ms=2000.0,
-                                   megakernel="on")
-    for p in prompts:
-        eng.add_request(p, 32)
-    return eng.run()
-""")
-def check_unfused_decode_serving(fndef, ctx):
-    """A serving engine constructed WITH overload knobs
-    (``max_queue``/``queue_policy``/``default_deadline_ms`` — this
-    engine clearly expects sustained traffic) but with the decode
-    megakernel absent or off-spelled.  Sustained serving is
-    decode-bound, and at small per-step batches the unfused decode
-    chain (~13 dispatches per layer) is launch-dominated: the chip
-    idles between kernels while the host feeds it one small op at a
-    time.  The fused path (``megakernel="on"`` / the
-    ``serving_megakernel`` flag) runs the same math as ~3 fused Pallas
-    kernels per layer plus one sampling epilogue — token streams are
-    bitwise-identical either way (tests/test_decode_megakernel.py
-    gates this), only dispatches-per-token moves (13 -> 4 per layer,
-    the serving-bench ``dispatches_per_token`` column).  Note-level
-    advice, not an error: the flag defaults off until the TPU round
-    re-measures, and a deliberate off-spelling on a compile-budget-
-    sensitive rig is legitimate."""
-    for node in _walk_fn(fndef):
-        if not isinstance(node, ast.Call) \
-                or (_dotted(node.func) or "").split(".")[-1] \
-                != "ContinuousBatchingEngine":
-            continue
-        kws = {kw.arg for kw in node.keywords if kw.arg}
-        if kws & _ENGINE_OVERLOAD_KWARGS \
-                and _megakernel_off_or_absent(node):
-            yield node, (
-                "engine has overload knobs (max_queue/queue_policy/"
-                "default_deadline_ms) but decodes unfused: sustained "
-                "traffic is decode-bound and the ~13-dispatch-per-"
-                "layer chain is launch-dominated at small batches — "
-                "pass megakernel=\"on\" (or the serving_megakernel "
-                "flag) for the fused ~3-kernel decode path; token "
-                "streams are bitwise-identical, only "
-                "dispatches-per-token moves")
 
 
 # batch-staging calls a custom train loop pays synchronously per step:

@@ -15,12 +15,11 @@ def pvary(xs, axes):
     return lax.pcast(xs, axes, to="varying")
 
 
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=True):
+def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
     """``jax.shard_map`` with ``axis_names`` given as any iterable (the
     manual axes; the rest are left to GSPMD)."""
     kw = {}
     if axis_names is not None:
         kw["axis_names"] = frozenset(axis_names)
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check_vma, **kw)
+                         out_specs=out_specs, **kw)

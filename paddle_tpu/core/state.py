@@ -138,24 +138,6 @@ for _env_name in ("PDTPU_SERVING_KV_QUANT", "PDTPU_KV_QUANT"):
         elif _env_kvq.lower() in KV_QUANT_OFF_SPELLINGS:
             _FLAGS["serving_kv_quant"] = False
 del _env_name, _env_kvq
-define_flag("serving_megakernel", False,
-            "fused decode megakernel path for the serving engine "
-            "(ISSUE 18, ops/pallas/fused_decode_qkv.py + "
-            "fused_decode_mlp.py): each decode layer runs as ~3 fused "
-            "dispatches (norm+QKV+RoPE+paged-KV-append, attention, "
-            "out-proj+residual+MLP+residual) plus one guarded-argmax "
-            "sampling epilogue riding the final norm+lm_head, instead "
-            "of ~10 unfused ops. Token streams are bitwise-identical "
-            "either way (the megakernel replays the exact unfused op "
-            "order); only dispatches-per-token moves. Default off "
-            "until the TPU round lands; engine kwarg megakernel "
-            "overrides per instance. PDT120 notes overload-tuned "
-            "engines built with the megakernel off-spelled.")
-# Spellings for the engine's megakernel kwarg — same convention as
-# kv_quant (strict parse: unrecognized spellings raise rather than
-# silently picking a path, since dispatch count is a measured claim).
-MEGAKERNEL_OFF_SPELLINGS = KV_QUANT_OFF_SPELLINGS
-MEGAKERNEL_ON_SPELLINGS = KV_QUANT_ON_SPELLINGS
 define_flag("serving_spec_decode", False,
             "speculative decoding for the serving engine (ISSUE 9, "
             "inference/speculative.py): per decode step each slot "
@@ -342,14 +324,14 @@ define_flag("train_glue_fusion", False,
             "standalone Pallas LN measured as a fusion BARRIER "
             "in-context (+6 ms/step on the GPT-124M bench, see "
             "nn/functional/norm.py) — the fused glue path ships dark "
-            "until the TPU round prices it end-to-end, the "
-            "serving_megakernel precedent. Numerics differ from the "
-            "unfused chain by norm-formula ulps (two-pass variance vs "
+            "until the TPU round prices it end-to-end. Numerics "
+            "differ from the unfused chain by norm-formula ulps "
+            "(two-pass variance vs "
             "E[x^2]-E[x]^2), so this is an A/B knob, not a "
             "bitwise-neutral toggle.")
 # Spellings for the glue-fusion knob (same strict convention as
-# kv_quant/megakernel: dispatch count is a measured claim, so an
-# unrecognized spelling must raise, never silently pick a path).
+# kv_quant: dispatch count is a measured claim, so an unrecognized
+# spelling must raise, never silently pick a path).
 GLUE_FUSION_OFF_SPELLINGS = KV_QUANT_OFF_SPELLINGS
 GLUE_FUSION_ON_SPELLINGS = KV_QUANT_ON_SPELLINGS
 define_flag("train_remat", "",

@@ -400,11 +400,7 @@ class ContinuousBatchingEngine:
     (``serving_prefix_cache`` flag; ``False``/``'off'`` restores
     uncached admission bitwise), ``kv_quant`` stores KV pages int8
     with in-kernel dequant (``serving_kv_quant`` flag; default off =
-    bitwise fp path), ``megakernel`` runs the decode step as ~3 fused
-    Pallas dispatches per layer plus a fused sampling epilogue
-    (``serving_megakernel`` flag, ISSUE 18; token streams are bitwise
-    vs off, and an off-spelling restores today's decode programs
-    exactly), ``spec_decode``/``spec_k``/``spec_proposer``/
+    bitwise fp path), ``spec_decode``/``spec_k``/``spec_proposer``/
     ``spec_temperature``/``spec_rejection_sampling`` drive speculative
     decoding (``serving_spec_*`` flags; greedy spec is bitwise vs
     off), ``slo`` arms declarative latency/goodput objectives over
@@ -426,8 +422,7 @@ class ContinuousBatchingEngine:
                  prefill_chunk=64, q_block=8, pages_per_block=None,
                  max_queue=None, queue_policy=None,
                  default_deadline_ms=None, dispatch_retries=None,
-                 prefix_cache=None, kv_quant=None, megakernel=None,
-                 spec_decode=None,
+                 prefix_cache=None, kv_quant=None, spec_decode=None,
                  spec_k=None, spec_proposer=None, spec_temperature=None,
                  spec_rejection_sampling=None, spec_seed=0, clock=None,
                  mesh=None, tp_axis=None, slo=None, watchdog_ms=None):
@@ -571,27 +566,9 @@ class ContinuousBatchingEngine:
                     f"{_state.KV_QUANT_ON_SPELLINGS} or "
                     f"{_state.KV_QUANT_OFF_SPELLINGS}")
         self.kv_quant = bool(kq)
-        mk = (_state.get_flag("serving_megakernel")
-              if megakernel is None else megakernel)
-        if isinstance(mk, str):
-            # same strict-spelling discipline as kv_quant: the
-            # megakernel swaps the entire compiled decode program, so
-            # a typo must not silently change which kernels serve
-            # tokens (ISSUE 18; PDT120 flags overload-tuned engines
-            # built with an off-spelling)
-            if mk.lower() in _state.MEGAKERNEL_ON_SPELLINGS:
-                mk = True
-            elif mk.lower() in _state.MEGAKERNEL_OFF_SPELLINGS:
-                mk = False
-            else:
-                raise ValueError(
-                    f"megakernel={mk!r}: expected one of "
-                    f"{_state.MEGAKERNEL_ON_SPELLINGS} or "
-                    f"{_state.MEGAKERNEL_OFF_SPELLINGS}")
-        self.megakernel = bool(mk)
         from ..ops import pallas as _pallas
         if not _pallas.use_interpret():
-            for name in ("kv_quant", "megakernel"):
+            for name in _pallas.TPU_REFUSED:
                 if getattr(self, name):
                     raise UnimplementedError(
                         f"ContinuousBatchingEngine({name}=True) on a "
@@ -1792,8 +1769,7 @@ class ContinuousBatchingEngine:
                       tuple(d.id for d in self._jmesh.devices.flat))
         return (self.max_slots, self.page_size, self.np_per_seq,
                 self.total_pages, self.token_budget, self.q_block,
-                self.pages_per_block, self.kv_quant, self.megakernel,
-                tp_key)
+                self.pages_per_block, self.kv_quant, tp_key)
 
     def _audit_program(self, name, fn, args, donated=()):
         """Whole-program audit (analysis/program.py) of a raw-jitted
@@ -2280,35 +2256,17 @@ class ContinuousBatchingEngine:
             model, decode = self.model, self._decode
             ppb = self.pages_per_block
 
-            if self.megakernel:
-                # decode megakernel (ISSUE 18): ~3 fused Pallas
-                # dispatches per layer plus the fused sampling
-                # epilogue — the step returns the guarded greedy pick
-                # alongside the logits, so windows and the bootstrap
-                # never re-derive it
-                from ..models.generation import _decode_fused_fn
-                decode_fused = _decode_fused_fn(model)
-
-                def step(tok, pos, bt, poison, *cs):
-                    import paddle_tpu as pp
-                    with pp.no_grad():
-                        logits, nxt, bad, new = decode_fused(
-                            model, tok, pos, bt, list(cs), poison,
-                            pages_per_block=ppb)
-                    return (logits, nxt, bad) + tuple(new)
-            else:
-                def step(tok, pos, bt, *cs):
-                    import paddle_tpu as pp
-                    with pp.no_grad():
-                        def attend(q, k, v, kc, vc, p, ks=None,
-                                   vs=None):
-                            return paged_slot_attention(
-                                q, k, v, kc, vc, p, bt,
-                                pages_per_block=ppb, k_scales=ks,
-                                v_scales=vs)
-                        logits, new = decode(model, tok, pos,
-                                             list(cs), attend=attend)
-                    return (logits,) + tuple(new)
+            def step(tok, pos, bt, *cs):
+                import paddle_tpu as pp
+                with pp.no_grad():
+                    def attend(q, k, v, kc, vc, p, ks=None, vs=None):
+                        return paged_slot_attention(
+                            q, k, v, kc, vc, p, bt,
+                            pages_per_block=ppb, k_scales=ks,
+                            v_scales=vs)
+                    logits, new = decode(model, tok, pos, list(cs),
+                                         attend=attend)
+                return (logits,) + tuple(new)
 
             self._step_fn = jit_mod.to_static(step)
             self._program_cache()[key] = self._step_fn
@@ -2373,30 +2331,16 @@ class ContinuousBatchingEngine:
             # first decode dispatch compiles the scalar step; its logits
             # advance every live slot by one token (host argmax; the
             # guard check runs host-side on the same poisoned values
-            # the windowed path applies in-graph).  The megakernel step
-            # takes the poison lane as an input and returns the guarded
-            # pick from its fused sampling epilogue — same bytes, same
-            # tie-breaking (first max index), zero host argmax.
-            if self.megakernel:
-                poison = self._guard.poison(rids)
-                res = self._dispatch("decode", lambda: step_fn(
-                    Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(pos)),
-                    Tensor(jnp.asarray(self._bt)),
-                    Tensor(jnp.asarray(poison)), *self._caches))
-                with _tracing.span("engine.readback", op="decode"):
-                    nxt = np.asarray(res[1]._read()).astype(np.int32)
-                    bad = np.asarray(res[2]._read()).astype(bool)
-                self._caches = list(res[3:])
-            else:
-                res = self._dispatch("decode", lambda: step_fn(
-                    Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(pos)),
-                    Tensor(jnp.asarray(self._bt)), *self._caches))
-                with _tracing.span("engine.readback", op="decode"):
-                    lg = np.asarray(res[0]._read()).astype(np.float32)
-                self._caches = list(res[1:])
-                lg = lg + self._guard.poison(rids)[:, None]
-                bad = ~np.isfinite(lg).all(-1)
-                nxt = np.where(bad, 0, lg.argmax(-1)).astype(np.int32)
+            # the windowed path applies in-graph)
+            res = self._dispatch("decode", lambda: step_fn(
+                Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(pos)),
+                Tensor(jnp.asarray(self._bt)), *self._caches))
+            with _tracing.span("engine.readback", op="decode"):
+                lg = np.asarray(res[0]._read()).astype(np.float32)
+            self._caches = list(res[1:])
+            lg = lg + self._guard.poison(rids)[:, None]
+            bad = ~np.isfinite(lg).all(-1)
+            nxt = np.where(bad, 0, lg.argmax(-1)).astype(np.int32)
             self._stats["decode_dispatches"] += 1
             accepted = 0
             for b, s in enumerate(self._slots):
@@ -2422,9 +2366,7 @@ class ContinuousBatchingEngine:
             "_slot_window_cache", {})
         runner = runners.get(K)
         if runner is None:
-            make = (_make_slot_window_mk if self.megakernel
-                    else _make_slot_window)
-            runner = make(self._decode_exe, K)
+            runner = _make_slot_window(self._decode_exe, K)
             runners[K] = runner
         return runner
 
@@ -2520,8 +2462,7 @@ class ContinuousBatchingEngine:
             from ..models.generation import make_tp_window
             runner = make_tp_window(self.model, self._tpp, self._jmesh,
                                     self.pages_per_block,
-                                    len(self._caches), K,
-                                    megakernel=self.megakernel)
+                                    len(self._caches), K)
             cache[key] = runner
         return runner
 
@@ -2601,54 +2542,6 @@ def _make_slot_window(exe, K):
             new_cstate = list(outs[1 + n_caches:
                                    1 + n_caches + len(carry_idx)])
             nxt_raw, row_bad = guarded_argmax.raw(lg, poison)     # [B]
-            bad2 = bad | (row_bad & jnp.logical_not(fin))
-            adv = jnp.logical_not(fin | bad2)
-            nxt = jnp.where(adv, nxt_raw, tok[:, 0])
-            pos2 = jnp.where(adv, pos + 1, pos)
-            fin2 = fin | bad2 | ((eos_ids >= 0) & (nxt == eos_ids)) \
-                | (pos2 + 1 >= stop_lens)
-            return (nxt[:, None], pos2, fin2, bad2, new_caches,
-                    new_cstate), (nxt, bad2)
-
-        (tok, pos, fin, bad, caches, cstate), (toks, bads) = lax.scan(
-            body, (tok, pos, fin, bad, caches, cstate), None, length=K)
-        return toks, bads, tok, pos, fin, bad, caches, cstate
-
-    return jax.jit(window, donate_argnums=(8, 9))
-
-
-def _make_slot_window_mk(exe, K):
-    """Megakernel variant of :func:`_make_slot_window` (ISSUE 18): the
-    compiled step already returns ``(logits, nxt, bad, *caches)`` with
-    the guarded greedy pick fused into its sampling-epilogue kernel, so
-    the scan body consumes the step's own token/bad vectors instead of
-    running ``guarded_argmax`` over full logits.  Carry layout, freeze
-    rule, donation (argnums 8, 9) and the stacked per-step bad flags
-    are identical — :meth:`ServingEngine._run_window` and the host
-    replay (``_apply_window``) cannot tell the two windows apart."""
-    from jax import lax
-
-    pure = exe._pure
-    n_ret = exe.n_ret
-    n_caches = n_ret - 3                   # logits, nxt, bad + caches
-    capt = exe.capt_state
-    carry_idx, const_idx = exe.state_split()
-
-    def window(tok, pos, fin, bad, eos_ids, stop_lens, poison, bt,
-               caches, cstate, const_state):
-        def body(c, _):
-            tok, pos, fin, bad, caches, cstate = c
-            state = [None] * len(capt)
-            for i, v in zip(carry_idx, cstate):
-                state[i] = v
-            for i, v in zip(const_idx, const_state):
-                state[i] = v
-            outs = pure(tok, pos, bt, poison, *caches, *state)
-            nxt_raw = outs[1]
-            row_bad = outs[2]
-            new_caches = list(outs[3:3 + n_caches])
-            new_cstate = list(outs[3 + n_caches:
-                                   3 + n_caches + len(carry_idx)])
             bad2 = bad | (row_bad & jnp.logical_not(fin))
             adv = jnp.logical_not(fin | bad2)
             nxt = jnp.where(adv, nxt_raw, tok[:, 0])

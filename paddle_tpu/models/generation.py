@@ -9,14 +9,6 @@ and updated in place with ``dynamic_update_slice`` at the traced position;
 attention masks positions beyond the current length. The per-token Python
 loop re-invokes the same compiled step (functional cache threading — no
 retrace after the first token).
-
-Decode megakernel (ISSUE 18): ``_gpt_decode_fused``/``_llama_decode_fused``
-(and the TP analogs behind ``_tp_decode_fused_fns``/``make_tp_window(...,
-megakernel=True)``) run the per-token layer chain as ~3 fused Pallas
-dispatches (``ops/pallas/fused_decode_qkv`` -> paged attention ->
-``ops/pallas/fused_decode_mlp``) plus one guarded-argmax sampling
-epilogue. Bitwise-identical to the unfused bodies; the serving engine
-selects them via its ``megakernel`` kwarg / ``serving_megakernel`` flag.
 """
 from __future__ import annotations
 
@@ -296,91 +288,6 @@ def verify_argmax(lg, tok_slot, tok_valid, poison):
 
 
 @primitive
-def fused_qkv_step(x, norm_params, weights, biases, positions,
-                   block_tables, k_pages, v_pages, k_scales=None,
-                   v_scales=None, norm="layer", eps=1e-5, n_heads=1,
-                   n_kv_heads=1, head_dim=1, rope_theta=None,
-                   rows=None):
-    """Decode-megakernel INGRESS (ISSUE 18): pre-attention norm + QKV
-    projection (+ rope) + paged-KV append as ONE fused dispatch —
-    ``ops/pallas/fused_decode_qkv.py``.
-
-    ``x`` [B, H] residual stream, ``norm_params`` [w] or [w, b],
-    ``weights`` one fused [H, 3*nh*hd] projection (GPT) or [wq, wk, wv]
-    (LLaMA, rope applied when ``rope_theta`` is set), ``biases`` a
-    matching list or []. Pool updates go through the kernel's DMA
-    append, byte-identical to :func:`_slot_page_write` (the int8 path
-    replays ``quantization.kv_quantize``'s exact math). Returns
-    ``(q [B, nh, hd], k_pages, v_pages[, k_scales, v_scales])``.
-    """
-    from ..ops.pallas.fused_decode_qkv import fused_decode_qkv
-    nw = norm_params[0]
-    nb = norm_params[1] if len(norm_params) > 1 else None
-    return fused_decode_qkv(
-        x, nw, nb, list(weights), list(biases),
-        positions.reshape(-1), block_tables, k_pages, v_pages,
-        k_scales=k_scales, v_scales=v_scales, norm=norm, eps=eps,
-        n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
-        rope_theta=rope_theta, rows=rows)
-
-
-@primitive
-def paged_attend(q, k_pages, v_pages, block_tables, positions,
-                 scale=None, pages_per_block=None, k_scales=None,
-                 v_scales=None):
-    """Attention half of :func:`paged_slot_attention` alone (the
-    megakernel path appends K/V inside :func:`fused_qkv_step`, so its
-    middle dispatch only reads the pools).  ``q`` [B, nh, hd] already
-    squeezed; the positions→lengths and dtype conventions are verbatim
-    ``paged_slot_attention``'s, so bytes cannot drift between the fused
-    and unfused decode paths."""
-    from ..ops.pallas.paged_attention import paged_decode_attention
-    p = positions.reshape(-1).astype(jnp.int32)
-    out = paged_decode_attention(q, k_pages, v_pages,
-                                 block_tables.astype(jnp.int32), p + 1,
-                                 scale=scale,
-                                 pages_per_block=pages_per_block,
-                                 k_scales=k_scales, v_scales=v_scales)
-    return out.astype(q.dtype)
-
-
-@primitive
-def fused_mlp_step(x, att, wo, norm_params, w1, w2, bo=None, b1=None,
-                   b2=None, w_up=None, arch="gpt", norm="layer",
-                   eps=1e-5, rows=None):
-    """Decode-megakernel EGRESS (ISSUE 18): out-projection + residual
-    + post-norm + MLP + residual as ONE fused dispatch —
-    ``ops/pallas/fused_decode_mlp.py``.  ``x`` [B, H] residual stream,
-    ``att`` [B, nh*hd] attention output; returns the next layer's
-    residual stream [B, H]."""
-    from ..ops.pallas.fused_decode_mlp import fused_decode_mlp
-    nw = norm_params[0]
-    nb = norm_params[1] if len(norm_params) > 1 else None
-    return fused_decode_mlp(x, att, wo, bo, nw, nb, w1, b1, w2, b2,
-                            w_up, arch=arch, norm=norm, eps=eps,
-                            rows=rows)
-
-
-@primitive
-def fused_decode_logits(x, norm_params, w_lm, poison, b_lm=None,
-                        norm="layer", eps=1e-5, transpose_lm=False,
-                        rows=None):
-    """Decode-megakernel SAMPLING EPILOGUE (ISSUE 18): final norm +
-    lm_head + the :func:`guarded_argmax` finiteness-guarded greedy pick
-    as ONE fused dispatch.  ``transpose_lm`` selects the tied-embedding
-    ``matmul(h, wte, transpose_y=True)`` spelling.  Returns
-    ``(logits [B, V] pre-poison, nxt [B] int32, bad [B] bool)`` — nxt
-    and bad exactly match ``guarded_argmax(logits, poison)``."""
-    from ..ops.pallas.fused_decode_mlp import fused_decode_epilogue
-    nw = norm_params[0]
-    nb = norm_params[1] if len(norm_params) > 1 else None
-    return fused_decode_epilogue(x, nw, nb, w_lm, b_lm,
-                                 poison.reshape(-1), norm=norm,
-                                 eps=eps, transpose_lm=transpose_lm,
-                                 rows=rows)
-
-
-@primitive
 def cache_prefill(k_new, v_new, k_cache, v_cache):
     """Write the WHOLE prompt's K/V [B, S, Hkv, D] into cache[:, :S] in
     one shot (batched prefill — the serving-path complement of the
@@ -578,121 +485,6 @@ def _llama_decode(model, ids_t, pos, caches, attend=cache_attention):
     else:
         logits = ops.matmul(h, lm.embed_tokens.weight, transpose_y=True)
     return ops.reshape(logits, [logits.shape[0], -1]), new + new_sc
-
-
-def _gpt_decode_fused(model, ids_t, pos, bt, caches, poison,
-                      pages_per_block=None):
-    """Megakernel decode step for GPTForCausalLM (ISSUE 18): one-token
-    forward in ~3 fused dispatches per layer (:func:`fused_qkv_step` →
-    :func:`paged_attend` → :func:`fused_mlp_step`) plus the
-    :func:`fused_decode_logits` sampling epilogue, against the serving
-    engine's per-slot paged caches.  ``bt`` rides as DATA (a traced
-    [B, NP] tensor — the block-tables-as-data discipline that keeps the
-    engine recompile-free); ``poison`` is the decode guard's [B] lane.
-    Returns ``(logits [B, V], nxt [B] i32, bad [B] bool, new caches)``
-    — logits/token/bad streams byte-identical to :func:`_gpt_decode`
-    over ``paged_slot_attention`` + ``guarded_argmax``."""
-    from .. import ops
-    gpt = model.gpt
-    data, scales = _split_caches(caches, len(gpt.blocks))
-    x = gpt.wte(ids_t) + gpt.wpe(ops.reshape(pos, [-1, 1]))
-    b = x.shape[0]
-    x = ops.reshape(x, [b, x.shape[-1]])
-    new, new_sc = [], []
-    for li, blk in enumerate(gpt.blocks):
-        a = blk.attn
-        ks = scales[2 * li] if scales else None
-        vs = scales[2 * li + 1] if scales else None
-        outs = fused_qkv_step(
-            x, [blk.ln1.weight, blk.ln1.bias], [a.qkv.weight],
-            [a.qkv.bias], pos, bt, data[2 * li], data[2 * li + 1],
-            k_scales=ks, v_scales=vs, norm="layer",
-            eps=blk.ln1._epsilon, n_heads=a.num_heads,
-            n_kv_heads=a.num_heads, head_dim=a.head_dim)
-        q, kc, vc = outs[0], outs[1], outs[2]
-        ks2 = vs2 = None
-        if ks is not None:
-            ks2, vs2 = outs[3], outs[4]
-            new_sc.extend([ks2, vs2])
-        new.extend([kc, vc])
-        att = paged_attend(q, kc, vc, bt, pos,
-                           pages_per_block=pages_per_block,
-                           k_scales=ks2, v_scales=vs2)
-        x = fused_mlp_step(x, ops.reshape(att, [b, -1]), a.proj.weight,
-                           [blk.ln2.weight, blk.ln2.bias],
-                           blk.mlp.fc1.weight, blk.mlp.fc2.weight,
-                           bo=a.proj.bias, b1=blk.mlp.fc1.bias,
-                           b2=blk.mlp.fc2.bias, arch="gpt",
-                           norm="layer", eps=blk.ln2._epsilon)
-    if model.lm_head is not None:
-        w_lm, tr = model.lm_head.weight, False
-    else:
-        w_lm, tr = gpt.wte.weight, True
-    logits, nxt, bad = fused_decode_logits(
-        x, [gpt.ln_f.weight, gpt.ln_f.bias], w_lm, poison,
-        norm="layer", eps=gpt.ln_f._epsilon, transpose_lm=tr)
-    return logits, nxt, bad, new + new_sc
-
-
-def _llama_decode_fused(model, ids_t, pos, bt, caches, poison,
-                        pages_per_block=None):
-    """Megakernel decode step for LlamaForCausalLM — rope folds into
-    the ingress kernel (``rope_theta``), SwiGLU into the egress; see
-    :func:`_gpt_decode_fused`."""
-    from .. import ops
-    lm = model.llama
-    data, scales = _split_caches(caches, len(lm.layers))
-    x = lm.embed_tokens(ids_t)
-    b = x.shape[0]
-    x = ops.reshape(x, [b, x.shape[-1]])
-    new, new_sc = [], []
-    for li, layer in enumerate(lm.layers):
-        a = layer.attn
-        ks = scales[2 * li] if scales else None
-        vs = scales[2 * li + 1] if scales else None
-        outs = fused_qkv_step(
-            x, [layer.input_norm.weight],
-            [a.q_proj.weight, a.k_proj.weight, a.v_proj.weight], [],
-            pos, bt, data[2 * li], data[2 * li + 1], k_scales=ks,
-            v_scales=vs, norm="rms", eps=layer.input_norm._epsilon,
-            n_heads=a.num_heads, n_kv_heads=a.num_kv_heads,
-            head_dim=a.head_dim, rope_theta=a.rope_theta)
-        q, kc, vc = outs[0], outs[1], outs[2]
-        ks2 = vs2 = None
-        if ks is not None:
-            ks2, vs2 = outs[3], outs[4]
-            new_sc.extend([ks2, vs2])
-        new.extend([kc, vc])
-        att = paged_attend(q, kc, vc, bt, pos,
-                           pages_per_block=pages_per_block,
-                           k_scales=ks2, v_scales=vs2)
-        x = fused_mlp_step(x, ops.reshape(att, [b, -1]),
-                           a.o_proj.weight, [layer.post_norm.weight],
-                           layer.mlp.gate_proj.weight,
-                           layer.mlp.down_proj.weight,
-                           w_up=layer.mlp.up_proj.weight, arch="llama",
-                           norm="rms", eps=layer.post_norm._epsilon)
-    if model.lm_head is not None:
-        w_lm, tr = model.lm_head.weight, False
-    else:
-        w_lm, tr = lm.embed_tokens.weight, True
-    logits, nxt, bad = fused_decode_logits(
-        x, [lm.norm.weight], w_lm, poison, norm="rms",
-        eps=lm.norm._epsilon, transpose_lm=tr)
-    return logits, nxt, bad, new + new_sc
-
-
-def _decode_fused_fn(model):
-    """Megakernel analog of :func:`_decode_fn` (ISSUE 18) — the fused
-    decode-step body for the serving engine's ``megakernel`` path."""
-    from .gpt import GPTForCausalLM
-    from .llama import LlamaForCausalLM
-    if isinstance(model, GPTForCausalLM):
-        return _gpt_decode_fused
-    if isinstance(model, LlamaForCausalLM):
-        return _llama_decode_fused
-    raise TypeError(
-        f"megakernel: unsupported model {type(model).__name__}")
 
 
 def _ragged_attend_layer(q, k, v, data, scales, li, tok_pos, tok_slot,
@@ -1761,169 +1553,6 @@ def _llama_tp_decode_body(model, tpp, ppb):
     return body
 
 
-def _gpt_tp_decode_body_fused(model, tpp, ppb):
-    """Megakernel TP decode step (ISSUE 18) — shard-local fused
-    kernels inside the shard_map body, with the layer's psum contract
-    UNCHANGED: the ingress kernel computes the shard's local heads and
-    appends them to the shard-local pools, the attention kernel reads
-    them back, and the out-projection matmul + psum + bias + residual
-    stay at jnp level exactly where :func:`_gpt_tp_decode_body` puts
-    them (one psum per matmul per layer — the collective schedule the
-    program audit pins).  The MLP runs as a shard-local
-    ``fused_decode_mlp_partial`` before its psum.  Only valid under the
-    ``shard_kv`` regime (local pools hold the shard's kv heads);
-    ``make_tp_window`` falls back to the unfused body otherwise.
-    Signature gains ``poison``: the fused epilogue returns the guarded
-    greedy pick in-graph, ``(logits, nxt, bad, new caches)``."""
-    from jax import lax as _lax
-
-    from ..distributed.fleet.pipeline import functional_call
-    from ..ops.pallas.fused_decode_mlp import (fused_decode_epilogue,
-                                               fused_decode_mlp_partial)
-    from ..ops.pallas.fused_decode_qkv import fused_decode_qkv
-    from ..ops.pallas.paged_attention import paged_decode_attention
-
-    gpt = model.gpt
-    meta = tpp.meta
-    names = tpp.names
-    n_p = len(names)
-    L = len(gpt.blocks)
-    axis = meta["axis"]
-
-    def body(tok, pos, bt, poison, *flat):
-        pv = dict(zip(names, flat[:n_p]))
-        caches = list(flat[n_p:])
-        data, scales = _split_caches(caches, L)
-        b = tok.shape[0]
-        x = functional_call(gpt.wte, {"weight": pv["wte"]}, tok) \
-            + functional_call(gpt.wpe, {"weight": pv["wpe"]},
-                              pos.reshape(-1, 1))
-        x = x.reshape(b, -1)
-        p = pos.reshape(-1).astype(jnp.int32)
-        bt_i = bt.astype(jnp.int32)
-        new, new_sc = [], []
-        for li, blk in enumerate(gpt.blocks):
-            wq = pv[f"b{li}.qkv.w"]          # [h, 3, nh_loc, hd]
-            nh_loc, hd = wq.shape[2], wq.shape[3]
-            ks = scales[2 * li] if scales else None
-            vs = scales[2 * li + 1] if scales else None
-            outs = fused_decode_qkv(
-                x, pv[f"b{li}.ln1.w"], pv[f"b{li}.ln1.b"],
-                [wq.reshape(wq.shape[0], -1)],
-                [pv[f"b{li}.qkv.b"].reshape(-1)], p, bt_i,
-                data[2 * li], data[2 * li + 1], k_scales=ks,
-                v_scales=vs, norm="layer", eps=blk.ln1._epsilon,
-                n_heads=nh_loc, n_kv_heads=nh_loc, head_dim=hd)
-            q, kc, vc = outs[0], outs[1], outs[2]
-            ks2 = vs2 = None
-            if ks is not None:
-                ks2, vs2 = outs[3], outs[4]
-                new_sc.extend([ks2, vs2])
-            new.extend([kc, vc])
-            att = paged_decode_attention(
-                q, kc, vc, bt_i, p + 1, pages_per_block=ppb,
-                k_scales=ks2, v_scales=vs2).astype(q.dtype)
-            wp = pv[f"b{li}.proj.w"]         # [nh_loc, hd, h]
-            prj = att.reshape(b, -1) @ wp.reshape(-1, wp.shape[-1])
-            prj = _lax.psum(prj, axis) + pv[f"b{li}.proj.b"]
-            y1 = x + prj
-            f2 = fused_decode_mlp_partial(
-                y1, pv[f"b{li}.ln2.w"], pv[f"b{li}.ln2.b"],
-                pv[f"b{li}.fc1.w"], pv[f"b{li}.fc1.b"],
-                pv[f"b{li}.fc2.w"], arch="gpt", norm="layer",
-                eps=blk.ln2._epsilon)
-            f2 = _lax.psum(f2, axis) + pv[f"b{li}.fc2.b"]
-            x = y1 + f2
-        if model.lm_head is not None:
-            w_lm, tr = pv["lm_head"], False
-        else:
-            w_lm, tr = pv["wte"], True
-        logits, nxt, bad = fused_decode_epilogue(
-            x, pv["ln_f.w"], pv["ln_f.b"], w_lm, None,
-            poison.reshape(-1), norm="layer", eps=gpt.ln_f._epsilon,
-            transpose_lm=tr)
-        return logits, nxt, bad, new + new_sc
-
-    return body
-
-
-def _llama_tp_decode_body_fused(model, tpp, ppb):
-    """LLaMA analog of :func:`_gpt_tp_decode_body_fused` (rope in the
-    ingress kernel, SwiGLU partial in the egress)."""
-    from jax import lax as _lax
-
-    from ..distributed.fleet.pipeline import functional_call
-    from ..ops.pallas.fused_decode_mlp import (fused_decode_epilogue,
-                                               fused_decode_mlp_partial)
-    from ..ops.pallas.fused_decode_qkv import fused_decode_qkv
-    from ..ops.pallas.paged_attention import paged_decode_attention
-
-    lm = model.llama
-    meta = tpp.meta
-    names = tpp.names
-    n_p = len(names)
-    L = len(lm.layers)
-    axis = meta["axis"]
-
-    def body(tok, pos, bt, poison, *flat):
-        pv = dict(zip(names, flat[:n_p]))
-        caches = list(flat[n_p:])
-        data, scales = _split_caches(caches, L)
-        b = tok.shape[0]
-        x = functional_call(lm.embed_tokens, {"weight": pv["wte"]},
-                            tok).reshape(b, -1)
-        p = pos.reshape(-1).astype(jnp.int32)
-        bt_i = bt.astype(jnp.int32)
-        new, new_sc = [], []
-        for li, layer in enumerate(lm.layers):
-            a = layer.attn
-            wqq = pv[f"b{li}.q.w"]           # [h, nh_loc, hd]
-            wkk = pv[f"b{li}.k.w"]           # [h, nhk_loc, hd]
-            wvv = pv[f"b{li}.v.w"]
-            ks = scales[2 * li] if scales else None
-            vs = scales[2 * li + 1] if scales else None
-            outs = fused_decode_qkv(
-                x, pv[f"b{li}.in_norm.w"], None,
-                [wqq.reshape(wqq.shape[0], -1),
-                 wkk.reshape(wkk.shape[0], -1),
-                 wvv.reshape(wvv.shape[0], -1)], [], p, bt_i,
-                data[2 * li], data[2 * li + 1], k_scales=ks,
-                v_scales=vs, norm="rms",
-                eps=layer.input_norm._epsilon, n_heads=wqq.shape[1],
-                n_kv_heads=wkk.shape[1], head_dim=wqq.shape[2],
-                rope_theta=a.rope_theta)
-            q, kc, vc = outs[0], outs[1], outs[2]
-            ks2 = vs2 = None
-            if ks is not None:
-                ks2, vs2 = outs[3], outs[4]
-                new_sc.extend([ks2, vs2])
-            new.extend([kc, vc])
-            att = paged_decode_attention(
-                q, kc, vc, bt_i, p + 1, pages_per_block=ppb,
-                k_scales=ks2, v_scales=vs2).astype(q.dtype)
-            wo = pv[f"b{li}.o.w"]            # [nh_loc, hd, h]
-            prj = att.reshape(b, -1) @ wo.reshape(-1, wo.shape[-1])
-            prj = _lax.psum(prj, axis)
-            y1 = x + prj
-            f2 = fused_decode_mlp_partial(
-                y1, pv[f"b{li}.post_norm.w"], None,
-                pv[f"b{li}.gate.w"], None, pv[f"b{li}.down.w"],
-                w_up=pv[f"b{li}.up.w"], arch="llama", norm="rms",
-                eps=layer.post_norm._epsilon)
-            f2 = _lax.psum(f2, axis)
-            x = y1 + f2
-        if model.lm_head is not None:
-            w_lm, tr = pv["lm_head"], False
-        else:
-            w_lm, tr = pv["wte"], True
-        logits, nxt, bad = fused_decode_epilogue(
-            x, pv["norm.w"], None, w_lm, None, poison.reshape(-1),
-            norm="rms", eps=lm.norm._epsilon, transpose_lm=tr)
-        return logits, nxt, bad, new + new_sc
-
-    return body
-
-
 def _tp_body_fns(model):
     from .gpt import GPTForCausalLM
     from .llama import LlamaForCausalLM
@@ -1931,19 +1560,6 @@ def _tp_body_fns(model):
         return _gpt_tp_body, _gpt_tp_decode_body
     if isinstance(model, LlamaForCausalLM):
         return _llama_tp_body, _llama_tp_decode_body
-    raise TypeError(
-        f"serving TP: unsupported model {type(model).__name__}")
-
-
-def _tp_decode_fused_fns(model):
-    """Megakernel analog of :func:`_tp_body_fns` (decode half only —
-    the mixed/spec programs keep the unfused body)."""
-    from .gpt import GPTForCausalLM
-    from .llama import LlamaForCausalLM
-    if isinstance(model, GPTForCausalLM):
-        return _gpt_tp_decode_body_fused
-    if isinstance(model, LlamaForCausalLM):
-        return _llama_tp_decode_body_fused
     raise TypeError(
         f"serving TP: unsupported model {type(model).__name__}")
 
@@ -2022,21 +1638,12 @@ def make_tp_spec(model, tpp, jmesh, q_block, ppb, n_caches,
                               out_specs=out_specs))
 
 
-def make_tp_window(model, tpp, jmesh, ppb, n_caches, K,
-                   megakernel=False):
+def make_tp_window(model, tpp, jmesh, ppb, n_caches, K):
     """K scanned TP decode steps in ONE dispatch — the
     ``_make_slot_window`` analog with explicit params instead of
     captured executable state.  Same carry (token, position, finished,
     guard-bad per slot + caches), same freeze rule, same stacked
-    per-step bad flags; cache pools are donated.
-
-    ``megakernel`` (ISSUE 18) swaps the scan body for the fused
-    ``_*_tp_decode_body_fused`` step: ~3 fused dispatches per layer,
-    the guarded greedy pick fused into the epilogue kernel, identical
-    token/bad streams.  The fused TP step needs shard-local KV pools,
-    so the non-``shard_kv`` regime (GQA ``Hk < tp``, replicated pools)
-    silently keeps the unfused body — correctness first, fusion where
-    the layout allows it."""
+    per-step bad flags; cache pools are donated."""
     import jax as _jax
     from jax import lax as _lax
     from jax.sharding import PartitionSpec as P
@@ -2044,12 +1651,8 @@ def make_tp_window(model, tpp, jmesh, ppb, n_caches, K,
     from ..core.meshutil import shard_map
     meta = tpp.meta
     axis = meta["axis"]
-    use_mk = bool(megakernel) and bool(meta["shard_kv"])
-    if use_mk:
-        step_body = _tp_decode_fused_fns(model)(model, tpp, ppb)
-    else:
-        _, decode_body_fn = _tp_body_fns(model)
-        step_body = decode_body_fn(model, tpp, ppb)
+    _, decode_body_fn = _tp_body_fns(model)
+    step_body = decode_body_fn(model, tpp, ppb)
     cspec = tp_cache_spec(meta, axis)
     rep = P()
     n_p = len(tpp.names)
@@ -2061,14 +1664,9 @@ def make_tp_window(model, tpp, jmesh, ppb, n_caches, K,
 
         def body(c, _):
             tok, pos, fin, bad, caches = c
-            if use_mk:
-                _, nxt_raw, row_bad, new_caches = step_body(
-                    tok, pos, bt, poison, *params, *caches)
-            else:
-                lg, new_caches = step_body(tok, pos, bt, *params,
-                                           *caches)
-                lg = lg.astype(jnp.float32)
-                nxt_raw, row_bad = guarded_argmax.raw(lg, poison)
+            lg, new_caches = step_body(tok, pos, bt, *params, *caches)
+            lg = lg.astype(jnp.float32)
+            nxt_raw, row_bad = guarded_argmax.raw(lg, poison)
             bad2 = bad | (row_bad & jnp.logical_not(fin))
             adv = jnp.logical_not(fin | bad2)
             nxt = jnp.where(adv, nxt_raw, tok[:, 0])
@@ -2084,12 +1682,8 @@ def make_tp_window(model, tpp, jmesh, ppb, n_caches, K,
 
     in_specs = (rep,) * 8 + tuple(tpp.specs) + (cspec,) * n_caches
     out_specs = (rep,) * 6 + (cspec,) * n_caches
-    # The fused kernels' bodies run at their top level, and interpret
-    # mode binds a body primitive by primitive: check_vma (JAX 0.9)
-    # then refuses a body literal that meets a tp-varying operand.  The
-    # ragged kernel escapes it only because its body sits in pl.when.
     fn = shard_map(window, jmesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=not use_mk)
+                   out_specs=out_specs)
     # donate the cache pools (the last n_caches positional args)
     donate = tuple(range(8 + n_p, 8 + n_p + n_caches))
     return _jax.jit(fn, donate_argnums=donate)

@@ -43,23 +43,14 @@ TPU_REFUSED = {
         "requires the last two dimensions of a block to be divisible "
         "by 8 and 128 or equal to the array's: block (1, page_size) "
         "is neither"),
-    "megakernel": (
-        "fused_decode_qkv: Mosaic infer-vector-layout: unsupported "
-        "shape cast (8,768)->(8,12,64); fused_decode_epilogue: the "
-        "whole [50304,768] head is one VMEM window, 154 MB against "
-        "128 MiB; fused_decode_mlp with f32 weights: 20.41M of "
-        "scoped VMEM against a 16.00M limit"),
 }
 
 
 from . import flash_attention  # noqa: E402
-from . import fused_decode_mlp  # noqa: E402
-from . import fused_decode_qkv  # noqa: E402
 from . import fused_optimizer  # noqa: E402
 from . import fused_residual_norm  # noqa: E402
 from . import norms  # noqa: E402
 from . import rope  # noqa: E402
 
-__all__ = ["flash_attention", "fused_decode_mlp", "fused_decode_qkv",
-           "fused_optimizer", "fused_residual_norm", "norms", "rope",
-           "out_struct", "use_interpret", "TPU_REFUSED"]
+__all__ = ["flash_attention", "fused_optimizer", "fused_residual_norm",
+           "norms", "rope", "out_struct", "use_interpret", "TPU_REFUSED"]

@@ -22,12 +22,7 @@ a trace, sweeps run on synthetic decode shapes when enabled),
 ``fused_optimizer_rows`` (row-block of the fused optimizer update —
 fused_optimizer.pick_rows), ``quant_matmul_blocks`` ((bm, bn) output
 tiling of the fused weight-only int8 matmul —
-quant_matmul.pick_blocks), ``fused_decode_qkv_rows`` (row block of the
-decode megakernel's norm+QKV+rope+paged-append ingress kernel —
-fused_decode_qkv.pick_qkv_rows; candidates VMEM-capped, default one
-block covering the whole decode batch), ``fused_decode_mlp_rows``
-(row block of the megakernel's out-proj+residual+MLP egress kernel —
-fused_decode_mlp.pick_mlp_rows) and ``fused_residual_norm_rows`` (row
+quant_matmul.pick_blocks) and ``fused_residual_norm_rows`` (row
 block of the training glue kernels' fused residual-add+norm fwd/bwd
 pair — fused_residual_norm.pick_glue_rows; the sweep times a full
 grad-through-custom_vjp round trip since the bwd kernel replays the

@@ -17,15 +17,14 @@ summed on the host, exactly like ``norms.py``.
 
 Every kernel has an unjitted twin (``*_fwd_twin`` / ``*_bwd_twin``)
 walking identical row blocks with the block math under ``jax.jit`` —
-bitwise vs interpret mode (fused_decode_mlp's twin contract).  Row
-block is an autotune entry (``fused_residual_norm_rows`` —
-``pick_glue_rows``).
+bitwise vs interpret mode.  Row block is an autotune entry
+(``fused_residual_norm_rows`` — ``pick_glue_rows``).
 
 Wired into the GPT/LLaMA/BERT blocks behind the ``train_glue_fusion``
 flag (default OFF: the standalone Pallas LN measured as a fusion
 BARRIER in-context — +6 ms/step on the GPT-124M bench, see
 nn/functional/norm.py — so the fused glue path ships dark until the
-TPU round prices it end-to-end, the serving_megakernel precedent).
+TPU round prices it end-to-end).
 """
 from __future__ import annotations
 

@@ -78,12 +78,6 @@ _TIER1_ORDER = [
     # programs; test_distserve is the ISSUE-13 TP/disagg acceptance
     # suite and reuses the session serving_gpt + the same geometry)
     "test_pallas.py", "test_quant_serving.py", "test_serving_engine.py",
-    # test_decode_megakernel is the ISSUE-18 acceptance suite (fused
-    # decode kernels bitwise vs twins, engine on/off bitwise over the
-    # serving workloads); it reuses the session serving_gpt + the
-    # serving-suite geometry, so the unfused halves of its comparisons
-    # ride the already-compiled programs
-    "test_decode_megakernel.py",
     "test_speculative.py", "test_distserve.py",
     # test_router is the ISSUE-17 fleet-routing acceptance suite; it
     # reuses the session serving_gpt + the same geometry, so every
@@ -136,3 +130,27 @@ def serving_gpt():
         max_seq_len=64, dropout=0.0))
     m.eval()
     return m
+
+
+@pytest.fixture(scope="session")
+def serving_llama_gqa():
+    """The LLaMA of the serving suites (4 query heads on 2 key/value
+    heads, rotary, RMS norm, SwiGLU), one for the session as
+    ``serving_gpt`` is: the same vocabulary, so the same prompts."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    paddle.seed(0)
+    m = LlamaForCausalLM(LlamaConfig(
+        vocab_size=96, hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, max_seq_len=64))
+    m.eval()
+    return m
+
+
+@pytest.fixture(params=["gpt", "llama_gqa"])
+def serving_lm(request, serving_gpt, serving_llama_gqa):
+    """Both served families, for the cases that must hold with rotary
+    keys of two kv heads under four query heads as well as GPT's."""
+    return {"gpt": serving_gpt, "llama_gqa": serving_llama_gqa}[
+        request.param]

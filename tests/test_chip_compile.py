@@ -1,7 +1,8 @@
-"""The main path's Pallas kernels, compiled for a DESCRIBED TPU v5e with
-``interpret=False`` — what the chip's compiler refuses fails here, on
-the CPU, before it costs chip time.  Nothing runs: shapes go in, a
-compiled program (or the compiler's refusal) comes out.
+"""The main path's Pallas kernels, and the serving engine's own programs
+round them, compiled for a DESCRIBED TPU v5e with ``interpret=False`` —
+what the chip's compiler refuses fails here, on the CPU, before it
+costs chip time.  Nothing runs there: shapes go in, a compiled program
+(or the compiler's refusal) comes out.
 
 Rules of this file (on-chip-measurement guide, section 2): the topology
 is described inside a module-scoped fixture that skips when it cannot
@@ -18,8 +19,6 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import pallas as P
 from paddle_tpu.ops.pallas import flash_attention as FA
-from paddle_tpu.ops.pallas import fused_decode_mlp as FM
-from paddle_tpu.ops.pallas import fused_decode_qkv as FQ
 from paddle_tpu.ops.pallas import fused_optimizer as FO
 from paddle_tpu.ops.pallas import fused_residual_norm as FRN
 from paddle_tpu.ops.pallas import paged_attention as PA
@@ -121,6 +120,117 @@ def test_paged_pools_stay_in_hbm_d64(chip):
     large = _paged(chip, "ragged", 12, 12, 64, F32, pages=8192)
     a, b = small.memory_analysis(), large.memory_analysis()
     assert b.temp_size_in_bytes == a.temp_size_in_bytes
+
+
+# ---------------------------------------------- the engine's own programs
+@pytest.fixture(scope="module", params=["gpt_12h_d64",
+                                        "llama_32_8h_d128"])
+def served(request):
+    """One layer at the family's head geometry, served here on the CPU
+    for one request, so that the engine holds its decode step and its
+    mixed step as captured programs."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    paddle.seed(0)
+    if request.param.startswith("gpt"):
+        from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=512, hidden_size=768, num_layers=1, num_heads=12,
+            max_seq_len=64, dropout=0.0))
+    else:
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=512, hidden_size=4096, num_layers=1, num_heads=32,
+            num_kv_heads=8, max_seq_len=64, intermediate_size=1024))
+    model.eval()
+    eng = ContinuousBatchingEngine(
+        model, max_slots=8, page_size=PAGE, max_seq_len=64,
+        decode_window=4, prefill_chunk=16, q_block=8)
+    eng.add_request(np.arange(5, dtype=np.int32), 8)
+    eng.run()
+    assert eng.stats["decode_dispatches"] >= 2      # step, then a window
+    return eng
+
+
+def _on_chip(chip, tree):
+    return jax.tree.map(lambda a: chip(a.shape, a.dtype), tree)
+
+
+@pytest.mark.parametrize("program", ["window", "mixed"])
+def test_engine_program_compiles(chip, served, program, monkeypatch):
+    """The programs the engine dispatches, whole, for the described
+    chip: the scanned decode window over the captured decode step, and
+    the mixed prefill+decode step.  Traced again with the kernels not
+    interpreted, from the shapes the engine ran here."""
+    from paddle_tpu.inference.engine import _make_slot_window
+    monkeypatch.setattr(P, "use_interpret", lambda: False)
+    if program == "window":
+        exe = served._decode_exe
+        caches = [c._read() for c in served._caches]
+        carry_idx, const_idx = exe.state_split()
+        state = [t._read() for t in exe.capt_state]
+        b = served.max_slots
+        vec = lambda dt: chip((b,), dt)                    # noqa: E731
+        lowered = _make_slot_window(exe, served.decode_window).lower(
+            chip((b, 1), jnp.int32), vec(jnp.int32), vec(jnp.bool_),
+            vec(jnp.bool_), vec(jnp.int32), vec(jnp.int32), vec(F32),
+            chip(served._bt.shape, jnp.int32), _on_chip(chip, caches),
+            _on_chip(chip, [state[i] for i in carry_idx]),
+            _on_chip(chip, [state[i] for i in const_idx]))
+    else:
+        (exe,) = served._get_mixed_fn()._cache.values()
+        # a new function, so that no trace made here on the CPU is reused
+        lowered = jax.jit(lambda *vals: exe._pure(*vals)).lower(
+            *[chip(shape, jnp.dtype(dt)) for shape, dt in exe._sig0])
+    assert _has_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("program", ["window", "mixed"])
+def test_engine_tp_program_compiles(topo, served, program, monkeypatch):
+    """The same two programs of a ``mesh=`` engine, for all four
+    described chips: one manual ``shard_map`` each, heads cut four
+    ways, the kernels inside it on each chip's own heads."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.models import generation as G
+    monkeypatch.setattr(P, "use_interpret", lambda: False)
+    eng = ContinuousBatchingEngine(
+        served.model, mesh=Mesh(np.asarray(jax.devices()[:4]), ("tp",)),
+        max_slots=served.max_slots, page_size=PAGE, max_seq_len=64,
+        decode_window=served.decode_window, prefill_chunk=16, q_block=8)
+    tpp, mesh = eng._tpp, Mesh(np.asarray(topo.devices), ("tp",))
+
+    def on(spec, shape, dtype):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    def rep(shape, dtype=jnp.int32):
+        return on(PartitionSpec(), shape, dtype)
+
+    cspec = G.tp_cache_spec(tpp.meta, "tp")
+    sharded = [on(spec, v.shape, v.dtype)
+               for spec, v in zip(tpp.specs, tpp.vals)] \
+        + [on(cspec, c.shape, c._read().dtype) for c in eng._caches]
+    b, t, n = eng.max_slots, eng.token_budget, len(eng._caches)
+    bt = rep(eng._bt.shape)
+    if program == "window":
+        lowered = G.make_tp_window(
+            eng.model, tpp, mesh, eng.pages_per_block, n,
+            eng.decode_window).lower(
+                rep((b, 1)), rep((b,)), rep((b,), jnp.bool_),
+                rep((b,), jnp.bool_), rep((b,)), rep((b,)),
+                rep((b,), F32), bt, *sharded)
+    else:
+        lowered = G.make_tp_mixed(
+            eng.model, tpp, mesh, eng.q_block, eng.pages_per_block,
+            n).lower(
+                rep((1, t)), rep((t,)), rep((t,)), rep((t,)), rep((b,)),
+                rep((b,)), rep((b,)), rep((b,), F32), bt, *sharded)
+    assert _has_kernel(lowered.compile())
 
 
 # ------------------------------------------------------ the trainer's path
@@ -240,61 +350,6 @@ def test_flash_attention_segment_ids_compiles(chip):
             argnums=(0, 1, 2))(q, k, v)
 
     assert _has_kernel(chip.compile(grads, q, q, q, seg))
-
-
-def _gpt_decode_args(chip, dtype):
-    b, h = 8, 768
-    return b, h, chip((b, h), dtype), chip((h,), dtype)
-
-
-def test_fused_decode_mlp_bf16_compiles(chip):
-    """Was refused with ``'tpu.matmul' op Expected matmul acc to be
-    32-bit``; the dots now accumulate in f32.  (With f32 weights the
-    whole MLP still asks for 20.41M of scoped VMEM against 16.00M.)"""
-    b, h, x, vec = _gpt_decode_args(chip, BF16)
-    f = 4 * h
-
-    def mlp(x, att, wo, bo, nw, nb, w1, b1, w2, b2):
-        return FM.fused_decode_mlp(x, att, wo, bo, nw, nb, w1, b1, w2, b2,
-                                   interpret=False)
-
-    assert _has_kernel(chip.compile(
-        mlp, x, x, chip((h, h), BF16), vec, vec, vec, chip((h, f), BF16),
-        chip((f,), BF16), chip((f, h), BF16), vec))
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "Mosaic infer-vector-layout: unsupported shape cast "
-    "(8,768)->(8,12,64): the kernel splits heads out of the lane "
-    "dimension in VMEM"))
-def test_fused_decode_qkv_compiles(chip):
-    b, h, x, vec = _gpt_decode_args(chip, F32)
-    pool = chip((12, 217, PAGE, 64), F32)
-
-    def qkv(x, nw, nb, w, bias, pos, bt, kp, vp):
-        return FQ.fused_decode_qkv(
-            x, nw, nb, [w], [bias], pos, bt, kp, vp, norm="layer",
-            eps=1e-5, n_heads=12, n_kv_heads=12, head_dim=64,
-            interpret=False)
-
-    chip.compile(qkv, x, vec, vec, chip((h, 3 * h), F32),
-                 chip((3 * h,), F32), chip((b,), jnp.int32),
-                 chip((b, TABLE), jnp.int32), pool, pool)
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "the whole [50304,768] head is one VMEM window: 'Allocation "
-    "(size=154533888) would exceed memory (size=134217728)'"))
-def test_fused_decode_epilogue_compiles(chip):
-    b, h, x, vec = _gpt_decode_args(chip, F32)
-
-    def epilogue(x, nw, nb, w_lm, poison):
-        return FM.fused_decode_epilogue(x, nw, nb, w_lm, None, poison,
-                                        transpose_lm=True,
-                                        interpret=False)
-
-    chip.compile(epilogue, x, vec, vec, chip((50304, h), F32),
-                 chip((b,), F32))
 
 
 @pytest.mark.xfail(strict=True, reason=(

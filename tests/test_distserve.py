@@ -140,15 +140,11 @@ def test_tp2_spec_decode(gpt, mesh2, refs):
     _assert_pool_conserved(eng)
 
 
-def test_tp_llama_gqa_both_regimes(mesh2, mesh4):
+def test_tp_llama_gqa_both_regimes(serving_llama_gqa, mesh2, mesh4):
     """GQA awareness: Hk=2 heads shard over tp=2 (Hk % tp == 0) and
     REPLICATE over tp=4 (each pair of shards attends a 1-head slice
     of the replicated pools) — both bitwise vs single-device."""
-    paddle.seed(0)
-    m = LlamaForCausalLM(LlamaConfig(
-        vocab_size=96, hidden_size=32, num_layers=2, num_heads=4,
-        num_kv_heads=2, max_seq_len=64))
-    m.eval()
+    m = serving_llama_gqa
     prompts, new = _workload(seed=3, sizes=(7, 4, 11), new=(5, 6, 4))
     ref, _ = _drive(m, None, prompts, new)
     for mesh in (mesh2, mesh4):

@@ -8,10 +8,8 @@ shuffling — must produce EXACTLY the greedy sequence that a standalone
 import numpy as np
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.inference import ContinuousBatchingEngine
 from paddle_tpu.models import generate
-from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 
 @pytest.fixture(scope="module")
@@ -129,12 +127,8 @@ def test_engine_eos_early_retire(gpt):
     _assert_pool_conserved(eng)
 
 
-def test_engine_llama_gqa():
-    paddle.seed(0)
-    m = LlamaForCausalLM(LlamaConfig(
-        vocab_size=96, hidden_size=32, num_layers=2, num_heads=4,
-        num_kv_heads=2, max_seq_len=64))
-    m.eval()
+def test_engine_llama_gqa(serving_llama_gqa):
+    m = serving_llama_gqa
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, 96, (n,)).astype(np.int32)
                for n in (7, 4, 11)]
@@ -421,7 +415,7 @@ def _engine(gpt, **kw):
     return ContinuousBatchingEngine(gpt, **args)
 
 
-def test_engine_prefix_cache_shared_prefix_bitwise(gpt):
+def test_engine_prefix_cache_shared_prefix_bitwise(serving_lm):
     """Requests sharing a long prompt prefix: later admissions map the
     shared pages from the index (prefill tokens computed drops below
     tokens requested) and every output is bitwise-identical to the
@@ -432,11 +426,11 @@ def test_engine_prefix_cache_shared_prefix_bitwise(gpt):
              for n in (3, 2, 5, 1)]
     prompts = [np.concatenate([shared, t]) for t in tails]
     new = [6, 5, 4, 6]
-    refs = _paged_refs(gpt, prompts, new)
+    refs = _paged_refs(serving_lm, prompts, new)
 
     outs = {}
     for mode in (True, False):
-        eng = _engine(gpt, prefix_cache=mode)
+        eng = _engine(serving_lm, prefix_cache=mode)
         rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
         done = eng.run()
         outs[mode] = [done[r].sequence for r in rids]
@@ -461,15 +455,15 @@ def test_engine_prefix_cache_shared_prefix_bitwise(gpt):
         np.testing.assert_array_equal(got_off, ref)
 
 
-def test_engine_prefix_cache_cow_full_prompt(gpt):
+def test_engine_prefix_cache_cow_full_prompt(serving_lm):
     """A fully-cached page-aligned prompt takes the copy-on-write
     path: the divergence page is duplicated, exactly ONE token is
     recomputed for the last position's logits, the shared page is
     never written, and the output stays bitwise."""
     rng = np.random.default_rng(31)
     prompt = rng.integers(0, 96, (8,)).astype(np.int32)  # 2 full pages
-    (ref,) = _paged_refs(gpt, [prompt], [6])
-    eng = _engine(gpt)
+    (ref,) = _paged_refs(serving_lm, [prompt], [6])
+    eng = _engine(serving_lm)
     r1 = eng.add_request(prompt, 6)
     done = eng.run()
     np.testing.assert_array_equal(done[r1].sequence, ref)

@@ -31,6 +31,15 @@ def gpt(serving_gpt):
     return serving_gpt
 
 
+def _ngram_workload(lm):
+    """The suite's workload; the LLaMA's first request runs long enough
+    to fall into the loop its greedy stream ends in, so that the n-gram
+    proposer has drafts the target accepts."""
+    if hasattr(lm, "llama"):
+        return _workload(3, new=(16, 4, 7, 5))
+    return _workload(0)
+
+
 @pytest.fixture(scope="module")
 def draft_gpt():
     """A smaller, differently-seeded GPT: a REAL draft model (its
@@ -126,15 +135,16 @@ def test_accept_sampled_rejection_rule():
 # engine parity: both proposers, eos, contention
 # ----------------------------------------------------------------------
 
-def test_spec_engine_matches_generate_ngram(gpt):
+def test_spec_engine_matches_generate_ngram(serving_lm):
     """Slot contention + mid-stream admission with the n-gram proposer:
     every output equals the sequential generate() row AND the spec-off
     engine; drafts were actually proposed and some accepted."""
-    prompts, new = _workload(0)
-    refs = _paged_refs(gpt, prompts, new)
+    prompts, new = _ngram_workload(serving_lm)
+    refs = _paged_refs(serving_lm, prompts, new)
     outs = {}
     for spec in (False, True):
-        eng = (_spec_engine(gpt) if spec else _engine(gpt))
+        eng = (_spec_engine(serving_lm) if spec
+               else _engine(serving_lm))
         rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
         done = eng.run()
         outs[spec] = [done[r].sequence for r in rids]
@@ -219,15 +229,15 @@ def test_spec_engine_prefix_cache_compose(gpt):
     assert st["pages_in_use"] == 0
 
 
-def test_spec_engine_kv_quant_token_identical(gpt):
+def test_spec_engine_kv_quant_token_identical(serving_lm):
     """int8 KV + speculation: quantized writes for accepted positions
     are byte-identical to the non-speculative quant path, so the spec
     quant engine's streams equal the plain quant engine's exactly."""
     prompts, new = _workload(3, lens=(5, 9, 3), new=(6, 4, 7))
     outs = {}
     for spec in (False, True):
-        eng = (_spec_engine(gpt, kv_quant=True) if spec
-               else _engine(gpt, kv_quant=True))
+        eng = (_spec_engine(serving_lm, kv_quant=True) if spec
+               else _engine(serving_lm, kv_quant=True))
         rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
         done = eng.run()
         outs[spec] = [done[r].sequence for r in rids]
