@@ -5,18 +5,35 @@ Capability analog of the reference FlashAttention-2 integration
 ``flash_attn_grad_kernel.cu`` bwd, python surface
 ``python/paddle/nn/functional/flash_attention.py:147``) — TPU-native design:
 
-* online-softmax tiling sized for the MXU (q blocks x k blocks, fp32
-  accumulators in registers/VMEM, bf16 matmul inputs);
+* online-softmax tiling sized for the MXU (q blocks x k blocks): all
+  seven products (two forward, five backward) take q, k, v, do as the
+  tensors hold them and ``p`` / ``ds`` cast to that dtype, and accumulate
+  in float32; float32 inputs stay float32; the row statistics, ``exp``,
+  the accumulators and ``delta`` are float32 always.  ``q * scale`` is
+  rounded once to the inputs' dtype (``_scaled``).  On the chip a
+  float32 product in a kernel is one bf16 pass too (measured, PR 32: the
+  same gradients to the last digit), so this buys no MXU time; it
+  halves the width of ``p`` / ``ds``, which a 1024 x 1024 backward tile
+  repays with 7%;
 * per-(batch, head) grid programs keep K/V resident in VMEM while a q block
   streams through — no [S, S] score matrix ever exists in HBM;
-* causal programs stop the k loop at the diagonal block (the FA2 trick that
-  halves causal FLOPs);
+* a tile does the work that tile needs: causal programs stop the k loop at
+  the diagonal block (the FA2 trick that halves causal FLOPs); the
+  backward runs the tiles wholly under the diagonal, inside the lengths
+  and free of segment ids through a body with no iota, compare or
+  ``where`` and skips those wholly above it; the forward builds a mask
+  only in a call some tile of which can need one (one body a call: a
+  mask-free loop beside the masked one was slower on the chip).
+  ``_fwd_k_blocks`` / ``_fwd_masks`` / ``_bwd_tile_kinds`` decide it for
+  the kernels, the twin and the ``flash.tiles{kernel, kind, shape}``
+  gauges of the ``observability`` registry alike;
 * grouped-query attention maps q-head -> kv-head in the BlockSpec index map
   (no materialized ``repeat`` of K/V, unlike the XLA fallback);
 * backward recomputes the softmax from the saved logsumexp (flash-attn
   recompute strategy) in ONE fused kernel: a 4-D grid walks (k-block,
   q-block) tiles, recomputing the attention probabilities ONCE per tile
-  and producing dk/dv (VMEM accumulators over the q grid dim) AND dq (a
+  and producing dk/dv (VMEM accumulators over the q grid dim, held
+  transposed so that no [bq, bk] operand has to be turned) AND dq (a
   persistent full-row VMEM scratch accumulated over the k grid dim) from
   the same ``p``/``ds`` — the previous two-pass backward paid the s/p
   recompute twice (7 tile dots; fused is 5, the ~2.5x-over-forward FLOP
@@ -24,13 +41,15 @@ Capability analog of the reference FlashAttention-2 integration
 
 Parity discipline (the ``quant_matmul_jnp`` contract):
 ``flash_attention_bwd_jnp`` is an UNJITTED jnp twin replaying the fused
-kernel's exact tile walk — same per-tile dot shapes, same accumulate
-order, same masks — so Pallas-interpret backward grads are BITWISE equal
-to the twin on CPU for every geometry (causal x GQA x segment-ids x
-padded tails). Backward block sizes are tuned separately from the
-forward under the ``flash_attention_bwd`` autotune entry (the backward's
-VMEM footprint — full-row q/do/dq buffers plus the k-tile accumulators —
-admits different winners than the forward).
+kernel's exact tile walk — same per-tile dot shapes, same operand casts,
+same tile kinds, same accumulate order, same masks — so Pallas-interpret
+backward grads are BITWISE equal to the twin on CPU for every geometry
+(causal x GQA x segment-ids x padded tails).  Gradients leave the kernel
+in the caller's dtype (dq always; dk and dv unless a GQA group is summed
+outside, which gets them float32).  Backward block sizes are tuned
+separately from the forward under the ``flash_attention_bwd`` autotune
+entry (the backward's VMEM footprint — full-row q/do/dq buffers plus the
+k-tile accumulators — admits different winners than the forward).
 
 Public entry: ``flash_attention(q, k, v, causal=..., scale=...)`` in
 paddle's [batch, seq, num_heads, head_dim] layout, differentiable via
@@ -44,6 +63,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -51,17 +71,23 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 _LANE = 8  # trailing lane width for per-row stats (Mosaic tile alignment)
 
 
-def _block_sizes(sq, sk):
-    """Default (block_q, block_k). Measured on the v5e-class chip with the
-    dispatch-free scan-slope method (benchmarks/attn_sweep.py): 512x512 is
-    3-8x faster than 128x128 at b8/h12/s1024/d64 (fwd 0.41 ms vs 1.46 ms;
-    grad call 0.36-1.2 ms vs 2.96 ms) — bigger q/k tiles amortize the
-    per-block softmax/stat work over more MXU cycles. VMEM stays
-    comfortable: K/V are already held full-length per (batch, head)
-    program."""
-    bq = min(512, sq)
-    bk = min(512, sk)
-    return bq, bk
+def _block_sizes(sq, sk, causal):
+    """Default forward (block_q, block_k): 512 x 512 whatever ``causal``.
+    Measured 2026-09-29 on one TPU v5e (PR 32; the kernel alone in a
+    jitted scan of grad calls under the profiler, bfloat16, ms a call,
+    this kernel / the one before the PR at 512 x 512):
+    causal 8 x 16 x 1024 x 64 (GPT-2-medium) 0.581 / 0.601, against
+    256 x 512 0.603, 1024 x 1024 0.635, 1024 x 512 0.675, 256 x 256
+    0.839, 128 x 128 1.916; causal 2 x 32/8 x 8192 x 64 (LFM2) 9.60 /
+    9.89, against 256 x 1024 9.74, 512 x 1024 9.89, 1024 x 1024 10.16,
+    512 x 2048 10.92, 512 x 256 15.02; full 16 x 16 x 512 x 64
+    (BERT-large) 0.355 / 0.352, against 256 x 512 0.500, 256 x 256
+    0.665.  Narrow k blocks lose most: the per-step rescale of the
+    accumulator and the lane-sparse row statistics are paid per k block.
+    What did NOT pay there: a mask-free loop over the tiles wholly
+    under the diagonal beside the masked one made the forward 11%
+    (1,024 positions) and 4% (8,192) slower, so it has one body."""
+    return min(512, sq), min(512, sk)
 
 
 def _pad_to(x, axis, mult):
@@ -72,6 +98,81 @@ def _pad_to(x, axis, mult):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _scaled(x, scale):
+    """``x * scale`` rounded ONCE to x's dtype, the operand both score
+    products take.  Exact for float32, and for bfloat16 where the scale
+    is a power of two (head_dim 64: 1/8); elsewhere (head_dim 128) each
+    element of q moves by at most half a bfloat16 ulp, 2**-8 of itself
+    at most: the size of the rounding the caller's q already carries."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+# --------------------------------------------------------------------------
+# which tiles need what: ONE place decides the kernels' loop bounds and
+# predicates, the twin's walk and the ``flash.tiles`` gauges.  Each
+# function takes traced int32 scalars (inside a kernel) or numpy index
+# arrays (counting, at trace time) and does the same integer arithmetic.
+# --------------------------------------------------------------------------
+def _fwd_k_blocks(iq, *, causal, sq, sk, bq, bk):
+    """How many k blocks q block ``iq`` visits: those the block's LAST
+    row can see; the rest lie above the diagonal and are skipped."""
+    xp = np if isinstance(iq, np.ndarray) else jnp
+    nk = -(-sk // bk)
+    if not causal:
+        return xp.full_like(iq, nk)
+    return xp.clip(((iq + 1) * bq + (sk - sq) + bk - 1) // bk, 0, nk)
+
+
+def _fwd_masks(*, causal, has_seg, sk, bk):
+    """Whether the forward's tile body builds a mask: it does when some
+    tile of the call can need one (the diagonal, a padded k tail,
+    segment ids).  One body for every visited tile: the forward is not
+    bound by its vector work, and a mask-free loop beside the masked one
+    made it 4-11% slower on the chip (`_block_sizes`)."""
+    return bool(causal or has_seg or sk % bk)
+
+
+def _bwd_tile_kinds(ik, iq, *, causal, has_seg, sq, sk, bq, bk):
+    """(plain, masked) for the backward's (k block, q block) grid step;
+    a step that is neither is skipped.  Each is ``True`` / ``False``
+    where the shapes alone decide it for every step of the call."""
+    offset = sk - sq
+    # the tile's last row sees its first column
+    active = ((iq + 1) * bq - 1 + offset >= ik * bk) if causal else True
+    if has_seg:
+        return False, active
+    # the tile's first row sees its last column
+    plain = ((ik + 1) * bk - 1 <= iq * bq + offset) if causal else True
+    if sq % bq:
+        plain = plain & ((iq + 1) * bq <= sq)
+    if sk % bk:
+        plain = plain & ((ik + 1) * bk <= sk)
+    if plain is True:
+        return True, False
+    return plain, active & ~plain
+
+
+def _shape_sig(q_shape, sk, causal):
+    """A call's shape as the autotune cache and the gauges key it."""
+    b, h, sq, d = q_shape
+    return f"b{b}h{h}sq{sq}sk{sk}d{d}c{int(causal)}"
+
+
+def _publish_tiles(kernel, q_shape, sk, causal, has_seg, blocks, **kinds):
+    """Gauges ``flash.tiles{kernel, kind, shape}``: the tiles of each
+    kind one call of this program shape runs (batch x heads x a row's),
+    set while the call is traced — static counts, nothing in the step."""
+    from ...observability import metrics
+    shape = (f"{_shape_sig(q_shape, sk, causal)}s{int(has_seg)}"
+             f".{blocks[0]}x{blocks[1]}")
+    for kind, n in kinds.items():
+        metrics.registry().gauge(
+            "flash.tiles",
+            "score tiles a flash-attention call runs, by what they need",
+            labels={"kernel": kernel, "kind": kind, "shape": shape}
+        ).set(int(n) * q_shape[0] * q_shape[1])
 
 
 # --------------------------------------------------------------------------
@@ -94,41 +195,36 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
         o_ref, lse_ref = refs
         qs_ref = ks_ref = None
     iq = pl.program_id(2)
-    q = q_ref[0, 0].astype(jnp.float32) * scale       # [bq, D]
+    dt = q_ref.dtype                                   # the products' operands
+    q = _scaled(q_ref[0, 0], scale)                    # [bq, D]
     offset = sk - sq                                   # causal diagonal shift
-
-    nk = pl.cdiv(sk, bk)
-    if causal:
-        # last k block that the last row of this q block can see
-        hi = jnp.minimum(nk, ((iq + 1) * bq + offset + bk - 1) // bk)
-    else:
-        hi = nk
-
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-    cols0 = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    hi = _fwd_k_blocks(iq, causal=causal, sq=sq, sk=sk, bq=bq, bk=bk)
+    masked = _fwd_masks(causal=causal, has_seg=has_seg, sk=sk, bk=bk)
 
     def body(j, carry):
         m_i, l_i, acc = carry
-        kb = k_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
-        vb = v_ref[0, 0, pl.ds(j * bk, bk), :].astype(jnp.float32)
+        kb = k_ref[0, 0, pl.ds(j * bk, bk), :]
+        vb = v_ref[0, 0, pl.ds(j * bk, bk), :]
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)       # [bq, bk]
-        cols = cols0 + j * bk
-        mask = cols < sk                               # k padding
-        if causal:
-            mask = mask & (rows + offset >= cols)
-        if has_seg:
-            qs = qs_ref[0]                             # [bq, 1]
-            ks = ks_ref[0, :, pl.ds(j * bk, bk)]       # [1, bk]
-            mask = mask & (qs == ks)
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
+            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + j * bk
+            mask = cols < sk                           # k padding
+            if causal:
+                mask = mask & (rows + offset >= cols)
+            if has_seg:
+                qs = qs_ref[0]                         # [bq, 1]
+                ks = ks_ref[0, :, pl.ds(j * bk, bk)]   # [1, bk]
+                mask = mask & (qs == ks)
+            s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m_i, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)                         # [bq, bk]
         alpha = jnp.exp(m_i - m_new)                   # [bq, 1]
         l_new = l_i * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
+            p.astype(dt), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
@@ -149,13 +245,20 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
     hk, sk = k.shape[1], k.shape[2]
     rep = hq // hk
     has_seg = seg_q is not None
-    bq, bk = blocks if blocks is not None else _block_sizes(sq, sk)
+    bq, bk = (blocks if blocks is not None
+              else _block_sizes(sq, sk, causal))
     bq, bk = min(bq, sq), min(bk, sk)
     qp = _pad_to(q, 2, bq)
     kp = _pad_to(k, 2, bk)
     vp = _pad_to(v, 2, bk)
     sqp, skp = qp.shape[2], kp.shape[2]
     grid = (b, hq, sqp // bq)
+    ran = _fwd_k_blocks(np.arange(sqp // bq), causal=causal, sq=sq, sk=sk,
+                        bq=bq, bk=bk).sum()
+    masked = _fwd_masks(causal=causal, has_seg=has_seg, sk=sk, bk=bk)
+    _publish_tiles("fwd", q.shape, sk, causal, has_seg, (bq, bk),
+                   plain=0 if masked else ran, masked=ran if masked else 0,
+                   skipped=(sqp // bq) * (skp // bk) - ran)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk)
@@ -196,33 +299,42 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
 # --------------------------------------------------------------------------
 # backward
 # --------------------------------------------------------------------------
-def _bwd_block_sizes(sq, sk):
-    """Default backward (block_q, block_k). The fused kernel holds
-    full-row q/do/dq buffers regardless of the block pair, so the tile
-    choice trades MXU utilization against the dk/dv accumulator + k/v
-    tile footprint only; 512x512 matches the measured forward default
-    and is re-tuned per shape under the ``flash_attention_bwd`` autotune
-    entry."""
-    return min(512, sq), min(512, sk)
+def _bwd_block_sizes(sq, sk, causal):
+    """Default backward (block_q, block_k): 1024 x 1024 whatever
+    ``causal``.  Measured as in ``_block_sizes`` (ms a call, bfloat16):
+    causal 8 x 16 x 1024 x 64: 1024 x 1024 (one tile a row) 0.773,
+    1024 x 512 0.972, 512 x 1024 0.977, 512 x 512 1.061, 256 x 512
+    1.399, 256 x 256 1.748 (before the PR, 512 x 512: 1.186); causal
+    2 x 32/8 x 8192 x 64: 1024 x 1024 16.60, 512 x 1024 17.53,
+    1024 x 512 17.88, 512 x 512 18.63, 512 x 2048 18.86, 256 x 1024
+    19.07 (before: 23.13); full 16 x 16 x 512 x 64 is one 512 x 512
+    tile: 0.598 (before: 0.767).  A larger tile wastes more area above
+    the diagonal and still wins: fewer grid steps (at 8,192 and 512 x
+    512, 120 of a row's 256 are skipped ones) and more independent work
+    for the scheduler inside a step.  The whole-row q/do/dq buffers are
+    there regardless of the pair (``_bwd_vmem_limit``)."""
+    return min(1024, sq), min(1024, sk)
 
 
 _SCOPED_VMEM = 16 << 20     # what Mosaic gives a kernel unless told
-_TILE_VMEM = 8 << 20        # room for the k/v tiles, p, ds and dp
+_TILE_VMEM = 8 << 20        # least room for the k/v tiles, p, ds and dp
 
 
-def _bwd_vmem_limit(sqp, d, itemsize):
+def _bwd_vmem_limit(sqp, d, itemsize, bq, bk):
     """``vmem_limit_bytes`` for the fused backward, or None where the
     default holds it.  The kernel keeps the whole row of q, do, the
     lane-replicated lse and delta (double-buffered inputs), dq (a
     double-buffered output) and the dq accumulator resident, each
     padded to 128 lanes: 4.5 KB a position at head_dim 64 in bfloat16,
     so 4.7 MB at 1024 positions and 37.7 MB at 8192, which the chip's
-    compiler refuses under the 16 MB default (the v5e has 128 MiB)."""
+    compiler refuses under the 16 MB default (the v5e has 128 MiB).
+    Beside the rows, room for a tile's s, p, dp and ds: 32 bytes a score,
+    ``_TILE_VMEM`` up to 512 x 512."""
     def lanes(n):
         return -(-n // 128) * 128
     rows = sqp * (2 * 2 * lanes(d) * itemsize + 2 * 2 * lanes(_LANE) * 4
                   + 2 * lanes(d) * 4 + lanes(d) * 4)
-    need = rows + _TILE_VMEM
+    need = rows + max(_TILE_VMEM, 32 * bq * bk)
     return None if need <= _SCOPED_VMEM else need
 
 
@@ -246,7 +358,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     Causal tiles strictly above the diagonal are predicated off with
     ``pl.when`` (the skip that halves causal backward FLOPs); the
     zero-init/flush bookkeeping runs outside the predicate so padded or
-    never-attending rows still produce zeros.
+    never-attending rows still produce zeros.  The five products take
+    q, k, v, do as the refs hold them and ``p`` / ``ds`` cast to that
+    dtype, and accumulate in float32.
     """
     if has_seg:
         qs_ref, ks_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc \
@@ -268,53 +382,64 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[pl.ds(iq * bq, bq), :] = jnp.zeros(
             (bq, dq_acc.shape[-1]), jnp.float32)
 
-    if causal:
-        lo = jnp.maximum(0, (ik * bk - offset) // bq)  # first attending q
-        active = iq >= lo
-    else:
-        active = None
+    dt = q_ref.dtype                                   # the products' operands
 
-    def tile():
-        kb = k_ref[0, 0].astype(jnp.float32)           # [bk, D]
-        vb = v_ref[0, 0].astype(jnp.float32)
-        qb = q_ref[0, 0, pl.ds(iq * bq, bq), :].astype(jnp.float32) * scale
-        dob = do_ref[0, 0, pl.ds(iq * bq, bq), :].astype(jnp.float32)
+    def tile(masked):
+        kb = k_ref[0, 0]                               # [bk, D]
+        vb = v_ref[0, 0]
+        qb = q_ref[0, 0, pl.ds(iq * bq, bq), :]
+        dob = do_ref[0, 0, pl.ds(iq * bq, bq), :]
         lse = lse_ref[0, 0, pl.ds(iq * bq, bq), 0:1]   # [bq, 1]
         dlt = delta_ref[0, 0, pl.ds(iq * bq, bq), 0:1]
         s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
+            _scaled(qb, scale), kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [bq, bk]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
-        mask = (cols < sk) & (rows < sq)
-        if causal:
-            mask = mask & (rows + offset >= cols)
-        if has_seg:
-            qs = qs_ref[0, pl.ds(iq * bq, bq), :]      # [bq, 1]
-            ks = ks_ref[0, :, pl.ds(ik * bk, bk)]      # [1, bk]
-            mask = mask & (qs == ks)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)     # recomputed ONCE
+        p = jnp.exp(s - lse)                           # recomputed ONCE
+        if masked:
+            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
+            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + ik * bk
+            mask = (cols < sk) & (rows < sq)
+            if causal:
+                mask = mask & (rows + offset >= cols)
+            if has_seg:
+                qs = qs_ref[0, pl.ds(iq * bq, bq), :]  # [bq, 1]
+                ks = ks_ref[0, :, pl.ds(ik * bk, bk)]  # [1, bk]
+                mask = mask & (qs == ks)
+            p = jnp.where(mask, p, 0.0)
+        # dv and dk accumulate TRANSPOSED, [D, bk] = do^T p and q^T ds:
+        # the operand Mosaic has to turn for a product contracted over
+        # rows is then the [bq, D] one, not the [bq, bk] one
         dv_acc[...] += jax.lax.dot_general(
-            p, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bk, D]
+            dob, p.astype(dt), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [D, bk]
         dp = jax.lax.dot_general(
             dob, vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [bq, bk]
-        ds = p * (dp - dlt)                            # [bq, bk]
+        ds = (p * (dp - dlt)).astype(dt)               # [bq, bk]
         dk_acc[...] += jax.lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bk, D]
-        # accumulate UNSCALED: a fused multiply in the accumulate chain
+            qb, ds, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [D, bk]
+        # dk (above) and dq accumulate UNSCALED, from q and k as the
+        # refs hold them: a fused multiply in the accumulate chain
         # FMA-contracts under compilation and drifts the last ulp vs the
-        # unjitted twin; the single scale multiply happens at flush
+        # unjitted twin, and a q scaled and rounded again would cost dk
+        # a rounding at head dims whose scale is no power of two; the
+        # single scale multiply happens at flush
         dq_acc[pl.ds(iq * bq, bq), :] += jax.lax.dot_general(
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(active)(tile)
-    else:
-        tile()
+    # a grid step is plain (wholly under the diagonal, inside sq and sk,
+    # no segment ids: no iota, compare or where), masked, or skipped
+    # (wholly above the diagonal); a kind no step of this call can have
+    # is decided here and its body is not emitted
+    plain, masked = _bwd_tile_kinds(ik, iq, causal=causal, has_seg=has_seg,
+                                    sq=sq, sk=sk, bq=bq, bk=bk)
+    for kind, is_masked in ((plain, False), (masked, True)):
+        if kind is True:
+            tile(is_masked)
+        elif kind is not False:
+            pl.when(kind)(functools.partial(tile, is_masked))
 
     # flush dq once this q row's LAST attending k block has run. hi can
     # be <= 0 for rows that attend nothing (sq > sk rectangles): clamp
@@ -332,8 +457,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(iq == nq - 1)
     def _flush_kv():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[...].T * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].T.astype(dv_ref.dtype)
 
 
 def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
@@ -348,7 +473,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     # gets the pre-split behavior of one pair driving both directions
     bq, bk = (bwd_blocks if bwd_blocks is not None
               else blocks if blocks is not None
-              else _bwd_block_sizes(sq, sk))
+              else _bwd_block_sizes(sq, sk, causal))
     bq, bk = min(bq, sq), min(bk, sk)
 
     # delta_i = rowsum(dO * O): the FA2 precompute — one fused XLA reduce
@@ -390,7 +515,17 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
         ]
         args += [_pad_to(seg_q.astype(jnp.int32), 1, bq)[:, :, None],
                  _pad_to(seg_k.astype(jnp.int32), 1, bk)[:, None, :]]
-    limit = _bwd_vmem_limit(sqp, d, q.dtype.itemsize)
+    limit = _bwd_vmem_limit(sqp, d, q.dtype.itemsize, bq, bk)
+    plain, masked = (
+        np.broadcast_to(kind, (nk, nq)).sum() for kind in _bwd_tile_kinds(
+            np.arange(nk)[:, None], np.arange(nq)[None, :], causal=causal,
+            has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk))
+    _publish_tiles("bwd", q.shape, sk, causal, has_seg, (bq, bk),
+                   plain=plain, masked=masked,
+                   skipped=nk * nq - plain - masked)
+    # dq leaves in q's dtype; dk and dv too unless a GQA group is summed
+    # outside, which wants them float32 until that sum
+    kv_dtype = k.dtype if rep == 1 else jnp.float32
     dqh, dkh, dvh = pl.pallas_call(
         kernel,
         grid=(b, hq, nk, nq),
@@ -404,14 +539,14 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
                          lambda ib, ih, ikb, iqb: (ib, ih, ikb, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sqp, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, skp, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, skp, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sqp, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, skp, d), kv_dtype),
+            jax.ShapeDtypeStruct((b, hq, skp, d), kv_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((sqp, d), jnp.float32),   # dq rows (persistent)
-            pltpu.VMEM((bk, d), jnp.float32),    # dk accumulator
-            pltpu.VMEM((bk, d), jnp.float32),    # dv accumulator
+            pltpu.VMEM((d, bk), jnp.float32),    # dk accumulator, transposed
+            pltpu.VMEM((d, bk), jnp.float32),    # dv accumulator, transposed
         ],
         interpret=interpret,
         name="flash_attention_bwd",
@@ -424,7 +559,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
         dvh = dvh.reshape(b, hk, rep, skp, d).sum(axis=2)
     dk = dkh[:, :, :sk].astype(k.dtype)
     dv = dvh[:, :, :sk].astype(v.dtype)
-    dq = dqh[:, :, :sq].astype(q.dtype)
+    dq = dqh[:, :, :sq]
     return dq, dk, dv, None, None
 
 
@@ -464,8 +599,10 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
     hk, sk = k.shape[1], k.shape[2]
     rep = hq // hk
     has_seg = seg_q is not None
-    bq, bk = blocks if blocks is not None else _bwd_block_sizes(sq, sk)
+    bq, bk = (blocks if blocks is not None
+              else _bwd_block_sizes(sq, sk, causal))
     bq, bk = min(bq, sq), min(bk, sk)
+    dt = q.dtype
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     qp = _pad_to(q, 2, bq)
@@ -483,68 +620,71 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
         ksp = _pad_to(seg_k, 1, bk)
     offset = sk - sq
 
-    dqh = jnp.zeros((b, hq, sqp, d), jnp.float32)
-    dkh = jnp.zeros((b, hq, skp, d), jnp.float32)
-    dvh = jnp.zeros((b, hq, skp, d), jnp.float32)
+    kv_dtype = k.dtype if rep == 1 else jnp.float32
+    dqh = jnp.zeros((b, hq, sqp, d), q.dtype)
+    dkh = jnp.zeros((b, hq, skp, d), kv_dtype)
+    dvh = jnp.zeros((b, hq, skp, d), kv_dtype)
     for ib in range(b):
         for ih in range(hq):
             dq_acc = jnp.zeros((sqp, d), jnp.float32)
             for ik in range(nk):
-                kb = kp[ib, ih // rep,
-                        ik * bk:(ik + 1) * bk].astype(jnp.float32)
-                vb = vp[ib, ih // rep,
-                        ik * bk:(ik + 1) * bk].astype(jnp.float32)
-                dk_acc = jnp.zeros((bk, d), jnp.float32)
-                dv_acc = jnp.zeros((bk, d), jnp.float32)
-                lo = max(0, (ik * bk - offset) // bq) if causal else 0
+                kb = kp[ib, ih // rep, ik * bk:(ik + 1) * bk]
+                vb = vp[ib, ih // rep, ik * bk:(ik + 1) * bk]
+                dk_acc = jnp.zeros((d, bk), jnp.float32)
+                dv_acc = jnp.zeros((d, bk), jnp.float32)
                 for iq in range(nq):
-                    if iq < lo:
+                    plain, masked = _bwd_tile_kinds(
+                        np.asarray(ik), np.asarray(iq), causal=causal,
+                        has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk)
+                    if not (plain or masked):
                         continue
-                    qb = qp[ib, ih, iq * bq:(iq + 1) * bq] \
-                        .astype(jnp.float32) * scale
-                    dob = dop[ib, ih, iq * bq:(iq + 1) * bq] \
-                        .astype(jnp.float32)
+                    qb = qp[ib, ih, iq * bq:(iq + 1) * bq]
+                    dob = dop[ib, ih, iq * bq:(iq + 1) * bq]
                     lse_t = lsep[ib, ih, iq * bq:(iq + 1) * bq, 0:1]
                     dlt_t = dltp[ib, ih, iq * bq:(iq + 1) * bq, 0:1]
                     s = jax.lax.dot_general(
-                        qb, kb, (((1,), (1,)), ((), ())),
+                        _scaled(qb, scale), kb, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
-                    rows = (jax.lax.broadcasted_iota(
-                        jnp.int32, (bq, bk), 0) + iq * bq)
-                    cols = (jax.lax.broadcasted_iota(
-                        jnp.int32, (bq, bk), 1) + ik * bk)
-                    mask = (cols < sk) & (rows < sq)
-                    if causal:
-                        mask = mask & (rows + offset >= cols)
-                    if has_seg:
-                        qs = qsp[ib, iq * bq:(iq + 1) * bq]
-                        ks = ksp[ib, ik * bk:(ik + 1) * bk]
-                        mask = mask & (qs[:, None] == ks[None, :])
-                    p = jnp.where(mask, jnp.exp(s - lse_t), 0.0)
+                    p = jnp.exp(s - lse_t)
+                    if masked:
+                        rows = (jax.lax.broadcasted_iota(
+                            jnp.int32, (bq, bk), 0) + iq * bq)
+                        cols = (jax.lax.broadcasted_iota(
+                            jnp.int32, (bq, bk), 1) + ik * bk)
+                        mask = (cols < sk) & (rows < sq)
+                        if causal:
+                            mask = mask & (rows + offset >= cols)
+                        if has_seg:
+                            qs = qsp[ib, iq * bq:(iq + 1) * bq]
+                            ks = ksp[ib, ik * bk:(ik + 1) * bk]
+                            mask = mask & (qs[:, None] == ks[None, :])
+                        p = jnp.where(mask, p, 0.0)
                     dv_acc = dv_acc + jax.lax.dot_general(
-                        p, dob, (((0,), (0,)), ((), ())),
+                        dob, p.astype(dt), (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
                     dp = jax.lax.dot_general(
                         dob, vb, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)
-                    ds = p * (dp - dlt_t)
+                    ds = (p * (dp - dlt_t)).astype(dt)
                     dk_acc = dk_acc + jax.lax.dot_general(
-                        ds, qb, (((0,), (0,)), ((), ())),
+                        qb, ds, (((0,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
                     dq_acc = dq_acc.at[iq * bq:(iq + 1) * bq].set(
                         dq_acc[iq * bq:(iq + 1) * bq]
                         + jax.lax.dot_general(
                             ds, kb, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
-                dkh = dkh.at[ib, ih, ik * bk:(ik + 1) * bk].set(dk_acc)
-                dvh = dvh.at[ib, ih, ik * bk:(ik + 1) * bk].set(dv_acc)
-            dqh = dqh.at[ib, ih].set(dq_acc * scale)
+                dkh = dkh.at[ib, ih, ik * bk:(ik + 1) * bk].set(
+                    (dk_acc.T * scale).astype(kv_dtype))
+                dvh = dvh.at[ib, ih, ik * bk:(ik + 1) * bk].set(
+                    dv_acc.T.astype(kv_dtype))
+            dqh = dqh.at[ib, ih].set((dq_acc * scale).astype(q.dtype))
     if rep > 1:
         dkh = dkh.reshape(b, hk, rep, skp, d).sum(axis=2)
         dvh = dvh.reshape(b, hk, rep, skp, d).sum(axis=2)
     dk = dkh[:, :, :sk].astype(k.dtype)
     dv = dvh[:, :, :sk].astype(v.dtype)
-    dq = dqh[:, :, :sq].astype(q.dtype)
+    dq = dqh[:, :, :sq]
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
             jnp.swapaxes(dv, 1, 2))
 
@@ -571,13 +711,13 @@ _flash_bhsd.defvjp(_flash_fwd_rule, _bwd)
 _TUNE_CANDIDATES = ((128, 128), (256, 256), (256, 512), (512, 256),
                     (512, 512), (512, 1024), (1024, 512), (1024, 1024))
 # backward candidates: the fused backward kernel carries full-row
-# q/do/dq VMEM buffers plus per-k-block dk/dv accumulators — a larger
-# fixed footprint than the forward (the old shared-candidate scheme let
-# the backward inherit forward-biased winners; see the validate() note
-# below) — so the sweep stays at or below 512x512 tiles where the
-# accumulators plus the k/v tiles cannot tip a full-row budget over.
+# q/do/dq VMEM buffers plus per-k-block dk/dv accumulators and asks for
+# its own VMEM limit by block pair (``_bwd_vmem_limit``), so the sweep
+# reaches the measured default, one 1024 x 1024 tile; beyond it the
+# tile's temporaries near the v5e's 128 MiB and lost on the chip.
 _TUNE_BWD_CANDIDATES = ((128, 128), (128, 256), (256, 128), (256, 256),
-                        (256, 512), (512, 256), (512, 512))
+                        (256, 512), (512, 256), (512, 512), (512, 1024),
+                        (1024, 512), (1024, 1024))
 
 
 def _scan_slope(make_runner, args, r1=4, r2=24):
@@ -616,12 +756,11 @@ def _tuned_entry(entry, candidates, qt, kt, causal, make_runner,
     defaults rather than crashing the call (nothing is cached, so a
     later quieter run can still tune)."""
     from . import autotune as at
-    b, h, sq, d = qt.shape
-    sk = kt.shape[2]
+    sq, sk = qt.shape[2], kt.shape[2]
     cands = [c for c in candidates if c[0] <= sq and c[1] <= sk]
     if len(cands) <= 1:
         return None
-    sig = f"b{b}h{h}sq{sq}sk{sk}d{d}c{int(causal)}"
+    sig = _shape_sig(qt.shape, sk, causal)
     cached = at._load_cache().get(f"{at._device_kind()}|{entry}|{sig}")
     if cached is not None:
         for c in cands:
@@ -682,9 +821,7 @@ def _autotuned_blocks(qt, kt, scale, causal):
 def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks):
     """BACKWARD block-size selection: its own ``flash_attention_bwd``
     autotune entry over backward-specific candidates
-    (``_TUNE_BWD_CANDIDATES`` — the fused kernel's VMEM footprint is
-    larger than the forward's, so forward-biased 1024-tile candidates
-    are excluded up front). The timed program is the full fwd+bwd chain
+    (``_TUNE_BWD_CANDIDATES``). The timed program is the full fwd+bwd chain
     with the FORWARD blocks pinned to the already-tuned winner: the
     forward term is constant across candidates, so the slope ranks the
     backward kernels alone."""

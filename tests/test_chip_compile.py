@@ -234,17 +234,30 @@ def test_engine_tp_program_compiles(topo, served, program, monkeypatch):
 
 
 # ------------------------------------------------------ the trainer's path
-def test_flash_attention_fwd_bwd_compiles(chip):
-    q = chip((8, 1024, 12, 64), BF16)
+@pytest.mark.parametrize("shape,causal", [
+    pytest.param((8, 1024, 12, 64), True, id="gpt-124m"),
+    pytest.param((8, 1024, 16, 64), True, id="gpt2-medium"),
+    pytest.param((16, 512, 16, 64), False, id="bert-large"),
+])
+def test_flash_attention_fwd_bwd_compiles(chip, shape, causal):
+    """Forward + backward under the block pairs the defaults pick:
+    ``chip_smoke.py``'s 12 heads, ``gpt2-medium.pretrain``'s own shape,
+    and BERT-large's rows (not causal: every tile plain)."""
+    q = chip(shape, BF16)
 
     def grads(q, k, v):
         return jax.grad(
             lambda q, k, v: FA.flash_attention(
-                q, k, v, causal=True, interpret=False).astype(F32).sum(),
+                q, k, v, causal=causal, interpret=False).astype(F32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
     text = chip.compile(grads, q, q, q).as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    # one 1024 x 1024 backward tile a row asks for its VMEM (the tile's
+    # s, p, dp and ds), BERT's 512 x 512 fits the default
+    limit = FA._bwd_vmem_limit(
+        shape[1], 64, 2, *FA._bwd_block_sizes(shape[1], shape[1], causal))
+    assert (limit is None) == (shape[1] == 512)
 
 
 def test_flash_attention_gqa_8k_fwd_bwd_compiles(chip):
@@ -252,8 +265,7 @@ def test_flash_attention_gqa_8k_fwd_bwd_compiles(chip):
     on 8 key/value heads of 64, 8192 positions.  The fused backward
     holds a whole row of q, do, lse, delta and dq in VMEM, 37.7 MB
     here, which the compiler refuses under its 16 MB default: the
-    kernel asks for what it needs (``_bwd_vmem_limit``), and at 1024
-    positions asks for nothing."""
+    kernel asks for what it needs (``_bwd_vmem_limit``)."""
     q, kv = chip((2, 8192, 32, 64), BF16), chip((2, 8192, 8, 64), BF16)
 
     def grads(q, k, v):
@@ -264,8 +276,8 @@ def test_flash_attention_gqa_8k_fwd_bwd_compiles(chip):
 
     text = chip.compile(grads, q, kv, kv).as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
-    assert FA._bwd_vmem_limit(8192, 64, 2) > 37 << 20
-    assert FA._bwd_vmem_limit(1024, 64, 2) is None
+    assert FA._bwd_vmem_limit(
+        8192, 64, 2, *FA._bwd_block_sizes(8192, 8192, True)) > 37 << 20
 
 
 def test_sparse_moe_grouped_products_compile(chip):

@@ -186,11 +186,12 @@ def test_fused_bwd_distinct_blocks_same_values():
 
 def test_bwd_autotune_candidates_registered():
     """The flash_attention_bwd entry exists with backward-specific
-    candidates bounded at 512 tiles (the vmem-footprint rationale) and
-    the public API threads bwd_blocks through."""
-    assert fa._TUNE_BWD_CANDIDATES
-    assert max(c[0] for c in fa._TUNE_BWD_CANDIDATES) <= 512
-    assert max(c[1] for c in fa._TUNE_BWD_CANDIDATES) <= 512
+    candidates that reach the measured default and no further (the
+    vmem-footprint rationale), and the public API threads bwd_blocks
+    through."""
+    assert fa._bwd_block_sizes(8192, 8192, True) in fa._TUNE_BWD_CANDIDATES
+    assert max(c[0] for c in fa._TUNE_BWD_CANDIDATES) <= 1024
+    assert max(c[1] for c in fa._TUNE_BWD_CANDIDATES) <= 1024
     # a cached winner under the entry is honored on a later call
     import paddle_tpu.ops.pallas.autotune as at
     key = f"{at._device_kind()}|flash_attention_bwd|b1h2sq512sk512d16c1"
@@ -204,3 +205,188 @@ def test_bwd_autotune_candidates_registered():
     finally:
         cache.clear()
         cache.update(old)
+
+
+# ------------------------------------------- what a tile is given (PR 32) --
+# geometries at block pairs where plain, masked and skipped tiles all occur
+# (the causal ones), and where a kind is missing altogether:
+# (hq, hk, sq, sk, d, causal, blocks, segments)
+_TILE_GEOMETRIES = [
+    pytest.param(2, 2, 128, 128, 32, True, (32, 32), False, id="causal"),
+    pytest.param(2, 2, 100, 100, 32, False, (32, 32), False,
+                 id="full-padded-tails"),
+    pytest.param(4, 2, 112, 112, 32, True, (32, 32), False,
+                 id="gqa-causal-padded"),
+    pytest.param(2, 2, 64, 128, 32, True, (32, 32), False, id="sq-lt-sk"),
+    pytest.param(2, 2, 128, 128, 32, True, (32, 64), False,
+                 id="causal-wide-k"),
+    pytest.param(2, 2, 128, 128, 32, True, (32, 32), True, id="segments"),
+    pytest.param(4, 1, 64, 64, 32, False, (32, 32), False,
+                 id="gqa-full-all-plain"),
+    pytest.param(2, 1, 96, 96, 128, True, (32, 32), False,
+                 id="head-dim-128"),
+]
+
+
+def _tile_inputs(hq, hk, sq, sk, d, segments, dtype=jnp.bfloat16):
+    q = _rand((1, sq, hq, d), dtype, seed=31, scale=1.0)
+    k = _rand((1, sk, hk, d), dtype, seed=32, scale=1.0)
+    v = _rand((1, sk, hk, d), dtype, seed=33, scale=1.0)
+    w = _rand((1, sq, hq, d), jnp.float32, seed=34, scale=1.0)
+    seg = None
+    if segments:
+        seg = jnp.asarray(np.sort(
+            np.random.default_rng(35).integers(0, 3, (1, sq)), axis=1))
+    return q, k, v, w, seg
+
+
+def _count_tiles(kernel, sq, sk, causal, blocks, segments):
+    """Brute force over the elements: a tile is skipped when causal and
+    none of its elements lies on or under the diagonal.  The backward
+    runs a tile plain when all of its elements do, all are in range and
+    there are no segment ids, and masked otherwise; the forward has one
+    body for the tiles of a call, masked when any of them can need it."""
+    bq, bk = blocks
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    rows = np.arange(nq * bq)[:, None]
+    cols = np.arange(nk * bk)[None, :]
+    under = (rows + (sk - sq) >= cols) if causal \
+        else np.ones((nq * bq, nk * bk), bool)
+    inside = (cols < sk) & (rows < sq)
+    n = {"plain": 0, "masked": 0, "skipped": 0}
+    for iq in range(nq):
+        for ik in range(nk):
+            t = np.s_[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+            if not under[t].any():
+                n["skipped"] += 1
+            elif kernel == "fwd":
+                n["masked" if causal or segments or sk % bk
+                  else "plain"] += 1
+            elif not segments and (under[t] & inside[t]).all():
+                n["plain"] += 1
+            else:
+                n["masked"] += 1
+    return n
+
+
+@pytest.mark.parametrize("hq,hk,sq,sk,d,causal,blocks,segments",
+                         _TILE_GEOMETRIES)
+def test_tile_gauges_equal_a_brute_force_count(hq, hk, sq, sk, d, causal,
+                                               blocks, segments):
+    """``flash.tiles{kernel, kind, shape}`` is what the loop bounds and
+    the predicates make of the geometry: tiles a call, batch x heads x
+    a row's."""
+    from paddle_tpu.observability import metrics
+    q, k, v, w, seg = _tile_inputs(hq, hk, sq, sk, d, segments)
+    jax.grad(lambda a: (fa.flash_attention(
+        a, k, v, causal=causal, interpret=True, blocks=blocks,
+        bwd_blocks=blocks, segment_ids=seg).astype(jnp.float32)
+        * w).sum())(q)
+    shape = (f"b1h{hq}sq{sq}sk{sk}d{d}c{int(causal)}s{int(segments)}"
+             f".{blocks[0]}x{blocks[1]}")
+    for kernel in ("fwd", "bwd"):
+        want = _count_tiles(kernel, sq, sk, causal, blocks, segments)
+        got = {kind: metrics.registry().gauge(
+            "flash.tiles", labels={"kernel": kernel, "kind": kind,
+                                   "shape": shape}).value / hq
+            for kind in want}
+        assert got == want, (kernel, got, want)
+    if causal and not segments and d != 128:
+        assert all(want.values()), want     # the backward has every kind
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("hq,hk,sq,sk,d,causal,blocks,segments",
+                         _TILE_GEOMETRIES)
+def test_bf16_grads_as_close_as_unfused_bf16(hq, hk, sq, sk, d, causal,
+                                             blocks, segments):
+    """bfloat16 operands into every product (float32 accumulation and
+    statistics): each gradient's relative error against float32
+    attention is within 1.25 x that of the repo's unfused bfloat16
+    attention, which also rounds the probabilities before its second
+    product; and the two bfloat16 paths differ by no more than their
+    errors together."""
+    from paddle_tpu.nn.functional.attention import _sdpa_xla
+    q, k, v, w, seg = _tile_inputs(hq, hk, sq, sk, d, segments)
+    mask = None if seg is None else \
+        (seg[:, :, None] == seg[:, None, :])[:, None]
+
+    def grads(fn, *xs):
+        return jax.grad(lambda a, b, c: (
+            fn(a, b, c).astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2))(*xs)
+
+    kernel = grads(lambda a, b, c: fa.flash_attention(
+        a, b, c, causal=causal, interpret=True, blocks=blocks,
+        bwd_blocks=blocks, segment_ids=seg), q, k, v)
+    unfused = grads(lambda a, b, c: _sdpa_xla(
+        a, b, c, mask=mask, causal=causal), q, k, v)
+    exact = grads(lambda a, b, c: _sdpa_xla(
+        a, b, c, mask=mask, causal=causal),
+        *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, gk, gu, ge in zip(("dq", "dk", "dv"), kernel, unfused, exact):
+        assert gk.dtype == jnp.bfloat16, name
+        err_k, err_u = _rel(gk, ge), _rel(gu, ge)
+        assert err_k <= 1.25 * err_u, (name, err_k, err_u)
+        assert _rel(gk, gu) <= err_k + err_u, name
+
+
+def _kernel_dots(fn, *args):
+    """(operand dtypes, result dtype) of every ``dot_general`` inside
+    the Pallas kernels of ``fn``'s jaxpr, loops and branches included."""
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            if inside and eqn.primitive.name == "dot_general":
+                yield (tuple(str(x.aval.dtype) for x in eqn.invars),
+                       str(eqn.outvars[0].aval.dtype))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(
+                    sub, inside or eqn.primitive.name == "pallas_call")
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr, False))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_kernel_products_take_the_tensors_dtype(direction, dtype):
+    """Every product of both kernels takes operands in the inputs'
+    dtype and accumulates in float32: two products in the forward's
+    tile body, five in each of the backward's two (plain and masked)."""
+    q, k, v, w, _ = _tile_inputs(2, 2, 128, 128, 32, False,
+                                 jnp.dtype(dtype))
+
+    def forward(a, b, c):
+        return fa.flash_attention(a, b, c, causal=True, interpret=True,
+                                  blocks=(32, 32), bwd_blocks=(32, 32))
+
+    if direction == "forward":
+        dots = _kernel_dots(forward, q, k, v)
+    else:
+        both = _kernel_dots(jax.grad(lambda a, b, c: (
+            forward(a, b, c).astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2)), q, k, v)
+        dots = both[2:]
+        assert both[:2] == _kernel_dots(forward, q, k, v)
+    assert len(dots) == (2 if direction == "forward" else 10)
+    assert set(dots) == {((dtype, dtype), "float32")}, dots
+
+
+def test_scaled_q_is_rounded_once_and_within_half_an_ulp():
+    """``q * scale`` becomes a product operand: exact in float32 and,
+    at a power-of-two scale (head_dim 64), in bfloat16; at head_dim 128
+    within half a bfloat16 ulp (at most 2**-8 of the value)."""
+    x32 = _rand((64, 128), jnp.float32, seed=41, scale=1.0)
+    x16 = x32.astype(jnp.bfloat16)
+    for scale in (0.125, 1.0 / math.sqrt(128)):
+        assert np.array_equal(fa._scaled(x32, scale), x32 * scale)
+        assert fa._scaled(x16, scale).dtype == jnp.bfloat16
+        got = np.asarray(fa._scaled(x16, scale), np.float64)
+        want = np.asarray(x16, np.float64) * scale
+        if scale == 0.125:
+            assert np.array_equal(got, want)
+        else:
+            assert (np.abs(got - want) <= 1.001 * 2.0 ** -8 * np.abs(want)).all()
+            assert not np.array_equal(got, want)

@@ -219,6 +219,41 @@ def test_flash_attention_bf16():
                                ref.astype(jnp.float32), atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("sq,sk,causal,blocks", [
+    pytest.param(128, 128, True, (32, 32), id="causal"),
+    pytest.param(64, 128, True, (32, 64), id="causal-sq-lt-sk"),
+    pytest.param(100, 100, True, (32, 32), id="causal-padded"),
+    pytest.param(100, 100, False, (32, 32), id="full-padded"),
+    pytest.param(128, 128, False, (32, 32), id="full-all-plain"),
+])
+def test_flash_attention_plain_tiles_equal_masked_tiles(sq, sk, causal,
+                                                        blocks, dtype):
+    """The backward runs a tile wholly under the diagonal through a body
+    with no mask, and the forward of a call no tile of which can need a
+    mask has none; under segment ids every tile builds its mask, as
+    every tile did before the kinds were told apart.  With one segment
+    the two calls do the same arithmetic: outputs and gradients are
+    equal to the bit."""
+    q = _rand((1, sq, 2, 32), dtype, seed=51)
+    k = _rand((1, sk, 2, 32), dtype, seed=52)
+    v = _rand((1, sk, 2, 32), dtype, seed=53)
+    one = (jnp.zeros((1, sq), jnp.int32), jnp.zeros((1, sk), jnp.int32))
+
+    def run(seg):
+        return jax.value_and_grad(lambda a, b, c: fa.flash_attention(
+            a, b, c, causal=causal, interpret=True, blocks=blocks,
+            bwd_blocks=blocks, segment_ids=seg).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    (out, grads), (out_m, grads_m) = run(None), run(one)
+    assert np.array_equal(out, out_m)
+    for g, gm in zip(grads, grads_m):
+        assert g.dtype == jnp.dtype(dtype)
+        assert np.array_equal(np.asarray(g, np.float32),
+                              np.asarray(gm, np.float32))
+
+
 # --------------------------------------------------------------------------
 def _ref_rms(x, w, eps=1e-6):
     x32 = x.astype(jnp.float32)
