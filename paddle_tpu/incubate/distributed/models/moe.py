@@ -296,13 +296,16 @@ _routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
 
 
 def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
-               scaling=1.0):
+               scaling=1.0, norm_eps=1e-6):
     """Values in, ``(out, tally, chunks)`` out; the math of
     ``SparseMoEBlock``.
 
     ``x`` [..., H]; ``gate`` [H, E] over all E experts; ``w1``/``w3``
     [held, H, I] and ``w2`` [held, I, H] are the experts
-    ``expert_offset .. expert_offset + held`` ; ``bias`` [E] float32.
+    ``expert_offset .. expert_offset + held`` ; ``bias`` [E] float32;
+    ``norm_eps`` is added to the selected scores' sum before they are
+    divided by it (the caller's family's: 1e-6 ``lfm2_moe``, 1e-20
+    ``deepseek_v3``).
     ``tally`` is int32 [held + 1]: the slots routed to each held expert
     and, last, the slots the router filled (N * top_k).  ``chunks`` is
     int32 [2]: the chunks of sorted slots whose rows were worked on, and
@@ -317,7 +320,8 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
         _, chosen = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
-        weight = picked / (picked.sum(-1, keepdims=True) + 1e-6) * scaling
+        weight = picked / (picked.sum(-1, keepdims=True) + norm_eps) \
+            * scaling
     with _scope.phase("dispatch"):
         local = chosen - expert_offset                        # [N, k]
         here = (local >= 0) & (local < held)
@@ -381,8 +385,9 @@ class SparseMoEBlock(Layer):
     The router scores ALL ``num_experts`` (sigmoid, float32); selection
     adds ``expert_bias`` (a float32 buffer no gradient reaches: the
     trainer's balancing rule owns it), the combine weights are the
-    selected scores normalised to sum to one, times
-    ``routed_scaling_factor``.  The block holds the SwiGLU experts
+    selected scores normalised to sum to one (their sum plus
+    ``norm_eps``), times ``routed_scaling_factor``.  The block holds
+    the SwiGLU experts
     ``expert_offset .. expert_offset + experts_held`` stacked
     ``[held, ...]`` and returns THEIR part of the layer's result: the
     slots routed here are gathered in expert order and multiplied group
@@ -404,7 +409,8 @@ class SparseMoEBlock(Layer):
     def __init__(self, hidden_size, intermediate_size, num_experts, top_k,
                  expert_offset=0, experts_held=None,
                  routed_scaling_factor=1.0, expert_bias=None,
-                 weight_attr=None, down_attr=None, name=None):
+                 weight_attr=None, down_attr=None, name=None,
+                 norm_eps=1e-6):
         super().__init__()
         held = num_experts - expert_offset if experts_held is None \
             else experts_held
@@ -418,6 +424,7 @@ class SparseMoEBlock(Layer):
         self.num_experts, self.top_k = num_experts, top_k
         self.expert_offset, self.experts_held = expert_offset, held
         self.routed_scaling_factor = float(routed_scaling_factor)
+        self.norm_eps = float(norm_eps)
         self.gate = Linear(hidden_size, num_experts, bias_attr=False,
                            weight_attr=weight_attr)
         h, i = hidden_size, intermediate_size
@@ -510,6 +517,7 @@ class SparseMoEBlock(Layer):
             "sparse_moe",
             functools.partial(sparse_moe, top_k=self.top_k,
                               expert_offset=self.expert_offset,
-                              scaling=self.routed_scaling_factor),
+                              scaling=self.routed_scaling_factor,
+                              norm_eps=self.norm_eps),
             x, self.gate.weight, self.w1, self.w3, self.w2,
             bias=self.expert_bias)

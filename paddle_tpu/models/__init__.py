@@ -17,3 +17,5 @@ from . import generation  # noqa: F401
 from .generation import generate  # noqa: F401
 from . import lfm2  # noqa: F401
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM  # noqa: F401
+from . import deepseek_v3  # noqa: F401
+from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM  # noqa: F401

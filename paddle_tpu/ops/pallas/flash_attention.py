@@ -53,7 +53,11 @@ k-tile accumulators — admits different winners than the forward).
 
 Public entry: ``flash_attention(q, k, v, causal=..., scale=...)`` in
 paddle's [batch, seq, num_heads, head_dim] layout, differentiable via
-``jax.custom_vjp``.
+``jax.custom_vjp``.  ``k``'s head_dim is ``q``'s (the scores contract over
+it); ``v``'s is its own: ``o``, ``do`` and ``dv`` are as wide as ``v``,
+``dq`` and ``dk`` as wide as ``q`` (latent attention: 192-wide keys, 128-wide
+values).  Every tensor keeps its own width in HBM; a width that is no
+multiple of the 128 lanes is padded by Mosaic inside VMEM only.
 """
 from __future__ import annotations
 
@@ -154,18 +158,21 @@ def _bwd_tile_kinds(ik, iq, *, causal, has_seg, sq, sk, bq, bk):
     return plain, active & ~plain
 
 
-def _shape_sig(q_shape, sk, causal):
-    """A call's shape as the autotune cache and the gauges key it."""
+def _shape_sig(q_shape, sk, causal, dv=None):
+    """A call's shape as the autotune cache and the gauges key it; the
+    values' width ``dv`` is named only where it is not the keys'."""
     b, h, sq, d = q_shape
-    return f"b{b}h{h}sq{sq}sk{sk}d{d}c{int(causal)}"
+    width = f"d{d}" if dv in (None, d) else f"d{d}v{dv}"
+    return f"b{b}h{h}sq{sq}sk{sk}{width}c{int(causal)}"
 
 
-def _publish_tiles(kernel, q_shape, sk, causal, has_seg, blocks, **kinds):
+def _publish_tiles(kernel, q_shape, dv, sk, causal, has_seg, blocks,
+                   **kinds):
     """Gauges ``flash.tiles{kernel, kind, shape}``: the tiles of each
     kind one call of this program shape runs (batch x heads x a row's),
     set while the call is traced — static counts, nothing in the step."""
     from ...observability import metrics
-    shape = (f"{_shape_sig(q_shape, sk, causal)}s{int(has_seg)}"
+    shape = (f"{_shape_sig(q_shape, sk, causal, dv)}s{int(has_seg)}"
              f".{blocks[0]}x{blocks[1]}")
     for kind, n in kinds.items():
         metrics.registry().gauge(
@@ -181,9 +188,10 @@ def _publish_tiles(kernel, q_shape, sk, causal, has_seg, blocks, **kinds):
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
                 sq, sk, bq, bk):
     """One (batch, q-head, q-block) program: stream k/v blocks with online
-    softmax. Block shapes: q/o [1,1,bq,D]; k/v [1,1,Skp,D]; lse
-    [1,1,bq,LANE] (Mosaic needs the trailing dims tile-aligned, so the
-    per-row logsumexp is replicated across a small lane axis). With
+    softmax. Block shapes: q [1,1,bq,D]; k [1,1,Skp,D]; v [1,1,Skp,Dv];
+    o [1,1,bq,Dv]; lse [1,1,bq,LANE] (Mosaic needs the trailing dims
+    tile-aligned, so the per-row logsumexp is replicated across a small
+    lane axis). With
     ``has_seg``, per-token segment ids (q a COLUMN [1,bq,1], kv a ROW
     [1,1,Skp] — the two layouts the mask compares without a relayout,
     and blocks whose minor dims Mosaic accepts) confine
@@ -230,7 +238,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    a0 = jnp.zeros((bq, q.shape[-1]), jnp.float32)
+    a0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
     m_f, l_f, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, a0))
 
     l_safe = jnp.where(l_f == 0.0, 1.0, l_f)           # padded q rows
@@ -239,10 +247,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
 
 
 def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
-    """q [B,Hq,Sq,D]; k,v [B,Hk,Sk,D]; seg_q/seg_k optional [B,Sq]/[B,Sk]
-    int32 segment ids -> (o [B,Hq,Sq,D], lse [B,Hq,Sq])."""
+    """q [B,Hq,Sq,D]; k [B,Hk,Sk,D]; v [B,Hk,Sk,Dv]; seg_q/seg_k
+    optional [B,Sq]/[B,Sk] int32 segment ids -> (o [B,Hq,Sq,Dv], lse
+    [B,Hq,Sq])."""
     b, hq, sq, d = q.shape
-    hk, sk = k.shape[1], k.shape[2]
+    hk, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = hq // hk
     has_seg = seg_q is not None
     bq, bk = (blocks if blocks is not None
@@ -256,7 +265,7 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
     ran = _fwd_k_blocks(np.arange(sqp // bq), causal=causal, sq=sq, sk=sk,
                         bq=bq, bk=bk).sum()
     masked = _fwd_masks(causal=causal, has_seg=has_seg, sk=sk, bk=bk)
-    _publish_tiles("fwd", q.shape, sk, causal, has_seg, (bq, bk),
+    _publish_tiles("fwd", q.shape, dv, sk, causal, has_seg, (bq, bk),
                    plain=0 if masked else ran, masked=ran if masked else 0,
                    skipped=(sqp // bq) * (skp // bk) - ran)
 
@@ -266,7 +275,7 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
         pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq: (ib, ih, iq, 0)),
         pl.BlockSpec((1, 1, skp, d),
                      lambda ib, ih, iq, _rep=rep: (ib, ih // _rep, 0, 0)),
-        pl.BlockSpec((1, 1, skp, d),
+        pl.BlockSpec((1, 1, skp, dv),
                      lambda ib, ih, iq, _rep=rep: (ib, ih // _rep, 0, 0)),
     ]
     args = [qp, kp, vp]
@@ -282,12 +291,12 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bq, dv), lambda ib, ih, iq: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq, _LANE),
                          lambda ib, ih, iq: (ib, ih, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sqp, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sqp, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sqp, _LANE), jnp.float32),
         ],
         interpret=interpret,
@@ -320,19 +329,23 @@ _SCOPED_VMEM = 16 << 20     # what Mosaic gives a kernel unless told
 _TILE_VMEM = 8 << 20        # least room for the k/v tiles, p, ds and dp
 
 
-def _bwd_vmem_limit(sqp, d, itemsize, bq, bk):
+def _bwd_vmem_limit(sqp, d, itemsize, bq, bk, dv=None):
     """``vmem_limit_bytes`` for the fused backward, or None where the
-    default holds it.  The kernel keeps the whole row of q, do, the
-    lane-replicated lse and delta (double-buffered inputs), dq (a
-    double-buffered output) and the dq accumulator resident, each
-    padded to 128 lanes: 4.5 KB a position at head_dim 64 in bfloat16,
-    so 4.7 MB at 1024 positions and 37.7 MB at 8192, which the chip's
-    compiler refuses under the 16 MB default (the v5e has 128 MiB).
+    default holds it.  The kernel keeps the whole row of q (``d`` wide),
+    do (``dv`` wide), the lane-replicated lse and delta (double-buffered
+    inputs), dq (a double-buffered output) and the dq accumulator
+    resident, each padded to 128 lanes: 4.5 KB a position at head_dim 64
+    in bfloat16, so 4.7 MB at 1024 positions and 37.7 MB at 8192, which
+    the chip's compiler refuses under the 16 MB default (the v5e has
+    128 MiB); 6.5 KB a position at 192-wide keys and 128-wide values
+    (q, dq and the accumulator padded to 256 lanes), 54.5 MB at 8192.
     Beside the rows, room for a tile's s, p, dp and ds: 32 bytes a score,
     ``_TILE_VMEM`` up to 512 x 512."""
     def lanes(n):
         return -(-n // 128) * 128
-    rows = sqp * (2 * 2 * lanes(d) * itemsize + 2 * 2 * lanes(_LANE) * 4
+    dv = d if dv is None else dv
+    rows = sqp * (2 * (lanes(d) + lanes(dv)) * itemsize
+                  + 2 * 2 * lanes(_LANE) * 4
                   + 2 * lanes(d) * 4 + lanes(d) * 4)
     need = rows + max(_TILE_VMEM, 32 * bq * bk)
     return None if need <= _SCOPED_VMEM else need
@@ -386,9 +399,9 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def tile(masked):
         kb = k_ref[0, 0]                               # [bk, D]
-        vb = v_ref[0, 0]
-        qb = q_ref[0, 0, pl.ds(iq * bq, bq), :]
-        dob = do_ref[0, 0, pl.ds(iq * bq, bq), :]
+        vb = v_ref[0, 0]                               # [bk, Dv]
+        qb = q_ref[0, 0, pl.ds(iq * bq, bq), :]        # [bq, D]
+        dob = do_ref[0, 0, pl.ds(iq * bq, bq), :]      # [bq, Dv]
         lse = lse_ref[0, 0, pl.ds(iq * bq, bq), 0:1]   # [bq, 1]
         dlt = delta_ref[0, 0, pl.ds(iq * bq, bq), 0:1]
         s = jax.lax.dot_general(
@@ -406,12 +419,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ks = ks_ref[0, :, pl.ds(ik * bk, bk)]  # [1, bk]
                 mask = mask & (qs == ks)
             p = jnp.where(mask, p, 0.0)
-        # dv and dk accumulate TRANSPOSED, [D, bk] = do^T p and q^T ds:
+        # dv and dk accumulate TRANSPOSED, [Dv, bk] = do^T p and
+        # [D, bk] = q^T ds:
         # the operand Mosaic has to turn for a product contracted over
         # rows is then the [bq, D] one, not the [bq, bk] one
         dv_acc[...] += jax.lax.dot_general(
             dob, p.astype(dt), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [D, bk]
+            preferred_element_type=jnp.float32)        # [Dv, bk]
         dp = jax.lax.dot_general(
             dob, vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # [bq, bk]
@@ -465,7 +479,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     q, k, v, seg_q, seg_k, o, lse = res
     do = g
     b, hq, sq, d = q.shape
-    hk, sk = k.shape[1], k.shape[2]
+    hk, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = hq // hk
     has_seg = seg_q is not None
     # precedence: explicit bwd_blocks > the forward's (possibly caller-
@@ -497,14 +511,22 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     kernel = functools.partial(_bwd_fused_kernel, scale=scale,
                                causal=causal, has_seg=has_seg, sq=sq,
                                sk=sk, bq=bq, bk=bk, nq=nq, nk=nk)
-    kv_spec = pl.BlockSpec(
-        (1, 1, bk, d),
-        lambda ib, ih, ikb, iqb, _rep=rep: (ib, ih // _rep, ikb, 0))
-    q_full = pl.BlockSpec((1, 1, sqp, d),
-                          lambda ib, ih, ikb, iqb: (ib, ih, 0, 0))
-    v1_full = pl.BlockSpec((1, 1, sqp, _LANE),
-                           lambda ib, ih, ikb, iqb: (ib, ih, 0, 0))
-    in_specs = [q_full, kv_spec, kv_spec, q_full, v1_full, v1_full]
+
+    def kv_spec(width):
+        return pl.BlockSpec(
+            (1, 1, bk, width),
+            lambda ib, ih, ikb, iqb, _rep=rep: (ib, ih // _rep, ikb, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec((1, 1, sqp, width),
+                            lambda ib, ih, ikb, iqb: (ib, ih, 0, 0))
+
+    def kv_out_spec(width):
+        return pl.BlockSpec((1, 1, bk, width),
+                            lambda ib, ih, ikb, iqb: (ib, ih, ikb, 0))
+
+    in_specs = [row_spec(d), kv_spec(d), kv_spec(dv), row_spec(dv),
+                row_spec(_LANE), row_spec(_LANE)]
     args = [qp, kp, vp, dop, lsep, dltp]
     if has_seg:
         in_specs += [
@@ -515,12 +537,12 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
         ]
         args += [_pad_to(seg_q.astype(jnp.int32), 1, bq)[:, :, None],
                  _pad_to(seg_k.astype(jnp.int32), 1, bk)[:, None, :]]
-    limit = _bwd_vmem_limit(sqp, d, q.dtype.itemsize, bq, bk)
+    limit = _bwd_vmem_limit(sqp, d, q.dtype.itemsize, bq, bk, dv)
     plain, masked = (
         np.broadcast_to(kind, (nk, nq)).sum() for kind in _bwd_tile_kinds(
             np.arange(nk)[:, None], np.arange(nq)[None, :], causal=causal,
             has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk))
-    _publish_tiles("bwd", q.shape, sk, causal, has_seg, (bq, bk),
+    _publish_tiles("bwd", q.shape, dv, sk, causal, has_seg, (bq, bk),
                    plain=plain, masked=masked,
                    skipped=nk * nq - plain - masked)
     # dq leaves in q's dtype; dk and dv too unless a GQA group is summed
@@ -530,23 +552,16 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
         kernel,
         grid=(b, hq, nk, nq),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, sqp, d),
-                         lambda ib, ih, ikb, iqb: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda ib, ih, ikb, iqb: (ib, ih, ikb, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda ib, ih, ikb, iqb: (ib, ih, ikb, 0)),
-        ],
+        out_specs=[row_spec(d), kv_out_spec(d), kv_out_spec(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sqp, d), q.dtype),
             jax.ShapeDtypeStruct((b, hq, skp, d), kv_dtype),
-            jax.ShapeDtypeStruct((b, hq, skp, d), kv_dtype),
+            jax.ShapeDtypeStruct((b, hq, skp, dv), kv_dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((sqp, d), jnp.float32),   # dq rows (persistent)
             pltpu.VMEM((d, bk), jnp.float32),    # dk accumulator, transposed
-            pltpu.VMEM((d, bk), jnp.float32),    # dv accumulator, transposed
+            pltpu.VMEM((dv, bk), jnp.float32),   # dv accumulator, transposed
         ],
         interpret=interpret,
         name="flash_attention_bwd",
@@ -556,11 +571,9 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     )(*args)
     if rep > 1:
         dkh = dkh.reshape(b, hk, rep, skp, d).sum(axis=2)
-        dvh = dvh.reshape(b, hk, rep, skp, d).sum(axis=2)
-    dk = dkh[:, :, :sk].astype(k.dtype)
-    dv = dvh[:, :, :sk].astype(v.dtype)
-    dq = dqh[:, :, :sq]
-    return dq, dk, dv, None, None
+        dvh = dvh.reshape(b, hk, rep, skp, dv).sum(axis=2)
+    return (dqh[:, :, :sq], dkh[:, :, :sk].astype(k.dtype),
+            dvh[:, :, :sk].astype(v.dtype), None, None)
 
 
 def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
@@ -568,7 +581,8 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
     """UNJITTED jnp twin of the fused Pallas backward (the
     ``quant_matmul_jnp`` parity contract).
 
-    Takes paddle-layout [batch, seq, heads, head_dim] ``q/k/v/do`` plus
+    Takes paddle-layout [batch, seq, heads, head_dim] ``q/k/v/do``
+    (``v``, ``do`` and ``o`` as wide as ``v``) plus
     the forward's ``o`` and logsumexp ``lse`` ([B, H, Sq], the second
     output of ``_fwd``), and replays the fused kernel's EXACT tile walk
     — the same padding, the same per-tile dot shapes and dimension
@@ -596,7 +610,7 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
     do = jnp.swapaxes(do, 1, 2)
     o = jnp.swapaxes(o, 1, 2)
     b, hq, sq, d = q.shape
-    hk, sk = k.shape[1], k.shape[2]
+    hk, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = hq // hk
     has_seg = seg_q is not None
     bq, bk = (blocks if blocks is not None
@@ -623,7 +637,7 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
     kv_dtype = k.dtype if rep == 1 else jnp.float32
     dqh = jnp.zeros((b, hq, sqp, d), q.dtype)
     dkh = jnp.zeros((b, hq, skp, d), kv_dtype)
-    dvh = jnp.zeros((b, hq, skp, d), kv_dtype)
+    dvh = jnp.zeros((b, hq, skp, dv), kv_dtype)
     for ib in range(b):
         for ih in range(hq):
             dq_acc = jnp.zeros((sqp, d), jnp.float32)
@@ -631,7 +645,7 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
                 kb = kp[ib, ih // rep, ik * bk:(ik + 1) * bk]
                 vb = vp[ib, ih // rep, ik * bk:(ik + 1) * bk]
                 dk_acc = jnp.zeros((d, bk), jnp.float32)
-                dv_acc = jnp.zeros((d, bk), jnp.float32)
+                dv_acc = jnp.zeros((dv, bk), jnp.float32)
                 for iq in range(nq):
                     plain, masked = _bwd_tile_kinds(
                         np.asarray(ik), np.asarray(iq), causal=causal,
@@ -681,12 +695,10 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
             dqh = dqh.at[ib, ih].set((dq_acc * scale).astype(q.dtype))
     if rep > 1:
         dkh = dkh.reshape(b, hk, rep, skp, d).sum(axis=2)
-        dvh = dvh.reshape(b, hk, rep, skp, d).sum(axis=2)
-    dk = dkh[:, :, :sk].astype(k.dtype)
-    dv = dvh[:, :, :sk].astype(v.dtype)
-    dq = dqh[:, :, :sq]
-    return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2))
+        dvh = dvh.reshape(b, hk, rep, skp, dv).sum(axis=2)
+    return (jnp.swapaxes(dqh[:, :, :sq], 1, 2),
+            jnp.swapaxes(dkh[:, :, :sk].astype(k.dtype), 1, 2),
+            jnp.swapaxes(dvh[:, :, :sk].astype(v.dtype), 1, 2))
 
 
 # --------------------------------------------------------------------------
@@ -745,7 +757,7 @@ def _scan_slope(make_runner, args, r1=4, r2=24):
     return slope if slope > 0 else float("inf")
 
 
-def _tuned_entry(entry, candidates, qt, kt, causal, make_runner,
+def _tuned_entry(entry, candidates, qt, kt, vt, causal, make_runner,
                  validate):
     """Shared cache-probe / sweep / fallback protocol for both flash
     autotune entries. Under a trace (tracer inputs) only cache HITS
@@ -760,7 +772,7 @@ def _tuned_entry(entry, candidates, qt, kt, causal, make_runner,
     cands = [c for c in candidates if c[0] <= sq and c[1] <= sk]
     if len(cands) <= 1:
         return None
-    sig = _shape_sig(qt.shape, sk, causal)
+    sig = _shape_sig(qt.shape, sk, causal, vt.shape[-1])
     cached = at._load_cache().get(f"{at._device_kind()}|{entry}|{sig}")
     if cached is not None:
         for c in cands:
@@ -778,7 +790,7 @@ def _tuned_entry(entry, candidates, qt, kt, causal, make_runner,
 
     def measure(cand):
         return _scan_slope(lambda reps: memo_runner(cand, reps),
-                           (qt, kt, kt))
+                           (qt, kt, vt))
 
     try:
         return tuple(at.autotune(entry, sig, cands, None,
@@ -787,12 +799,13 @@ def _tuned_entry(entry, candidates, qt, kt, causal, make_runner,
         return None
 
 
-def _autotuned_blocks(qt, kt, scale, causal):
+def _autotuned_blocks(qt, kt, scale, causal, vt=None):
     """FORWARD block-size selection through the autotune cache (SURVEY
     C14; see autotune.py). The backward tunes separately
     (``_autotuned_bwd_blocks``) — its fused kernel has different VMEM
     pressure and different winners, and fwd+bwd-blended timing used to
     bias both."""
+    vt = kt if vt is None else vt       # values as wide as the keys
 
     def make_runner(cand, reps):
         def chained(a, bb, cc, _n=reps, _cand=tuple(cand)):
@@ -801,7 +814,7 @@ def _autotuned_blocks(qt, kt, scale, causal):
                                 None, None, scale, causal, False,
                                 _cand)
                 return c + o.astype(a.dtype), None
-            z = jnp.zeros(a.shape, a.dtype)
+            z = jnp.zeros((*a.shape[:-1], cc.shape[-1]), a.dtype)
             return jax.lax.scan(body, z, jnp.arange(_n))[0]
         return chained
 
@@ -810,21 +823,22 @@ def _autotuned_blocks(qt, kt, scale, causal):
         # call: compile+run the forward in the caller's real eager
         # context — a scoped-vmem overflow disqualifies the candidate
         # and the next-best wins.
-        o = _flash_bhsd(qt, kt, kt, None, None, scale, causal, False,
+        o = _flash_bhsd(qt, kt, vt, None, None, scale, causal, False,
                         tuple(cand))
         float(jax.device_get(o.ravel()[0]))  # force execution
 
-    return _tuned_entry("flash_attention", _TUNE_CANDIDATES, qt, kt,
+    return _tuned_entry("flash_attention", _TUNE_CANDIDATES, qt, kt, vt,
                         causal, make_runner, validate)
 
 
-def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks):
+def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks, vt=None):
     """BACKWARD block-size selection: its own ``flash_attention_bwd``
     autotune entry over backward-specific candidates
     (``_TUNE_BWD_CANDIDATES``). The timed program is the full fwd+bwd chain
     with the FORWARD blocks pinned to the already-tuned winner: the
     forward term is constant across candidates, so the slope ranks the
     backward kernels alone."""
+    vt = kt if vt is None else vt
 
     def make_runner(cand, reps):
         grad = jax.grad(
@@ -856,11 +870,11 @@ def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks):
             return _flash_bhsd(
                 a, bb, cc, None, None, scale, causal, False, fwd_blocks,
                 tuple(cand)).astype(jnp.float32).sum()
-        grads = jax.grad(f, argnums=(0, 1, 2))(qt, kt, kt)
+        grads = jax.grad(f, argnums=(0, 1, 2))(qt, kt, vt)
         float(jax.device_get(grads[0].ravel()[0]))  # force execution
 
     return _tuned_entry("flash_attention_bwd", _TUNE_BWD_CANDIDATES,
-                        qt, kt, causal, make_runner, validate)
+                        qt, kt, vt, causal, make_runner, validate)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
@@ -868,7 +882,12 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
     """Flash attention in paddle layout [batch, seq, num_heads, head_dim].
 
     ``num_heads(q)`` may be a multiple of ``num_heads(k) == num_heads(v)``
-    (grouped-query attention). Returns [batch, seq_q, num_heads, head_dim].
+    (grouped-query attention).  ``head_dim(k)`` must equal ``head_dim(q)``
+    (the scores contract over it; the default ``scale`` is
+    ``1 / sqrt(head_dim(q))``); ``head_dim(v)`` is its own, and the
+    result is [batch, seq_q, num_heads, head_dim(v)]: ``do`` and ``dv``
+    are as wide as ``v``, ``dq`` and ``dk`` as wide as ``q``.  A
+    ``ValueError`` says which of these a call breaks.
     ``blocks``: optional (block_q, block_k) override; with autotuning
     enabled (``incubate.autotune.set_config``) the best pair is measured
     on-device and cached per shape. ``bwd_blocks``: the same for the
@@ -891,6 +910,16 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
         raise ValueError(
             f"flash_attention: query heads ({hq}) must be a multiple of "
             f"key/value heads ({hk}) for grouped-query attention")
+    if k.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"flash_attention: k's head_dim ({k.shape[-1]}) must equal q's "
+            f"({q.shape[-1]}): the scores contract over it; only v's "
+            f"head_dim may differ")
+    if v.shape[:-1] != k.shape[:-1]:
+        raise ValueError(
+            f"flash_attention: v {tuple(v.shape)} must match k "
+            f"{tuple(k.shape)} in batch, positions and heads; its head_dim "
+            f"is its own")
     seg_q = seg_k = None
     if segment_ids is not None:
         if isinstance(segment_ids, (tuple, list)):
@@ -914,10 +943,10 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
             # backward through _bwd's fallback chain)
             if blocks is None:
                 blocks = _autotuned_blocks(qt, kt, float(scale),
-                                           bool(causal))
+                                           bool(causal), vt)
                 if bwd_blocks is None:
                     bwd_blocks = _autotuned_bwd_blocks(
-                        qt, kt, float(scale), bool(causal), blocks)
+                        qt, kt, float(scale), bool(causal), blocks, vt)
     o = _flash_bhsd(qt, kt, vt, seg_q, seg_k, float(scale), bool(causal),
                     bool(interpret), blocks, bwd_blocks)
     return jnp.swapaxes(o, 1, 2)

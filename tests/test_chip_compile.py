@@ -280,6 +280,34 @@ def test_flash_attention_gqa_8k_fwd_bwd_compiles(chip):
         8192, 64, 2, *FA._bwd_block_sizes(8192, 8192, True)) > 37 << 20
 
 
+def test_flash_attention_latent_8k_fwd_bwd_compiles(chip):
+    """Moonlight-16B-A3B's latent attention at the benchmark's shape: 16
+    heads, keys of 192 (128 un-rotated + 64 rotated), values of 128,
+    8192 positions, one row.  The scores contract over 192, which is no
+    multiple of the 128 lanes: q, dq and the dq accumulator take 256
+    lanes a position in VMEM, v, do and dv 128; the tensors in HBM keep
+    192 and 128.  The backward's whole-row buffers are 54.5 MB here."""
+    q = chip((1, 8192, 16, 192), BF16)
+    v = chip((1, 8192, 16, 128), BF16)
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: FA.flash_attention(
+                q, k, v, causal=True, interpret=False).astype(F32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = chip.compile(grads, q, q, v)
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    # nothing in HBM is padded to the lanes: no 256-wide array
+    assert "8192,256]" not in text and "8192,16,256]" not in text
+    dq, dk, dv = compiled.out_info
+    assert (dq.shape, dk.shape, dv.shape) == (q.shape, q.shape, v.shape)
+    limit = FA._bwd_vmem_limit(
+        8192, 192, 2, *FA._bwd_block_sizes(8192, 8192, True), dv=128)
+    assert 54 << 20 < limit < 128 << 20
+
+
 def test_sparse_moe_grouped_products_compile(chip):
     """The dropless block's share of LFM2-24B-A2B (8 of 64 experts,
     2048 -> 1536, top-4) over 16384 tokens, forward and backward: the
