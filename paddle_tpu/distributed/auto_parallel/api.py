@@ -350,7 +350,9 @@ class _ShardOptimizer:
         for acc_name, store in opt._accumulators.items():
             for pid, acc in store.items():
                 p = params.get(pid)
-                if p is None or acc.is_dist():
+                # a 0-d accumulator (a bias-correction power) has nothing
+                # to place: it stays replicated and shard_fn is not asked
+                if p is None or acc.is_dist() or not acc.ndim:
                     continue
                 mesh = p.process_mesh or _global_mesh
                 if mesh is None:

@@ -43,7 +43,10 @@ def _spec_names(spec):
 def _compose_parts(shape, cur, own_mesh, fallback_mesh, axis_name):
     """Core of the compose: given an existing partial spec ``cur`` over
     ``own_mesh``, pick the first free divisible dim for ``axis_name``.
-    None = leave as is."""
+    None = leave as is (a 0-d accumulator always: one number has no dim
+    to cut and stays replicated, whatever its parameter's spec)."""
+    if not shape:
+        return None
     cur = tuple(cur) + (None,) * (len(shape) - len(cur))
     names = _spec_names(cur)
     if axis_name in names:

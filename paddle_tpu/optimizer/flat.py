@@ -92,7 +92,10 @@ class FlatGroup:
         self.param_store: Optional[FlatStore] = None
         self.master_store: Optional[FlatStore] = None
         self.moment_stores: dict[str, FlatStore] = {}
-        self.b1p: Optional[Tensor] = None  # per-bucket beta-pow scalars
+        # ONE 0-d beta-pow pair for the bucket, where the per-param path
+        # keeps one 0-d pair a parameter; a defuse hands this pair to
+        # every member as it is
+        self.b1p: Optional[Tensor] = None
         self.b2p: Optional[Tensor] = None
         self.grad_store: Optional[FlatStore] = None
 

@@ -13,6 +13,7 @@ subclasses (adam.py, adamw.py, momentum.py, ...). TPU-native details:
 from __future__ import annotations
 
 import warnings
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +40,24 @@ class L1Decay:
         self.coeff = float(coeff)
 
 
+def _one_number(key, arr):
+    """A saved bias-correction power as the 0-d float32 it is kept as,
+    whatever shape it was saved at: 0-d, ``[1]`` (the reference
+    framework's) or a parameter's full shape (this one's older
+    checkpoints)."""
+    a = np.asarray(arr, np.float32).ravel()
+    if a.size == 0 or not np.all(a == a[0]):
+        raise ValueError(
+            f"{key}: a bias-correction power is one number a parameter, "
+            f"and the saved array of shape {tuple(np.shape(arr))} holds "
+            f"{'none' if a.size == 0 else 'several'}")
+    return jnp.float32(a[0])
+
+
 class Optimizer:
+    # accumulators that are one number a parameter (``beta ** steps``)
+    _SCALAR_ACCS = ("beta1_pow", "beta2_pow")
+
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None,
                  grad_clip=None, name=None, multi_precision=False):
         if parameters is None:
@@ -82,6 +100,7 @@ class Optimizer:
         # Created here, not lazily — it must pre-exist any capture so the
         # tracker classifies it as an input rather than a temporary.
         self._lr_var = Tensor(jnp.float32(self.get_lr()))
+        self._publish_state_bytes()
 
     # --- lr -------------------------------------------------------------
     def get_lr(self):
@@ -100,14 +119,21 @@ class Optimizer:
 
     # --- accumulators (state lives in Tensors so jit capture threads it
     # through the compiled step as inputs/outputs) ------------------------
-    def _acc(self, name, p, init=None, dtype=None):
+    def _acc(self, name, p, init=None, dtype=None, scalar=False):
+        """``p``'s accumulator ``name``, made on first use: at ``p``'s
+        shape, or with ``scalar`` ONE float32 number (0-d, like
+        ``_lr_var``) for state that is the same in every element."""
         store = self._accumulators.setdefault(name, {})
         pid = id(p)
         if pid not in store:
-            v = p._read()
-            dt = dtype or (jnp.float32 if self._use_master(p) else v.dtype)
-            store[pid] = Tensor(jnp.zeros(v.shape, dt) if init is None
-                                else jnp.full(v.shape, init, dt))
+            if scalar:
+                store[pid] = Tensor(jnp.float32(init or 0.0))
+            else:
+                v = p._read()
+                dt = dtype or (jnp.float32 if self._use_master(p)
+                               else v.dtype)
+                store[pid] = Tensor(jnp.zeros(v.shape, dt) if init is None
+                                    else jnp.full(v.shape, init, dt))
         return store[pid]._read()
 
     def _set_acc(self, name, p, val):
@@ -123,6 +149,37 @@ class Optimizer:
             self._master_weights[pid] = Tensor(
                 p._read().astype(jnp.float32))
         return self._master_weights[pid]._read()
+
+    def state_bytes(self):
+        """Bytes of step state by part, reckoned on the host from shapes
+        (a bucket's padding is not counted): the parameters, their
+        float32 masters, the accumulators at a parameter's shape and the
+        0-d ones."""
+        def nbytes(ts):
+            return sum(t.size * t.dtype.itemsize for t in ts)
+        accs = [t for store in self._accumulators.values()
+                for t in store.values()]
+        accs += [t for grp in (self._flat or ())
+                 for t in (grp.b1p, grp.b2p) if t is not None]
+        return {"param": nbytes(self._parameters),
+                "master": nbytes(self._master_weights.values()),
+                "moments": nbytes(t for t in accs if t.ndim),
+                "scalars": nbytes(t for t in accs if not t.ndim)}
+
+    def _publish_state_bytes(self):
+        """Point the ``optimizer.state_bytes`` gauges at this optimizer,
+        the newest. Read at snapshot time only, so whichever path makes
+        the state (``_acc``, ``_get_master``, the bucket build, a loaded
+        checkpoint) is counted, and through a weak reference: the
+        registry outlives the optimizer."""
+        from ..observability import metrics
+        ref = weakref.ref(self)
+        for part in ("param", "master", "moments", "scalars"):
+            metrics.registry().gauge(
+                "optimizer.state_bytes",
+                "bytes of step state of the newest optimizer",
+                labels={"part": part}
+            ).set_function(lambda part=part: ref().state_bytes()[part])
 
     # --- step -----------------------------------------------------------
     def _collect(self):
@@ -411,6 +468,12 @@ class Optimizer:
             if kind in ("adam", "adamw"):
                 grp.b1p = Tensor(jnp.float32(b1v))
                 grp.b2p = Tensor(jnp.float32(b2v))
+                # the bucket's pair now counts for its members: their
+                # own would go stale (a defuse hands the bucket's back)
+                for name in self._SCALAR_ACCS:
+                    store = self._accumulators.get(name, {})
+                    for p in members:
+                        store.pop(id(p), None)
                 if log is not None:
                     log.append((grp.b1p, grp.b1p._read()))
                     log.append((grp.b2p, grp.b2p._read()))
@@ -421,7 +484,7 @@ class Optimizer:
         """(b1, b2) when every member's saved beta-pow history agrees
         (the normal case: all params step together); None when mixed."""
         out = []
-        for name in ("beta1_pow", "beta2_pow"):
+        for name in self._SCALAR_ACCS:
             store = self._accumulators.get(name, {})
             ts = [store.get(id(p)) for p in members]
             if all(t is None for t in ts):
@@ -429,16 +492,10 @@ class Optimizer:
                 continue
             if any(t is None for t in ts):
                 return None
-            first = None
-            for t in ts:
-                a = np.asarray(t._read()).ravel()
-                if a.size == 0:
-                    return None
-                if first is None:
-                    first = a.flat[0]
-                if not np.all(a == first):
-                    return None
-            out.append(float(first))
+            a = np.asarray(jax.device_get([t._read() for t in ts]))
+            if not np.all(a == a[0]):
+                return None
+            out.append(float(a[0]))
         return out[0], out[1]
 
     def _make_spec(self, grp, has_clip):
@@ -598,12 +655,11 @@ class Optimizer:
                 " — defuse eagerly before capturing the step")
         for grp in fl:
             if grp.b1p is not None:
-                for i, p in enumerate(grp.params):
-                    for name, t in (("beta1_pow", grp.b1p),
-                                    ("beta2_pow", grp.b2p)):
+                for p in grp.params:
+                    for name, t in zip(self._SCALAR_ACCS,
+                                       (grp.b1p, grp.b2p)):
                         self._accumulators.setdefault(name, {})[id(p)] = \
-                            Tensor(jnp.full(grp.shapes[i], t._read(),
-                                            jnp.float32))
+                            Tensor(t._read())
             for st in grp.stores():
                 st.unbind_all()
             if grp.grad_store is not None:
@@ -702,8 +758,9 @@ class Optimizer:
         for pid, val in self._master_weights.items():
             if pid in names:
                 sd[f"{names[pid]}.master_weight"] = Tensor(val._read())
-        # fused buckets keep ONE beta-pow scalar per bucket; emit it per
-        # param so the per-param path (and older checkpoints) round-trip
+        # fused buckets keep ONE beta-pow pair per bucket; emit it per
+        # param, 0-d as the per-param path keeps its own, so a checkpoint
+        # reads the same from either path
         for grp in (self._flat or ()):
             if grp.b1p is None:
                 continue
@@ -711,8 +768,8 @@ class Optimizer:
                 nm = names.get(id(p))
                 if nm is None:
                     continue
-                sd[f"{nm}.beta1_pow"] = Tensor(grp.b1p._read())
-                sd[f"{nm}.beta2_pow"] = Tensor(grp.b2p._read())
+                for name, t in zip(self._SCALAR_ACCS, (grp.b1p, grp.b2p)):
+                    sd[f"{nm}.{name}"] = Tensor(t._read())
         if isinstance(self._learning_rate, LRScheduler):
             sd["LR_Scheduler"] = self._learning_rate.state_dict()
         sd["@step"] = self._step_count
@@ -741,8 +798,10 @@ class Optimizer:
                 jnp.asarray(np.asarray(val))
             if acc == "master_weight":
                 self._master_weights[id(p)] = Tensor(arr)
-            else:
-                self._accumulators.setdefault(acc, {})[id(p)] = Tensor(arr)
+                continue
+            if acc in self._SCALAR_ACCS:
+                arr = _one_number(key, arr)
+            self._accumulators.setdefault(acc, {})[id(p)] = Tensor(arr)
 
     def minimize(self, loss, startup_program=None, parameters=None,
                  no_grad_set=None):
@@ -811,8 +870,8 @@ class Adam(Optimizer):
         return None if self._amsgrad else "adam"
 
     def _beta_pows(self, p):
-        b1p = self._acc("beta1_pow", p, init=1.0, dtype=jnp.float32)
-        b2p = self._acc("beta2_pow", p, init=1.0, dtype=jnp.float32)
+        b1p = self._acc("beta1_pow", p, init=1.0, scalar=True)
+        b2p = self._acc("beta2_pow", p, init=1.0, scalar=True)
         b1p = b1p * self._beta1
         b2p = b2p * self._beta2
         self._set_acc("beta1_pow", p, b1p)
@@ -884,7 +943,7 @@ class Adamax(Optimizer):
     def _update(self, p, w, g, lr):
         m = self._acc("moment", p, dtype=jnp.float32)
         u = self._acc("inf_norm", p, dtype=jnp.float32)
-        b1p = self._acc("beta1_pow", p, init=1.0, dtype=jnp.float32)
+        b1p = self._acc("beta1_pow", p, init=1.0, scalar=True)
         b1p = b1p * self._beta1
         self._set_acc("beta1_pow", p, b1p)
         m = self._beta1 * m + (1 - self._beta1) * g
@@ -971,8 +1030,8 @@ class Lamb(Optimizer):
     def _update(self, p, w, g, lr):
         m = self._acc("moment1", p, dtype=jnp.float32)
         v = self._acc("moment2", p, dtype=jnp.float32)
-        b1p = self._acc("beta1_pow", p, init=1.0, dtype=jnp.float32)
-        b2p = self._acc("beta2_pow", p, init=1.0, dtype=jnp.float32)
+        b1p = self._acc("beta1_pow", p, init=1.0, scalar=True)
+        b2p = self._acc("beta2_pow", p, init=1.0, scalar=True)
         b1p, b2p = b1p * self._beta1, b2p * self._beta2
         self._set_acc("beta1_pow", p, b1p)
         self._set_acc("beta2_pow", p, b2p)

@@ -119,10 +119,10 @@ class StepGuard:
         orig_acc = optimizer._acc
         orig_master = optimizer._get_master
 
-        def patched_acc(name, p, init=None, dtype=None):
+        def patched_acc(name, p, **kw):
             store = optimizer._accumulators.setdefault(name, {})
             fresh = id(p) not in store
-            val = orig_acc(name, p, init=init, dtype=dtype)
+            val = orig_acc(name, p, **kw)
             if fresh:
                 created.append((store[id(p)], val))
             return val

@@ -142,7 +142,10 @@ def test_shard_optimizer_zero1(mesh2d):
     net = _MLP()
     dist.shard_layer(net, mesh2d)
 
+    asked = set()
+
     def moment_shard(acc_name, param, acc):
+        asked.add(acc_name)
         if param.shape[0] % 4 == 0:
             return [dist.Shard(0), dist.Replicate()]
         return None
@@ -157,6 +160,12 @@ def test_shard_optimizer_zero1(mesh2d):
     opt.step()
     m = opt._inner._accumulators["moment1"][id(net.fc1.weight)]
     assert {s.data.shape for s in m._read().addressable_shards} == {(2, 32)}
+    # a bias-correction power is one number: shard_fn is not asked to
+    # place it and it stays replicated
+    assert asked == {"moment1", "moment2"}
+    for name in ("beta1_pow", "beta2_pow"):
+        for t in opt._inner._accumulators[name].values():
+            assert t._read().shape == () and not t.is_dist()
 
 
 def test_to_static_sharded_step(mesh2d):

@@ -122,6 +122,10 @@ def test_sharding2_training():
         m = inner._accumulators["moment1"][id(w)]
         shapes = {s.data.shape for s in m._read().addressable_shards}
         assert shapes == {(32 // 8, 128)}, shapes
+        # and the compiled step leaves the 0-d powers replicated
+        for name in ("beta1_pow", "beta2_pow"):
+            v = inner._accumulators[name][id(w)]._read()
+            assert v.shape == () and v.sharding.is_fully_replicated
     finally:
         fleet.set_hybrid_communicate_group(hcg_prev)
 

@@ -157,6 +157,12 @@ def test_sharding_stage2():
         m = inner._accumulators["moment1"][id(net.weight)]
         assert {s.data.shape for s in m._read().addressable_shards} \
             == {(2, 16)}
+        # a bias-correction power is one number: nothing to cut
+        for name in ("beta1_pow", "beta2_pow"):
+            for t in inner._accumulators[name].values():
+                v = t._read()
+                assert v.shape == () and not t.is_dist()
+                assert v.sharding.is_fully_replicated
     finally:
         fleet.set_hybrid_communicate_group(hcg_prev)
 

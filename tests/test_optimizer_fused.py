@@ -73,14 +73,14 @@ def _run(name, fused, dtype, clip, decay, steps=3):
     out = {f"p{i}": np.asarray(p._read()) for i, p in enumerate(ps)}
     for i, p in enumerate(ps):
         p.name = f"w{i}"
-    # state_dict normalizes fused vs per-param layout (beta pows are
-    # per-bucket scalars on the fused path, full arrays per-param —
-    # same VALUE either way)
+    # state_dict gives both layouts alike (a beta pow is ONE 0-d pair a
+    # bucket on the fused path and one a parameter on the other, emitted
+    # per parameter by both)
     for key, t in o.state_dict().items():
         if key in ("@step", "LR_Scheduler"):
             continue
-        a = np.asarray(t._read())
-        out[key] = a.ravel()[:1] if "_pow" in key else a
+        out[key] = np.asarray(t._read())
+        assert "_pow" not in key or out[key].shape == ()
     return out, o
 
 
@@ -204,28 +204,90 @@ def test_state_dict_roundtrip_fused_unfused():
             assert np.array_equal(x, y), f"roundtrip {a}->{b} differs"
 
 
-def test_resume_from_checkpoint_parity():
-    """Save/restore mid-run through state_dict (the checkpoint path)
-    matches an uninterrupted fused run."""
-    def train(o, ps, lo, hi):
-        for i in range(lo, hi):
-            for p, g in zip(ps, _grads(i)):
-                p.grad = pt.to_tensor(g)
-            o.step()
-            o.clear_grad()
-
+def _named_params():
     ps = _params()
     for i, p in enumerate(ps):
         p.name = f"w{i}"
+    return ps
+
+
+def _adam_steps(o, ps, lo, hi):
+    for i in range(lo, hi):
+        for p, g in zip(ps, _grads(i)):
+            p.grad = pt.to_tensor(g)
+        o.step()
+        o.clear_grad()
+
+
+@pytest.mark.parametrize("saved", ["0d", "[1]", "full_shape"])
+@pytest.mark.parametrize("path", ["per_param_to_fused", "fused_to_per_param"])
+def test_saved_power_of_any_shape_continues_the_bias_correction(path, saved):
+    """Both paths write a power 0-d; a checkpoint that holds it at ``[1]``
+    (the reference framework's) or at the parameter's full shape (this
+    repo's older ones) loads as the same number and the next steps are an
+    uninterrupted run's, on either path."""
+    st.set_flags({"fused_opt": False})
+    ref = _named_params()
+    _adam_steps(opt.Adam(0.05, parameters=ref), ref, 0, 4)
+
+    fused_a = path == "fused_to_per_param"
+    st.set_flags({"fused_opt": fused_a})
+    ps = _named_params()
     o = opt.Adam(0.05, parameters=ps)
-    train(o, ps, 0, 4)
+    _adam_steps(o, ps, 0, 2)
+    assert bool(o._flat) == fused_a
+    sd = o.state_dict()
+    pows = [k for k in sd if k.endswith("_pow")]
+    assert len(pows) == 2 * len(ps)
+    for k in pows:
+        v = sd[k]._read()
+        assert v.shape == () and v.dtype == np.float32
+        shape = {"0d": (), "[1]": (1,),
+                 "full_shape": SHAPES[int(k[1])]}[saved]
+        sd[k] = np.full(shape, np.asarray(v), "float32")
+
+    st.set_flags({"fused_opt": not fused_a})
+    o2 = opt.Adam(0.05, parameters=ps)
+    o2.set_state_dict(sd)
+    for name, beta in (("beta1_pow", 0.9), ("beta2_pow", 0.999)):
+        for t in o2._accumulators[name].values():
+            v = t._read()
+            assert v.shape == () and v.dtype == np.float32
+            assert np.float32(v) == np.float32(beta) * np.float32(beta)
+    _adam_steps(o2, ps, 2, 4)
+    assert bool(o2._flat) == (not fused_a)
+    for x, p in zip(ref, ps):
+        assert np.array_equal(np.asarray(x._read()), np.asarray(p._read()))
+    # no power of more than one element exists on either path
+    for store in (o._accumulators, o2._accumulators):
+        assert all(t.size == 1 for n in ("beta1_pow", "beta2_pow")
+                   for t in store.get(n, {}).values())
+
+
+def test_saved_power_of_unequal_elements_is_refused():
+    ps = _named_params()
+    o = opt.Adam(0.05, parameters=ps)
+    _adam_steps(o, ps, 0, 1)
+    sd = o.state_dict()
+    bad = np.full(SHAPES[0], 0.9, "float32")
+    bad[0, 0] = 0.81
+    sd["w0.beta1_pow"] = bad
+    with pytest.raises(ValueError, match=r"w0\.beta1_pow.*one number.*"
+                       r"\(6, 3\)"):
+        opt.Adam(0.05, parameters=ps).set_state_dict(sd)
+
+
+def test_resume_from_checkpoint_parity():
+    """Save/restore mid-run through state_dict (the checkpoint path)
+    matches an uninterrupted fused run."""
+    ps = _named_params()
+    o = opt.Adam(0.05, parameters=ps)
+    _adam_steps(o, ps, 0, 4)
     ref = [np.asarray(p._read()) for p in ps]
 
-    ps2 = _params()
-    for i, p in enumerate(ps2):
-        p.name = f"w{i}"
+    ps2 = _named_params()
     o2 = opt.Adam(0.05, parameters=ps2)
-    train(o2, ps2, 0, 2)
+    _adam_steps(o2, ps2, 0, 2)
     sd = o2.state_dict()
     wsd = {f"w{i}": pt.Tensor(p._read()) for i, p in enumerate(ps2)}
     # fresh process analog: new params + optimizer, restore both
@@ -235,7 +297,7 @@ def test_resume_from_checkpoint_parity():
         p._write(wsd[f"w{i}"]._read())
     o3 = opt.Adam(0.05, parameters=ps3)
     o3.set_state_dict(sd)
-    train(o3, ps3, 2, 4)
+    _adam_steps(o3, ps3, 2, 4)
     for x, p in zip(ref, ps3):
         assert np.array_equal(x, np.asarray(p._read()))
 
@@ -366,8 +428,29 @@ def test_grad_scaler_fused_parity_with_per_param():
 
 
 # ------------------------------------------------------------ guard --
-def test_step_guard_bitwise_noop_on_fused_path():
+def _powers(o):
+    """Every beta pow the optimizer holds, per parameter or per bucket."""
+    ts = [t for n in ("beta1_pow", "beta2_pow")
+          for t in o._accumulators.get(n, {}).values()]
+    ts += [t for grp in (o._flat or ()) for t in (grp.b1p, grp.b2p)]
+    vals = [np.asarray(t._read()) for t in ts]
+    assert vals and all(v.shape == () for v in vals)
+    return vals
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "per_param"])
+def test_step_guard_skip_is_bitwise_noop(fused):
     from paddle_tpu.resilience import StepGuard
+    st.set_flags({"fused_opt": fused})
+    nan = pt.to_tensor(np.float32(np.nan))
+    # a skipped FIRST step leaves the powers it created at 1
+    ps = _params()
+    o = opt.Adam(0.05, parameters=ps)
+    for p, g in zip(ps, _grads(0)):
+        p.grad = pt.to_tensor(g)
+    StepGuard(max_bad_steps=3).guarded_step(o, nan)
+    assert all(v == 1.0 for v in _powers(o))
+
     ps = _params()
     o = opt.Adam(0.05, parameters=ps)
     guard = StepGuard(max_bad_steps=3)
@@ -376,19 +459,22 @@ def test_step_guard_bitwise_noop_on_fused_path():
     loss = pt.to_tensor(np.float32(1.0))
     guard.guarded_step(o, loss)
     o.clear_grad()
-    assert o._flat
+    assert bool(o._flat) == fused
+    pow_snap = _powers(o)
+    assert all(v < 1.0 for v in pow_snap)
     snap = [np.asarray(p._read()) for p in ps]
     m_snap = np.asarray(o._accumulators["moment1"][id(ps[0])]._read())
     bad = _grads(1)
     bad[0][0] = np.nan
     for p, g in zip(ps, bad):
         p.grad = pt.to_tensor(g)
-    guard.guarded_step(o, pt.to_tensor(np.float32(np.nan)))
+    guard.guarded_step(o, nan)
     o.clear_grad()
     for x, p in zip(snap, ps):
         assert np.array_equal(x, np.asarray(p._read()))
     assert np.array_equal(
         m_snap, np.asarray(o._accumulators["moment1"][id(ps[0])]._read()))
+    assert _powers(o) == pow_snap
     assert guard.bad_streak == 1
 
 
