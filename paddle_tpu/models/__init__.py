@@ -19,3 +19,5 @@ from . import lfm2  # noqa: F401
 from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM  # noqa: F401
 from . import deepseek_v3  # noqa: F401
 from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM  # noqa: F401
+from . import kimi_linear  # noqa: F401
+from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa: F401

@@ -16,19 +16,14 @@ feed_forward(RMSNorm(h))``.
   The scores contract over ``qk_nope + qk_rope`` (192), the values are
   ``v_head_dim`` (128) wide: one causal flash-attention call whose
   values have their own width.  No biases.
-* The feed-forward is a dense SwiGLU MLP in the leading
-  ``first_k_dense_replace`` layers; after them the dropless
-  sigmoid-routed ``SparseMoEBlock``
-  (``incubate/distributed/models/moe.py``), which holds
-  ``experts_held`` of the router's ``n_routed_experts`` from
-  ``expert_offset`` on (one chip's share under expert parallelism),
-  PLUS a shared expert: one SwiGLU of ``n_shared_experts *
-  moe_intermediate_size`` that every token passes.  The shared expert
-  lives here and not in the block: under expert parallelism every chip
-  computes it alike, and a sum over the chips' shares counts it once.
-* The head is its own leaf (``tie_word_embeddings`` false).
+  With ``rotate`` false (``mla_use_nope`` of ``kimi_linear``) the
+  ``qk_rope_head_dim`` dimensions of q and of the shared key head are
+  used as they are: the same shapes, no rotation.
+* The feed-forward (dense, then shared + routed experts), the stack
+  and the untied head are ``models/sparse_decoder.py``'s, which
+  ``models/kimi_linear.py`` shares.
 
-Shares ``rope_angles`` and the SwiGLU MLP with ``models/llama.py``.
+Shares ``rope_angles`` with ``models/llama.py``.
 Used as ``Lfm2MoeForCausalLM`` is: ``amp.decorate`` O2, ``AdamW``, one
 ``jit.to_static`` step, ``recompute`` per block.  It trains; the
 serving engine's paged cache has no layout for a latent yet, so
@@ -36,17 +31,16 @@ serving engine's paged cache has no layout for a latent yet, so
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass, field
 
 from ..core import scope as _scope
 from ..core.dispatch import apply
-from ..core.tensor import Tensor
 from ..nn import functional as F
-from ..nn import initializer as I
 from ..nn.layer import Layer
-from ..nn.layers import Embedding, Linear, RMSNorm
-from .llama import LlamaConfig, LlamaMLP, rope_angles
+from ..nn.layers import Linear, RMSNorm
+from .llama import rope_angles
+from .sparse_decoder import (SparseDecoderForCausalLM, SparseDecoderLayer,
+                             SparseDecoderModel, init, out_std)
 
 
 @dataclass
@@ -75,6 +69,7 @@ class DeepseekV3Config:
     norm_eps: float = 1e-5              # rms_norm_eps
     kv_norm_eps: float = 1e-6           # the latent's norm (HF's default)
     rope_theta: float = 50000.0
+    rotate: bool = True                 # False: no RoPE (mla_use_nope)
     use_flash_attention: bool = True
     recompute: bool = False
     recompute_policy: str = "full"
@@ -91,23 +86,16 @@ class DeepseekV3Config:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
-def _init(std=0.02):
-    return I.Normal(mean=0.0, std=std)
-
-
-def _out_std(cfg):
-    return 0.02 / math.sqrt(2 * cfg.num_layers)
-
-
 def _heads(q, kv, k_pe, cos, sin, cfg):
     """The kernel's operands from the three projections' results:
     ``q`` [B, S, H * (nope + rope)], ``kv`` [B, S, H * (nope + v)],
     ``k_pe`` [B, S, rope] -> q, k [B, S, H, nope + rope], v [B, S, H, v].
     ``cos`` / ``sin`` are float32 [S, rope] tables with each pair's
-    angle twice (constants of the program).  Plain jnp that XLA fuses
-    into its neighbours."""
+    angle twice (constants of the program), or None where the family
+    does not rotate.  Plain jnp that XLA fuses into its neighbours."""
     h, nope, rope = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    if cos is not None:
+        c, s = cos[None, :, None, :], sin[None, :, None, :]
 
     def impl(qv, kvv, pe):
         import jax.numpy as jnp
@@ -126,8 +114,10 @@ def _heads(q, kv, k_pe, cos, sin, cfg):
             qv = qv.reshape(b, n, h, nope + rope)
             kvv = kvv.reshape(b, n, h, nope + cfg.v_head_dim)
             q_pe, k_nope, v = qv[..., nope:], kvv[..., :nope], kvv[..., nope:]
-        with _scope.phase("rope"):
-            q_pe, pe = rot(q_pe), rot(pe[:, :, None, :])
+        pe = pe[:, :, None, :]
+        if cos is not None:
+            with _scope.phase("rope"):
+                q_pe, pe = rot(q_pe), rot(pe)
         with _scope.phase("assemble"):
             # the one rotated key head serves every head
             k = jnp.concatenate(
@@ -149,18 +139,18 @@ class DeepseekV3Attention(Layer):
         self.cfg = cfg
         h, heads = cfg.hidden_size, cfg.num_heads
         self.q_proj = Linear(h, heads * cfg.qk_head_dim, bias_attr=False,
-                             weight_attr=_init())
+                             weight_attr=init())
         # the latent and, last, the shared rotated key head
         self.kv_down = Linear(h, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
-                              bias_attr=False, weight_attr=_init())
+                              bias_attr=False, weight_attr=init())
         self.kv_norm = RMSNorm(cfg.kv_lora_rank, epsilon=cfg.kv_norm_eps)
         # per head: the un-rotated key, then the value
         self.kv_up = Linear(
             cfg.kv_lora_rank,
             heads * (cfg.qk_nope_head_dim + cfg.v_head_dim),
-            bias_attr=False, weight_attr=_init())
+            bias_attr=False, weight_attr=init())
         self.o_proj = Linear(heads * cfg.v_head_dim, h, bias_attr=False,
-                             weight_attr=_init(_out_std(cfg)))
+                             weight_attr=init(out_std(cfg)))
 
     def forward(self, x):
         import jax.numpy as jnp
@@ -173,9 +163,13 @@ class DeepseekV3Attention(Layer):
         with _scope.phase("assemble"):
             latent, k_pe = ops.split(
                 down, [cfg.kv_lora_rank, cfg.qk_rope_head_dim], axis=-1)
-        half = cfg.qk_rope_head_dim // 2
-        cos, sin = (jnp.repeat(t[:, :half], 2, axis=-1) for t in rope_angles(
-            np.arange(s), cfg.qk_rope_head_dim, cfg.rope_theta))
+        cos = sin = None
+        if cfg.rotate:
+            half = cfg.qk_rope_head_dim // 2
+            cos, sin = (jnp.repeat(t[:, :half], 2, axis=-1)
+                        for t in rope_angles(np.arange(s),
+                                             cfg.qk_rope_head_dim,
+                                             cfg.rope_theta))
         q, k, v = _heads(self.q_proj(x), self.kv_up(self.kv_norm(latent)),
                          k_pe, cos, sin, cfg)
         # the default scale is 1 / sqrt(q's width): sqrt(nope + rope)
@@ -185,113 +179,17 @@ class DeepseekV3Attention(Layer):
         return self.o_proj(ops.reshape(out, [b, s, -1]))
 
 
-class DeepseekV3DecoderLayer(Layer):
-    """One layer.  ``forward`` returns the new hidden state; a sparse
-    layer's routing tally is counted outside its recomputed region."""
-
+class DeepseekV3DecoderLayer(SparseDecoderLayer):
     def __init__(self, cfg: DeepseekV3Config, index: int):
-        super().__init__()
-        self.input_norm = RMSNorm(cfg.hidden_size, epsilon=cfg.norm_eps)
-        self.latent_attention = DeepseekV3Attention(cfg)
-        self.ffn_norm = RMSNorm(cfg.hidden_size, epsilon=cfg.norm_eps)
-        self.is_sparse = index >= cfg.first_k_dense_replace
-
-        def mlp(width):
-            return LlamaMLP(LlamaConfig(
-                hidden_size=cfg.hidden_size, num_layers=cfg.num_layers,
-                intermediate_size=width))
-
-        if self.is_sparse:
-            from ..incubate.distributed.models.moe import SparseMoEBlock
-            biases = cfg.expert_bias or ()
-            at = index - cfg.first_k_dense_replace
-            self.routed_experts = SparseMoEBlock(
-                cfg.hidden_size, cfg.moe_intermediate_size,
-                cfg.n_routed_experts, cfg.num_experts_per_tok,
-                expert_offset=cfg.expert_offset,
-                experts_held=cfg.experts_held,
-                routed_scaling_factor=cfg.routed_scaling_factor,
-                expert_bias=biases[at] if at < len(biases) else None,
-                weight_attr=_init(), down_attr=_init(_out_std(cfg)),
-                name=f"layer_{index}", norm_eps=cfg.router_norm_eps)
-            self.shared_expert = mlp(
-                cfg.n_shared_experts * cfg.moe_intermediate_size)
-        else:
-            self.mlp = mlp(cfg.intermediate_size)
-        self._recompute = cfg.recompute
-        self._policy = (cfg.recompute_policy
-                        if cfg.recompute_policy != "full" else None)
-
-    def _inner(self, x):
-        x = x + self.latent_attention(self.input_norm(x))
-        f = self.ffn_norm(x)
-        if not self.is_sparse:
-            return x + self.mlp(f)
-        # this chip's part of the routed experts' result, and the shared
-        # expert whole
-        part, *counts = self.routed_experts(f)
-        return (x + part + self.shared_expert(f), *counts)
-
-    def forward(self, x):
-        if self._recompute and self.training:
-            from ..distributed.fleet.recompute import recompute
-            out = recompute(self._inner, x, policy=self._policy)
-        else:
-            out = self._inner(x)
-        if self.is_sparse:
-            # outside the recomputed region, whose writes stay inside it
-            self.routed_experts.count(*out[1:])
-            return out[0]
-        return out
+        super().__init__(cfg, index, "latent_attention",
+                         DeepseekV3Attention(cfg))
 
 
-class DeepseekV3Model(Layer):
+class DeepseekV3Model(SparseDecoderModel):
     def __init__(self, cfg: DeepseekV3Config):
-        super().__init__()
-        self.cfg = cfg
-        self.embed_tokens = Embedding(cfg.vocab_size, cfg.hidden_size,
-                                      weight_attr=_init())
-        self.layers = [DeepseekV3DecoderLayer(cfg, i)
-                       for i in range(cfg.num_layers)]
-        for i, layer in enumerate(self.layers):
-            self.add_sublayer(f"layer_{i}", layer)
-        self.norm = RMSNorm(cfg.hidden_size, epsilon=cfg.norm_eps)
-
-    def forward(self, input_ids):
-        x = self.embed_tokens(input_ids)
-        for layer in self.layers:
-            x = layer(x)
-        return self.norm(x)
+        super().__init__(cfg, DeepseekV3DecoderLayer)
 
 
-class DeepseekV3ForCausalLM(Layer):
-    """An untied head; ``forward(ids, labels)`` is the mean next-token
-    cross-entropy (labels already shifted)."""
-
+class DeepseekV3ForCausalLM(SparseDecoderForCausalLM):
     def __init__(self, cfg: DeepseekV3Config):
-        super().__init__()
-        self.cfg = cfg
-        self.model = DeepseekV3Model(cfg)
-        self.lm_head = Linear(cfg.hidden_size, cfg.vocab_size,
-                              bias_attr=False, weight_attr=_init())
-
-    def logits(self, input_ids) -> Tensor:
-        return self.lm_head(self.model(input_ids))
-
-    def forward(self, input_ids, labels=None):
-        from .. import ops
-        logits = self.logits(input_ids)
-        if labels is None:
-            return logits
-        return F.cross_entropy(
-            ops.reshape(logits, [-1, self.cfg.vocab_size]),
-            ops.reshape(labels, [-1]))
-
-    def num_params(self) -> int:
-        return sum(p.size for p in self.parameters())
-
-    def sparse_blocks(self):
-        """{layer's name: its ``SparseMoEBlock``}."""
-        return {f"layer_{i}": layer.routed_experts
-                for i, layer in enumerate(self.model.layers)
-                if layer.is_sparse}
+        super().__init__(cfg, DeepseekV3Model(cfg))

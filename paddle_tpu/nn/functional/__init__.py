@@ -30,7 +30,7 @@ from .loss import (  # noqa: F401
 )
 from .attention import (  # noqa: F401
     scaled_dot_product_attention, flash_attention, flash_attn_qkvpacked,
-    flash_attn_unpadded, sdp_kernel,
+    flash_attn_unpadded, kda_chunk, sdp_kernel,
 )
 from .ring_attention import ring_flash_attention  # noqa: F401
 from .vision_ops import (  # noqa: F401

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...core.dispatch import apply
+from ...core.dispatch import apply, primitive
 
 
 def _use_pallas(q):
@@ -106,6 +106,28 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                          dropout_key=dk)
 
     return apply("scaled_dot_product_attention", impl, *args)
+
+
+@primitive
+def kda_chunk(q, k, v, g, beta, *, chunk=None, scale=None):
+    """Gated delta-rule linear attention with a per-channel decay (KDA),
+    by chunks: per head ``S_t = (I - b_t k_t k_t^T) diag(exp(g_t))
+    S_{t-1} + b_t k_t v_t^T``, ``o_t = S_t^T q_t * scale`` with q and k
+    L2-normalised here and ``scale`` = ``1 / sqrt(dk)`` by default.
+
+    ``q``, ``k``, ``g`` [batch, seq, heads, dk] (``g`` at most 0, the
+    decay's logarithm per channel), ``v`` [batch, seq, heads, dv],
+    ``beta`` [batch, seq, heads] in (0, 1); the result has v's shape and
+    q's dtype.  Forward and a hand-written backward over chunks of
+    ``chunk`` positions (``ops/pallas/kda.py``, whose ``CHUNK`` is the
+    default): the Pallas kernels on a
+    TPU, the same algebra through XLA elsewhere.  Under AMP O2 the
+    dispatch hands an op every input in the compute type: a caller
+    that wants ``g`` and its running sums in float32 makes the gate
+    inside its own op and calls ``kda_chunk.raw``, as
+    ``models/kimi_linear.py`` does."""
+    from ...ops.pallas import kda
+    return kda.kda_chunk(q, k, v, g, beta, chunk=chunk, scale=scale)
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,

@@ -49,8 +49,9 @@ TPU_REFUSED = {
 from . import flash_attention  # noqa: E402
 from . import fused_optimizer  # noqa: E402
 from . import fused_residual_norm  # noqa: E402
+from . import kda  # noqa: E402
 from . import norms  # noqa: E402
 from . import rope  # noqa: E402
 
 __all__ = ["flash_attention", "fused_optimizer", "fused_residual_norm",
-           "norms", "rope", "out_struct", "use_interpret", "TPU_REFUSED"]
+           "kda", "norms", "rope", "out_struct", "use_interpret", "TPU_REFUSED"]

@@ -1,0 +1,24 @@
+"""Least time the chip could take for the gated delta rule the step
+needs (one forward and one backward per KDA layer per step; a forward
+run again by recompute adds time and no need) over the summed device
+time of ``kda_chunk_fwd`` and ``kda_chunk_bwd``."""
+from perf import readers
+
+
+def read(run):
+    ctx = run.ctx
+    shape_of = getattr(ctx.models, "kda_shape", None)
+    if shape_of is None or run.trace is None:
+        return None
+    n_fwd, t_fwd = run.trace.kernel_seconds("kda_chunk_fwd")
+    n_bwd, t_bwd = run.trace.kernel_seconds("kda_chunk_bwd")
+    if not n_bwd or not n_fwd:
+        return None
+    shape = shape_of(ctx.cfg, ctx.traffic["batch"])
+    cost = readers.kernel_cost("kda_chunk")
+    fwd, how_f = readers.least_seconds(*cost.fwd(**shape), ctx.peaks)
+    bwd, how_b = readers.least_seconds(*cost.bwd(**shape), ctx.peaks)
+    run.note(kda_chunk_bound={"fwd": how_f, "bwd": how_b},
+             kda_chunk_calls={"fwd": n_fwd, "bwd": n_bwd},
+             kda_chunk_device_s={"fwd": t_fwd, "bwd": t_bwd})
+    return readers.roofline_share(n_bwd * (fwd + bwd), t_fwd + t_bwd)

@@ -280,15 +280,20 @@ def test_flash_attention_gqa_8k_fwd_bwd_compiles(chip):
         8192, 64, 2, *FA._bwd_block_sizes(8192, 8192, True)) > 37 << 20
 
 
-def test_flash_attention_latent_8k_fwd_bwd_compiles(chip):
-    """Moonlight-16B-A3B's latent attention at the benchmark's shape: 16
-    heads, keys of 192 (128 un-rotated + 64 rotated), values of 128,
-    8192 positions, one row.  The scores contract over 192, which is no
+@pytest.mark.parametrize("heads", [
+    pytest.param(16, id="moonlight-16b-a3b"),
+    pytest.param(32, id="kimi-linear-48b-a3b"),
+])
+def test_flash_attention_latent_8k_fwd_bwd_compiles(chip, heads):
+    """Latent attention at the benchmark's shapes: Moonlight-16B-A3B's 16
+    heads and Kimi-Linear-48B-A3B's 32 (un-rotated: the same kernel
+    call), keys of 192 (128 + 64), values of 128, 8192 positions, one
+    row.  The scores contract over 192, which is no
     multiple of the 128 lanes: q, dq and the dq accumulator take 256
     lanes a position in VMEM, v, do and dv 128; the tensors in HBM keep
     192 and 128.  The backward's whole-row buffers are 54.5 MB here."""
-    q = chip((1, 8192, 16, 192), BF16)
-    v = chip((1, 8192, 16, 128), BF16)
+    q = chip((1, 8192, heads, 192), BF16)
+    v = chip((1, 8192, heads, 128), BF16)
 
     def grads(q, k, v):
         return jax.grad(
@@ -300,12 +305,41 @@ def test_flash_attention_latent_8k_fwd_bwd_compiles(chip):
     text = compiled.as_text()
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
     # nothing in HBM is padded to the lanes: no 256-wide array
-    assert "8192,256]" not in text and "8192,16,256]" not in text
+    assert "8192,256]" not in text and f"8192,{heads},256]" not in text
     dq, dk, dv = compiled.out_info
     assert (dq.shape, dk.shape, dv.shape) == (q.shape, q.shape, v.shape)
+    # the head count is in the autotune and gauge key, not in the VMEM
+    # the backward asks for
+    assert FA._shape_sig((1, heads, 8192, 192), 8192, True, 128) \
+        == f"b1h{heads}sq8192sk8192d192v128c1"
     limit = FA._bwd_vmem_limit(
         8192, 192, 2, *FA._bwd_block_sizes(8192, 8192, True), dv=128)
     assert 54 << 20 < limit < 128 << 20
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_kda_chunk_fwd_bwd_compiles(chip, chunk):
+    """The gated delta rule's two kernels at Kimi-Linear-48B-A3B's shape
+    (32 heads of 128, 8192 positions, one row, bfloat16 operands and a
+    float32 decay): the sub-blocks' single-row slices, the transposed
+    products, the ``HIGHEST`` products of the triangular inverse and the
+    blocks cut from the [B, S, H * d] layout all pass the chip's
+    compiler; chunk 128 is the configuration's."""
+    from paddle_tpu.ops.pallas import kda
+    x = chip((1, 8192, 32, 128), BF16)
+    g, beta = chip((1, 8192, 32, 128), F32), chip((1, 8192, 32), F32)
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(
+            lambda *a: kda.kda_chunk(*a, chunk=chunk, how="pallas").astype(
+                F32).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    compiled = chip.compile(grads, x, x, x, g, beta)
+    text = compiled.as_text()
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    assert [o.shape for o in compiled.out_info] == [
+        x.shape, x.shape, x.shape, g.shape, beta.shape]
+    assert [o.dtype for o in compiled.out_info] == [BF16] * 3 + [F32] * 2
 
 
 def test_sparse_moe_grouped_products_compile(chip):

@@ -420,7 +420,19 @@ def test_fit_populates_global_registry(metrics_on):
     assert reg.gauge("train.tokens_per_sec").value > 0
 
 
-def test_eager_optimizer_step_telemetry(metrics_on):
+@pytest.fixture
+def fused_opt_on():
+    """The fused optimizer path is the default, but a flag is the
+    process's: a test of another file that builds a benchmark program
+    in this worker (``perf/models/common.TrainProgram`` sets the
+    configuration's ``fused_optimizer``) leaves it off."""
+    old = paddle.get_flags("fused_opt")["fused_opt"]
+    paddle.set_flags({"fused_opt": True})
+    yield
+    paddle.set_flags({"fused_opt": old})
+
+
+def test_eager_optimizer_step_telemetry(metrics_on, fused_opt_on):
     import paddle_tpu.nn as nn
     reg = obs.registry()
     h0 = reg.histogram("train.opt_step_ms").count
