@@ -100,7 +100,9 @@ class KimiLinearConfig:
 def _gated_delta(q, k, v, f, b, a_log, dt_bias, heads, chunk):
     """The decay gate and the operator in ONE op, so that under AMP O2,
     which hands every op its inputs in the compute type, the gate and
-    its running sums are made in float32 from the projections."""
+    its running sums are made in float32 from the projections: the
+    gate here, the running sums inside ``F.kda_chunk``'s chunk kernels
+    from the float32 ``g`` it is handed."""
 
     def impl(q, k, v, f, b, a_log, dt_bias):
         import jax

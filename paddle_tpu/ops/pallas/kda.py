@@ -49,24 +49,44 @@ read in the caller's [B, S, H * d] layout with no transpose.
 Elsewhere ``jax.lax.scan`` over the chunks runs the same bodies under
 ``vmap`` (the CPU path and the tests' second witness).
 
+**The decay's running sum is the chunk body's own**: the core takes
+the decay's logarithm ``g`` as the model hands it over, and both
+bodies begin with ``G = _running_sum(g)`` on the [C, dk] float32 tile
+they hold: log2(C) adds of the rows shifted by 1, 2, 4, ....  The
+backward body ends with the transposed sum of its ``dG`` (``dg_i =
+sum_{j >= i} dG_j``; the chunk's last row of ``G`` also decays the
+state, and that term goes through the same sum) and returns ``dg``.
+Nothing scans over positions outside the kernels: XLA makes a
+``cumsum`` over a reshaped axis a window scan over the whole decay,
+134 MB a layer at the shape below, in the forward, again in the
+layer's recompute and transposed in the backward.
+
 **The backward** is a ``jax.custom_vjp``: a sweep over the chunks in
 reverse that carries ``dS``.  The forward SAVES the state each chunk
 starts from ([B, H, S / C, dv, dk] float32: 268 MB a layer at 1 x 32 x
 8192 x 128 x 128 and chunks of 64, 134 MB at 128) and the backward
-recomputes the chunk's scores, inverse and corrected values from q, k,
-v, G and that state: recomputing the states instead is a second whole
-forward sweep, a third of the operator's work, for memory a step has
-to spare.
+recomputes the chunk's running sum, scores, inverse and corrected
+values from q, k, v, g and that state: recomputing the states instead
+is a second whole forward sweep, a third of the operator's work, for
+memory a step has to spare.
 
 On a TPU v5e at [1, 8192, 32, 128] bfloat16 with a float32 decay, the
-operator with its glue, forward / forward + backward: chunk 32 14.42 /
-36.89 ms, 64 11.78 / 31.62, 128 10.57 / 28.18 (2026-09-30, PERF.md
-PR 37).  The body is bound by its column-at-a-time score loops and
-its chain of small float32 products, not by the MXU or the memory.
+operator with its glue, forward / forward + backward: chunk 32 14.04 /
+36.58 ms, 64 11.38 / 31.51, 128 9.68 / 27.12 (2026-10-01, PERF.md
+PR 38).  The shifted adds are 0.08 ms of the forward kernel's 7.77 at
+chunk 128 and 0.10 of the backward's 11.91, where XLA's two window
+scans take 1.31 ms a layer; as one product with the triangle of ones,
+the decay cut into three bfloat16 parts, the same sums cost the
+kernels 0.35 + 0.74 ms, at ``HIGHEST`` 0.46 + 1.07.  The body is
+bound by its column-at-a-time score loops and its chain of small
+float32 products, not by the MXU or the memory.
 
-The decay's running sum, the L2 normalisation of q and k, the scale
-and the folding of ``b`` into k and v are plain jnp round the core
-(``kda_chunk`` below) and left to autodiff.
+The L2 normalisation of q and k, the scale and the folding of ``b``
+into k and v are plain jnp round the core (``kda_chunk`` below) and
+left to autodiff.  Each per-head reduction over the 128 lanes there
+costs a layout copy of the whole tensor: the kernels read [B, S, H *
+d] (8 positions to a tile), XLA reduces [B, S, H, d] with the heads
+on the sublanes (PERF.md, PR 38).
 """
 from __future__ import annotations
 
@@ -101,6 +121,22 @@ def _mm32(a, b, dims=_NN):
 
 def _iota(shape, axis):
     return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _running_sum(x, reverse=False):
+    """``x_1 + .. + x_i`` down the rows of a float32 [C, d] (``reverse``:
+    ``x_i + .. + x_C``, its transpose): log2(C) adds of the rows shifted
+    by 1, 2, 4, ..., float32 throughout.  On a v5e these sublane shifts
+    cost a chunk body a fifth of what one product with the triangle of
+    ones does (the head of this file)."""
+    C, d = x.shape
+    shift = 1
+    while shift < C:
+        zeros = jnp.zeros((shift, d), x.dtype)
+        x = x + (jnp.concatenate([x[shift:], zeros], axis=0) if reverse
+                 else jnp.concatenate([zeros, x[:C - shift]], axis=0))
+        shift *= 2
+    return x
 
 
 # --------------------------------------------------------------------------
@@ -228,12 +264,14 @@ def _solve(n):
 # --------------------------------------------------------------------------
 # the chunk's body
 # --------------------------------------------------------------------------
-def _chunk_fwd(q, k, kb, vb, G, st, cd):
+def _chunk_fwd(q, k, kb, vb, g, st, cd):
     """One chunk: q (scaled), k, kb = b k [C, dk], vb = b v [C, dv],
-    G [C, dk] float32, ``st`` the state it starts from, TRANSPOSED
-    [dv, dk] float32 -> (o [C, dv] float32, the state it leaves)."""
+    the decay's logarithm g [C, dk] float32, ``st`` the state it starts
+    from, TRANSPOSED [dv, dk] float32 -> (o [C, dv] float32, the state
+    it leaves)."""
     C = q.shape[0]
     q, k, kb, vb = (x.astype(_F32) for x in (q, k, kb, vb))
+    G = _running_sum(g)
     m, n = _scores(q, k, kb, G, cd)
     x = _solve(n)
     e = jnp.exp(G)
@@ -244,13 +282,14 @@ def _chunk_fwd(q, k, kb, vb, G, st, cd):
     return o, st
 
 
-def _chunk_bwd(q, k, kb, vb, G, st, do, dst, cd):
+def _chunk_bwd(q, k, kb, vb, g, st, do, dst, cd):
     """The chunk's backward from its inputs, the state it started from,
     ``do`` [C, dv] and the gradient ``dst`` [dv, dk] of the state it
-    left -> (dq, dk, dkb [C, dk], dvb [C, dv], dG [C, dk], the gradient
+    left -> (dq, dk, dkb [C, dk], dvb [C, dv], dg [C, dk], the gradient
     of the state it started from), all float32."""
     C = q.shape[0]
     q, k, kb, vb, do = (x.astype(_F32) for x in (q, k, kb, vb, do))
+    G = _running_sum(g)
     row, col = _iota((C, C), 0), _iota((C, C), 1)
     m, n = _scores(q, k, kb, G, cd)
     x = _solve(n)
@@ -272,7 +311,7 @@ def _chunk_bwd(q, k, kb, vb, G, st, do, dst, cd):
     dst0 = dst0 - _mm(dr, kgb, _TN, cd)
     dn = jnp.where(row > col, -_mm32(_mm32(x, dx, _TN), x, _NT), 0.0)
 
-    dq, dk, dkb, dg = _scores_bwd(q, k, kb, G, dm, dn, cd)
+    dq, dk, dkb, dG = _scores_bwd(q, k, kb, G, dm, dn, cd)
     t = dkbar * kbar
     dq = dq + dqg * e
     dkb = dkb + dkgb * e
@@ -280,9 +319,10 @@ def _chunk_bwd(q, k, kb, vb, G, st, do, dst, cd):
     # the chunk's last row of G also decays the state and the keys
     last = (jnp.sum(t, axis=0, keepdims=True)
             + jnp.sum(dst * st, axis=0, keepdims=True) * ec)
-    dg = dg + dqg * qg + dkgb * kgb - t \
+    dG = dG + dqg * qg + dkgb * kgb - t \
         + jnp.where(_iota((C, 1), 0) == C - 1, last, 0.0)
-    return dq, dk, dkb, dr, dg, dst0
+    # g_i is in every G_j from row i on
+    return dq, dk, dkb, dr, _running_sum(dG, reverse=True), dst0
 
 
 # --------------------------------------------------------------------------
@@ -301,38 +341,38 @@ def _by_row(x):
 
 
 @functools.partial(jax.jit, static_argnames="chunk")
-def _fwd_xla(q, k, kb, vb, G, chunk):
+def _fwd_xla(q, k, kb, vb, g, chunk):
     cd = q.dtype
     dk, dv = q.shape[-1], vb.shape[-1]
 
-    def head(q, k, kb, vb, G):
+    def head(q, k, kb, vb, g):
         def step(st, xs):
             o, new = _chunk_fwd(*xs, st, cd)
             return new, (o, st)
         _, (o, states) = lax.scan(step, jnp.zeros((dv, dk), _F32),
-                                  (q, k, kb, vb, G))
+                                  (q, k, kb, vb, g))
         return o, states
 
     o, states = jax.vmap(jax.vmap(head))(
-        *(_by_chunk(x, chunk) for x in (q, k, kb, vb, G)))
+        *(_by_chunk(x, chunk) for x in (q, k, kb, vb, g)))
     return _by_row(o).astype(cd), states
 
 
 @functools.partial(jax.jit, static_argnames="chunk")
-def _bwd_xla(q, k, kb, vb, G, states, do, chunk):
+def _bwd_xla(q, k, kb, vb, g, states, do, chunk):
     cd = q.dtype
     dk, dv = q.shape[-1], vb.shape[-1]
 
-    def head(q, k, kb, vb, G, states, do):
+    def head(q, k, kb, vb, g, states, do):
         def step(dst, xs):
             *grads, dst = _chunk_bwd(*xs, dst, cd)
             return dst, tuple(grads)
         _, grads = lax.scan(step, jnp.zeros((dv, dk), _F32),
-                            (q, k, kb, vb, G, states, do), reverse=True)
+                            (q, k, kb, vb, g, states, do), reverse=True)
         return grads
 
     grads = jax.vmap(jax.vmap(head))(
-        *(_by_chunk(x, chunk) for x in (q, k, kb, vb, G)), states,
+        *(_by_chunk(x, chunk) for x in (q, k, kb, vb, g)), states,
         _by_chunk(do, chunk))
     return tuple(_by_row(x) for x in grads)
 
@@ -393,7 +433,7 @@ def _params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _fwd_pallas(q, k, kb, vb, G, chunk, interpret):
+def _fwd_pallas(q, k, kb, vb, g, chunk, interpret):
     b, s, h, dk = q.shape
     dv, n = vb.shape[-1], s // chunk
     _publish_chunks("fwd", q.shape, dv, chunk)
@@ -414,11 +454,11 @@ def _fwd_pallas(q, k, kb, vb, G, chunk, interpret):
         compiler_params=_params(),
         interpret=interpret,
         name="kda_chunk_fwd",
-    )(*(_flat(x) for x in (q, k, kb, vb, G)))
+    )(*(_flat(x) for x in (q, k, kb, vb, g)))
     return o.reshape(b, s, h, dv), states
 
 
-def _bwd_pallas(q, k, kb, vb, G, states, do, chunk, interpret):
+def _bwd_pallas(q, k, kb, vb, g, states, do, chunk, interpret):
     b, s, h, dk = q.shape
     dv, n = vb.shape[-1], s // chunk
     _publish_chunks("bwd", q.shape, dv, chunk)
@@ -442,7 +482,7 @@ def _bwd_pallas(q, k, kb, vb, G, states, do, chunk, interpret):
         compiler_params=_params(),
         interpret=interpret,
         name="kda_chunk_bwd",
-    )(*(_flat(x) for x in (q, k, kb, vb, G)), states, _flat(do))
+    )(*(_flat(x) for x in (q, k, kb, vb, g)), states, _flat(do))
     return tuple(x.reshape(b, s, h, -1) for x in grads)
 
 
@@ -450,16 +490,16 @@ def _bwd_pallas(q, k, kb, vb, G, states, do, chunk, interpret):
 # the core and its gradient
 # --------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _core(q, k, kb, vb, G, chunk, how):
-    return _core_fwd(q, k, kb, vb, G, chunk, how)[0]
+def _core(q, k, kb, vb, g, chunk, how):
+    return _core_fwd(q, k, kb, vb, g, chunk, how)[0]
 
 
-def _core_fwd(q, k, kb, vb, G, chunk, how):
+def _core_fwd(q, k, kb, vb, g, chunk, how):
     if how == "xla":
-        o, states = _fwd_xla(q, k, kb, vb, G, chunk=chunk)
+        o, states = _fwd_xla(q, k, kb, vb, g, chunk=chunk)
     else:
-        o, states = _fwd_pallas(q, k, kb, vb, G, chunk, how == "interpret")
-    return o, (q, k, kb, vb, G, states)
+        o, states = _fwd_pallas(q, k, kb, vb, g, chunk, how == "interpret")
+    return o, (q, k, kb, vb, g, states)
 
 
 def _core_bwd(chunk, how, res, do):
@@ -518,15 +558,13 @@ def kda_chunk(q, k, v, g, beta, *, chunk=None, scale=None, how=None):
     bf = beta.astype(_F32)[..., None]
     kn = _l2(k)
     pad = -s % chunk
-    sp = s + pad
 
     def padded(x):
         # k = 0 and g = 0: the state passes a padded position unchanged
+        # (the running sum of 0 is flat)
         return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
 
-    G = jnp.cumsum(padded(g.astype(_F32)).reshape(
-        b, sp // chunk, chunk, h, dk), axis=2).reshape(b, sp, h, dk)
     return _core(padded((_l2(q) * scale).astype(cd)), padded(kn.astype(cd)),
                  padded((kn * bf).astype(cd)),
-                 padded((v.astype(_F32) * bf).astype(cd)), G, chunk,
-                 how)[:, :s]
+                 padded((v.astype(_F32) * bf).astype(cd)),
+                 padded(g.astype(_F32)), chunk, how)[:, :s]

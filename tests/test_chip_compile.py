@@ -337,6 +337,10 @@ def test_kda_chunk_fwd_bwd_compiles(chip, chunk):
     compiled = chip.compile(grads, x, x, x, g, beta)
     text = compiled.as_text()
     assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    # the decay's running sum is the kernels' own: XLA holds no window
+    # scan and no decay cut [B, S / chunk, chunk, H, d] for one
+    assert "reduce-window" not in text
+    assert f"f32[1,{8192 // chunk},{chunk},32,128]" not in text
     assert [o.shape for o in compiled.out_info] == [
         x.shape, x.shape, x.shape, g.shape, beta.shape]
     assert [o.dtype for o in compiled.out_info] == [BF16] * 3 + [F32] * 2

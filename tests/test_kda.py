@@ -192,6 +192,44 @@ def test_the_solve_is_the_inverse_of_a_unit_lower_triangle():
         close(x @ (np.eye(size) + n), np.eye(size), tol=1e-5)
 
 
+@pytest.mark.parametrize("chunk", K.CHUNKS)
+def test_the_bodys_running_sum_and_its_transpose_are_float32s(chunk):
+    """The chunk body makes the decay's running sum itself: against
+    numpy's float64 ``cumsum`` (and the reversed one), at an ordinary
+    decay and at -20 a step (a chunk of 128 then reaches -2,560)."""
+    rng = np.random.default_rng(8)
+    for g_min in (-1.0, -20.0):
+        g = (g_min * rng.random((chunk, 128))).astype("f4")
+        want = np.cumsum(g.astype("f8"), axis=0)
+        close(jax.jit(K._running_sum)(g), want, tol=1e-6)
+        close(jax.jit(lambda x: K._running_sum(x, reverse=True))(g),
+              np.cumsum(g.astype("f8")[::-1], axis=0)[::-1], tol=1e-6)
+
+
+def test_no_running_sum_is_left_to_xla_round_the_kernels():
+    """Forward and backward, the only scans over positions are inside
+    the two ``pallas_call``s: XLA makes a ``cumsum`` over a reshaped
+    axis a window scan over the whole decay, three times a layer a
+    step."""
+    args, w = operands(40, seed=9)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(K.kda_chunk(*a, chunk=16, how="interpret") * w),
+        argnums=(0, 1, 2, 3, 4)))(*args)
+    names = []
+
+    def walk(part):
+        for eqn in part.eqns:
+            names.append(eqn.primitive.name)
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert names.count("pallas_call") == 2
+    assert not [n for n in names if n.startswith("cum")
+                or n.startswith("reduce_window")], names
+
+
 def test_the_chunks_gauge_is_set_while_a_kernel_call_is_traced():
     from paddle_tpu.observability import metrics
     args, w = operands(40, seed=7)
