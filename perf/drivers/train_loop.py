@@ -164,8 +164,6 @@ def run(ctx):
              reference_s=reference_s,
              peak_bytes_after_reference=peak_after_reference,
              model_flops_per_token=flops,
-             model_flops_share_of_peak_not_a_kernel_roofline=rate * flops
-             / (ctx.peaks["bf16_flops_per_s"] * len(ctx.devices)),
              checks=checks.as_dict())
     return run
 

@@ -10,18 +10,18 @@ from perf import readers, scope_readers
 
 def read(run):
     ctx = run.ctx
+    shape_of = getattr(ctx.models, "expert_shape", None)
     slots = scope_readers.expert_slots_a_traced_step(run)
     spent_ms = scope_readers.device_ms_under(run, **scope_readers.EXPERT_MLP)
-    if slots is None or not spent_ms:
+    if shape_of is None or slots is None or not spent_ms:
         return None
-    cfg = ctx.cfg
+    shape = shape_of(ctx.cfg)
     cost = readers.kernel_cost("expert_mlp")
     least, bounds = 0.0, set()
     for routed in slots.values():
         for need in (cost.fwd, cost.bwd):
             t, how = readers.least_seconds(
-                *need(routed, cfg["num_experts"], cfg["hidden_size"],
-                      cfg["moe_intermediate_size"]), ctx.peaks)
+                *need(routed, **shape), ctx.peaks)
             least += t
             bounds.add(how)
     run.note(expert_mlp_slots_a_traced_step=slots,

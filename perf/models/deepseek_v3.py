@@ -87,6 +87,14 @@ def routed_share(cfg):
     return cfg["n_routed_experts"] / cfg["published"]["n_routed_experts"]
 
 
+def expert_shape(cfg):
+    """The held experts' products' shapes, for
+    ``kernel_costs/expert_mlp``: the experts held here, the hidden
+    width and an expert's width."""
+    return dict(held=cfg["n_routed_experts"], h=cfg["hidden_size"],
+                i=cfg["moe_intermediate_size"])
+
+
 def train_flops_per_token(cfg, batch):
     """6 x the parameters a token multiplies with + attention's scores
     and values: every matrix outside the routed experts once (the

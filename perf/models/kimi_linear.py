@@ -18,8 +18,10 @@ from . import common
 # the latent-attention layers' flash call has deepseek_v3's shapes under
 # the same keys (the mean of the keys' and the values' widths)
 from .deepseek_v3 import attention_shape  # noqa: F401
-# the sparse block's counters are the block's, whatever the family
-from .lfm2_moe import expert_calls, expert_counters  # noqa: F401
+# the sparse block's counters are the block's, whatever the family, and
+# this configuration spells the held experts as LFM2's does
+from .lfm2_moe import (expert_calls, expert_counters,  # noqa: F401
+                       expert_shape)
 
 _MLP = {"w1": "gate_proj.weight", "w3": "up_proj.weight",
         "w2": "down_proj.weight"}

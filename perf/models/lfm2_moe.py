@@ -77,6 +77,14 @@ def routed_share(cfg):
     return cfg["num_experts"] / cfg["published"]["num_experts"]
 
 
+def expert_shape(cfg):
+    """The held experts' products' shapes, for
+    ``kernel_costs/expert_mlp``: the experts held here, the hidden
+    width and an expert's width."""
+    return dict(held=cfg["num_experts"], h=cfg["hidden_size"],
+                i=cfg["moe_intermediate_size"])
+
+
 def train_flops_per_token(cfg, batch):
     """6 x the parameters a token multiplies with + attention's scores
     and values: every parameter outside the experts once; of the held

@@ -13,6 +13,23 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+# the cells with a ``SparseMoEBlock``, in the order they came
+SPARSE_CELLS = ["lfm2-24b-a2b.pretrain_8k", "moonlight-16b-a3b.pretrain_8k",
+                "kimi-linear-48b-a3b.pretrain_8k"]
+# per-layer metrics every training cell reports (test_perf_phases.py,
+# test_perf_step_mfu.py) and every cell with a sparse block does
+# (test_perf_lfm2.py); the block's first three are its device time by
+# parts
+EVERY_STEP = ("forward_device_ms.train", "recompute_device_ms.train",
+              "backward_device_ms.train", "optimizer_device_ms.train",
+              "head_loss_device_ms.train", "unattributed_device_share.train",
+              "launch_ms.train", "state_io_ms.train", "step_mfu.train")
+EVERY_BLOCK = ("router_device_ms.train", "expert_dispatch_device_ms.train",
+               "expert_mlp_device_ms.train", "expert_mlp_roofline.train",
+               "expert_load_max_over_mean.train",
+               "expert_rows_run_share.train")
+BLOCK_PARTS = EVERY_BLOCK[:3]
+
 TRAIN_SHAPE = {"gpt2": dict(rows=4, seq_len=32),
                "bert": dict(rows=4, seq_len=32, masked_per_row=5)}
 TRAIN_MIX = {"gpt2": "pretrain_lm_8x1024", "bert": "pretrain_mlm_16x512"}

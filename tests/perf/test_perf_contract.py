@@ -117,6 +117,28 @@ def test_every_name_has_its_file(bench):
         assert not word.startswith("/") and ".." not in word
 
 
+# readers of a training cell that no entry declares, each with its
+# reason: keep this list short
+UNDECLARED = {
+    "fused_optimizer_roofline.train": "every configuration sets the fused "
+    "optimizer off, so no cell runs the kernel it reads (ROADMAP R0d)",
+}
+TRAIN_READERS = sorted(
+    f[:-len(".py")] for f in os.listdir(os.path.join(ROOT, "perf", "metrics"))
+    if f.endswith(".train.py"))
+
+
+@pytest.mark.parametrize("reader", TRAIN_READERS)
+def test_every_training_reader_is_declared_or_listed_with_its_reason(
+        bench, reader):
+    """A reader without an entry is read by no run and held by no
+    ledger line: it ships with its entry, or stands in ``UNDECLARED``."""
+    declared = reader in {m["name"] for m in bench["per_layer"]}
+    assert declared != (reader in UNDECLARED), reader
+    assert all(UNDECLARED.values())
+    assert set(UNDECLARED) <= set(TRAIN_READERS)
+
+
 def test_unknown_device_kind_is_an_error():
     assert loader.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     assert loader.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9

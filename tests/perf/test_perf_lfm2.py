@@ -105,9 +105,12 @@ def test_model_flops_count_expert_work_by_the_routed_share(cfg):
 
 # ----------------------------------------- readers on a synthetic table
 class _Models:
-    def __init__(self, tokens, shares, calls):
+    def __init__(self, tokens, shares, calls, family="lfm2_moe"):
         self.expert_counters = lambda: (tokens, shares)
         self.expert_calls = lambda: calls
+        # the held experts' shapes as the family's adapter reads them
+        # from the configuration's own spelling
+        self.expert_shape = loader.module("models", family).expert_shape
 
 
 class _Ctx:
@@ -142,7 +145,7 @@ OPS = [     # (op_name, duration in ns) of one step, two steps traced
 ]
 
 
-def _synthetic_run(models):
+def _synthetic_run(models, cfg=None):
     events, names, at = [], [], 1000
     for _ in range(2):
         for i, (op, ns) in enumerate(OPS):
@@ -160,6 +163,8 @@ def _synthetic_run(models):
                                          "events": host}]}]}
     ctx = _Ctx()
     ctx.models = models
+    if cfg is not None:
+        ctx.cfg = cfg
     run = common.Run(ctx)
     run.trace = tr.Trace({"planes": [
         {"name": p["name"], "lines": [{"name": ln["name"],
@@ -200,6 +205,42 @@ def test_the_six_readers_on_a_synthetic_table(monkeypatch):
         run) is None
 
 
+@pytest.mark.parametrize("family, cfg", [
+    ("lfm2_moe", _Ctx.cfg),
+    ("kimi_linear", {"num_experts_per_token": 4, "num_experts": 2,
+                     "hidden_size": 8, "moe_intermediate_size": 4}),
+    ("deepseek_v3", {"num_experts_per_tok": 4, "n_routed_experts": 2,
+                     "hidden_size": 8, "moe_intermediate_size": 4}),
+])
+def test_the_roofline_reads_the_held_experts_in_each_family_s_spelling(
+        family, cfg, monkeypatch):
+    """Moonlight's file spells the held experts ``n_routed_experts``,
+    LFM2's and Kimi-Linear's ``num_experts``: the reader asks the
+    cell's adapter, and the same slots, widths and device time give
+    the same share under every spelling."""
+    monkeypatch.setattr(tr, "find_xplane", lambda d: "synthetic")
+    read = loader.module("metrics", "expert_mlp_roofline.train").read
+
+    def run_of(family, cfg):
+        calls = {"layer_2": {n: [50, 50, 400] for n in range(1, 5)}}
+        run, raw = _synthetic_run(_Models(
+            {"layer_2": [300, 100]}, {"layer_2": 0.25}, calls, family), cfg)
+        run.counters = {"steps": 4}
+        monkeypatch.setattr(pr, "load", lambda path: raw)
+        return run
+
+    run = run_of(family, cfg)
+    cost = loader.module("kernel_costs", "expert_mlp")
+    least = sum(max(f / 1e12, b / 1e11) for f, b in
+                (cost.fwd(100, 2, 8, 4), cost.bwd(100, 2, 8, 4)))
+    got = read(run)
+    assert got == pytest.approx(100 * least / 4500e-9)
+    assert got == read(run_of("lfm2_moe", _Ctx.cfg))     # to the last digit
+    # an adapter that cannot say what it holds gives nothing
+    del run.ctx.models.expert_shape
+    assert read(run) is None
+
+
 def test_the_readers_find_nothing_on_a_program_without_the_scopes(
         monkeypatch):
     """The parent commit's program: no expert scope in the trace, no
@@ -223,9 +264,16 @@ def test_the_readers_find_nothing_on_a_program_without_the_scopes(
 
 
 @pytest.mark.parametrize("metric", METRICS)
-def test_each_metric_has_an_entry_for_the_cell_alone(metric):
+def test_each_metric_has_an_entry_for_the_cells_it_reads(metric):
+    """``SparseMoEBlock`` is one block for three cells: its metrics list
+    the cell they were written for first and the other sparse cells
+    after it (a later cell may follow); the short conv is LFM2's."""
     entry = loader.by_name(loader.benchmark()["per_layer"], metric, "metric")
-    assert entry["workloads"] == [CELL]
+    assert entry["workloads"][0] == CELL
+    if metric.startswith("short_conv"):
+        assert entry["workloads"] == [CELL]
+    else:
+        assert set(L.SPARSE_CELLS) <= set(entry["workloads"])
     assert entry["moves"] == "train_tokens_per_s"
     assert entry["source"] == ("program_counter" if "load" in metric
                                else "device_trace")

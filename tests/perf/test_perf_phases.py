@@ -259,8 +259,14 @@ def test_an_untraced_run_gives_none():
 @pytest.mark.parametrize("metric", NEW_METRICS)
 def test_each_new_metric_has_a_reader_and_an_entry(metric):
     assert callable(loader.module("metrics", metric).read)
-    entry = loader.by_name(loader.benchmark()["per_layer"], metric, "metric")
-    assert entry["workloads"] == ["gpt2-medium.pretrain"]
+    bench = loader.benchmark()
+    entry = loader.by_name(bench["per_layer"], metric, "metric")
+    # the readers read every family's step: the cell they were written
+    # on stays first, and every cell the training loop drives is there
+    assert entry["workloads"][0] == "gpt2-medium.pretrain"
+    trained = {w["name"] for w in bench["workloads"] if loader.data(
+        "traffic", w["traffic"])["driver"] == "train_loop"}
+    assert len(trained) >= 4 and trained <= set(entry["workloads"])
     assert entry["moves"] == "train_tokens_per_s"
     assert entry["source"] == ("program_span" if metric in NEW_METRICS[6:]
                                else "device_trace")
