@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from typing import Any, Optional
 
 import jax
@@ -22,6 +23,8 @@ import numpy as np
 
 from . import state
 from .dtype import Place, convert_dtype
+from ..observability import steptimer as _steptimer
+from ..observability import tracing as _tracing
 
 
 # Active capture tracker (set by paddle_tpu.jit); sees every read/write of
@@ -61,6 +64,10 @@ def __getattr__(name):
 # time and its id() gets reused by a LATER tensor — whose seeded
 # cotangent would then alias onto the dead output's tape slot.
 _uid_counter = itertools.count(1)
+
+
+def _item(value):
+    return value.item()
 
 
 class Tensor:
@@ -322,17 +329,32 @@ class Tensor:
         return ops.assign(self)
 
     # --- host interop ---------------------------------------------------
+    def _host(self, convert):
+        """``convert`` of this tensor's value, on the host: where the
+        eight methods below wait for the device.  Outside a capture the
+        wait is the span ``tensor.readback`` and, for a device array, a
+        row of the read log (``observability/steptimer.py``); under a
+        capture nothing is recorded."""
+        if _tracker_tls.value is not None:
+            return convert(self._read())
+        with _tracing.span("tensor.readback") as wait:
+            value = self._read()
+            out = convert(value)
+            if wait.t0 and isinstance(value, jax.Array):
+                _steptimer.note_read(wait.t0, time.perf_counter_ns())
+        return out
+
     def numpy(self) -> np.ndarray:
-        return np.asarray(self._read())
+        return self._host(np.asarray)
 
     def item(self):
-        return self._read().item()
+        return self._host(_item)
 
     def tolist(self):
-        return np.asarray(self._read()).tolist()
+        return self._host(np.asarray).tolist()
 
     def __array__(self, dtype=None):
-        a = np.asarray(self._read())
+        a = self._host(np.asarray)
         return a.astype(dtype) if dtype is not None else a
 
     def __dlpack__(self, *a, **k):
@@ -345,16 +367,16 @@ class Tensor:
         return self._data.shape[0]
 
     def __bool__(self):
-        return bool(self._read())
+        return self._host(bool)
 
     def __float__(self):
-        return float(self._read())
+        return self._host(float)
 
     def __int__(self):
-        return int(self._read())
+        return self._host(int)
 
     def __index__(self):
-        return int(self._read())
+        return self._host(int)
 
     def __hash__(self):
         return id(self)

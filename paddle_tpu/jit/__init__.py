@@ -49,9 +49,13 @@ from ..core import scope as _scope
 from ..core import state
 from ..core import tensor as tensor_mod
 from ..core.tensor import Tensor
+from ..observability import steptimer as _obs_steptimer
 from ..observability import tracing as _obs_tracing
+from ..observability.metrics import enabled as _obs_enabled
 
 logger = logging.getLogger("paddle_tpu.jit")
+# the ring of compiled calls: ``_Executable.__call__`` stores its row
+_call_log = _obs_steptimer._calls
 
 
 # --- compile/HBM observability (ISSUE 12) ----------------------------------
@@ -338,6 +342,7 @@ class _Executable:
         # or asks for create_graph
         self.tape_nodes = None
         self._fn_name = getattr(fn, "__name__", "step")
+        self._fn_id = _obs_steptimer.register_fn(self._fn_name)
 
     def state_split(self):
         """(carry_idx, const_idx) into ``capt_state``: which captured
@@ -478,18 +483,31 @@ class _Executable:
                     "linearised them (counted as the program is traced)",
                     labels={"linearised": where}).inc(getattr(tape, where))
 
-    def __call__(self, arg_tensors):
-        span = _obs_tracing.span
-        with span("to_static.call", fn=self._fn_name):
-            with span("to_static.read_state"):
+    def __call__(self, arg_tensors, t_enter=0):
+        """Run the compiled program.  Under ``PDTPU_METRICS`` the call
+        is row ``n`` of the call log (``observability/steptimer.py``):
+        ``t_enter``, when ``StaticFunction.__call__`` was entered, the
+        clock the three inner spans took as they opened, and one mark
+        more; its ``to_static.call`` span carries the same ``n`` (0 with
+        the flag off: no row)."""
+        span, log = _obs_tracing.span, _call_log
+        on = _obs_enabled()
+        n = next(log.numbers) if on else 0
+        with span("to_static.call", fn=self._fn_name, n=n):
+            with span("to_static.read_state") as read:
                 for sync in self.discovery.host_syncs:
                     sync()
                 vals = [t._read() for t in arg_tensors] + \
                     [t._read() for t in self.capt_state]
-            with span("to_static.launch"):
+            with span("to_static.launch") as launch:
                 outs = self.compiled(*vals)
-            with span("to_static.write_state"):
-                return self._write_state(arg_tensors, outs)
+            with span("to_static.write_state") as write:
+                out = self._write_state(arg_tensors, outs)
+            if on:
+                log.pack(log.buf, ((n - 1) % log.size) * log.stride, n,
+                         self._fn_id, t_enter or read.t0, read.t0,
+                         launch.t0, write.t0, time.perf_counter_ns())
+        return out
 
     def _write_state(self, arg_tensors, outs):
         n_ret = self.n_ret
@@ -653,6 +671,7 @@ class StaticFunction:
                 state.is_grad_enabled())
 
     def __call__(self, *args, **kwargs):
+        t_enter = time.perf_counter_ns()    # the call log's first mark
         if tensor_mod._tracker is not None:
             # nested to_static: inline into the outer capture
             return self._converted()(*args, **kwargs)
@@ -665,7 +684,7 @@ class StaticFunction:
         exe = self._cache.get(key)
         arg_tensors = _flatten_tensors((list(args), kwargs), [])
         if exe is not None:
-            return exe(arg_tensors)
+            return exe(arg_tensors, t_enter)
         return self._capture(key, args, kwargs, arg_tensors)
 
     def _capture(self, key, args, kwargs, arg_tensors):

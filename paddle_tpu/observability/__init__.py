@@ -30,7 +30,12 @@ Pieces
 * ``steptimer`` — :class:`StepTimer` training telemetry (step wall
   histogram, retrace counter over ``Executable.trace_count``,
   tokens/sec + MFU estimate gauges, fused-optimizer bucket dispatch
-  counter) hooked into ``hapi.Model.fit`` and ``Optimizer.step``.
+  counter) hooked into ``hapi.Model.fit`` and ``Optimizer.step``;
+  and, for the loop a user writes round a ``jit.to_static`` step, the
+  window's timeline (ISSUE 40): three preallocated rings of
+  ``perf_counter_ns`` marks, :func:`steptimer.call_log` (compiled
+  calls), :func:`steptimer.read_log` (blocking host reads, with the
+  process's rusage) and :func:`steptimer.gc_log` (collector pauses).
 * ``tracing``   — distributed tracing (ISSUE 12): :func:`span` /
   :func:`traced` write ``span.begin``/``span.end`` pairs into the ring
   with a propagatable trace context (``trace_id``/``span_id``/
@@ -108,8 +113,14 @@ Every event is one flat JSON-able dict::
     span.end              name, span_id, trace_id, dur_us, error?
     spans of the program  (as span.begin/end) compile: fn, n_inputs,
                           n_state, n_donated (jit build); to_static.call
-                          (fn) > to_static.read_state / .launch /
-                          .write_state (jit._Executable.__call__);
+                          (fn, n: the call's number, its row's in
+                          the call log) > to_static.read_state /
+                          .launch / .write_state
+                          (jit._Executable.__call__); tensor.readback
+                          (a blocking host read of a tensor:
+                          core/tensor.py Tensor._host); host.gc
+                          (generation=2: a full collection, start to
+                          stop; steptimer._gc_hook);
                           engine.step > engine.retire / .sweep / .admit
                           / .stage / serving.dispatch / engine.readback
                           (inference/engine.py); router.*, dp.*, pp.*,
@@ -120,6 +131,21 @@ Every event is one flat JSON-able dict::
                           burn_slow                 (slo.SLOEngine)
     slo.recovered         slo, metric               (slo.SLOEngine)
     watchdog.stall        site, key, deadline_ms    (watchdog)
+
+Rings of marks (``steptimer``; ``time.perf_counter_ns()``, int64 record
+arrays in time order, empty while ``PDTPU_METRICS`` is off)::
+
+    call_log()   n, fn, enter, read_state, launch, launched, done
+                 last 4,096 compiled to_static calls; ``fn`` is the
+                 compiled program's own index into call_fn_names();
+                 four host phases a call: lookup, read_state, launch,
+                 write_state
+    read_log()   seq, begin, end, utime_ns, stime_ns, nivcsw, majflt
+                 last 4,096 blocking reads of a device value; rusage
+                 of the process as the read ends
+    gc_log()     seq, begin, end, generation, collected
+                 last 1,024 collections of generation 2 or of 1 ms
+                 and more
 
 Flight records are JSON files under ``PDTPU_FLIGHT_DIR`` (default
 ``<tempdir>/paddle_tpu_flight``); see ``events.dump``.  Flight-record

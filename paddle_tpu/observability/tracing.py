@@ -175,7 +175,9 @@ class span:
     under ``PDTPU_METRICS`` writes one begin/end pair to the event
     ring, exception-safe (the end event records the error type and
     still pops the stack).  With no session and metrics off it costs
-    the TraceMe's enter+exit and nothing else.
+    the TraceMe's enter+exit and nothing else.  ``t0`` is the
+    ``perf_counter_ns`` it took as it opened (0 where it took none):
+    the call log's marks (``jit._Executable.__call__``).
 
     The FIRST span on a thread starts a new trace (fresh ``trace_id``);
     nested spans inherit it and point ``parent_id`` at the enclosing
@@ -184,7 +186,7 @@ class span:
     ``ts``/``name``/``span_id``/``trace_id``/``parent_id``/``tname``).
     """
 
-    __slots__ = ("name", "attrs", "span_id", "_t0", "_on", "_root",
+    __slots__ = ("name", "attrs", "span_id", "t0", "_on", "_root",
                  "_ann", "_sink", "_late")
 
     # ring record: a ``span.begin``/``span.end`` pair in the trace
@@ -196,6 +198,7 @@ class span:
     def __init__(self, name, **attrs):
         self.name = name
         self.attrs = attrs
+        self.t0 = 0             # perf_counter_ns as it opened, if on
         self._on = False
         self._sink = None
         self._late = None
@@ -207,7 +210,7 @@ class span:
         self._sink = _host_sink
         self._on = enabled()
         if self._on or self._sink is not None:
-            self._t0 = time.perf_counter_ns()
+            self.t0 = time.perf_counter_ns()
         if not (self._on and self._paired):
             return self
         self._root = not _ctx.stack
@@ -238,9 +241,9 @@ class span:
             return False
         self._on, self._sink = False, None
         name = str(self.name)
-        dur_ns = time.perf_counter_ns() - self._t0
+        dur_ns = time.perf_counter_ns() - self.t0
         if sink is not None:
-            sink(name, self._t0, dur_ns // 1000, self._cat)
+            sink(name, self.t0, dur_ns // 1000, self._cat)
         if not on:
             return False
         if not self._paired:

@@ -10,9 +10,11 @@ Everything is model-free and sub-second except the export acceptance
 drill, which reuses the session tiny GPT (``conftest.serving_gpt``)
 and the geometries the serving suite already compiled.
 """
+import gc
 import json
 import math
 import os
+import time
 
 import numpy as np
 import pytest
@@ -519,3 +521,220 @@ def test_metrics_off_tracing_and_aggregation_noop(tmp_path):
     finally:
         paddle.set_flags({"metrics": old})
         tracing._reset()
+
+
+# ==========================================================================
+# the window's timeline (ISSUE 40): call log, read log, collector pauses
+# ==========================================================================
+
+from paddle_tpu.observability import steptimer  # noqa: E402
+
+
+@pytest.fixture
+def fresh_logs(fresh_trace):
+    steptimer._reset_logs()
+    yield
+    steptimer._reset_logs()
+
+
+def _tiny_step():
+    w = paddle.to_tensor(np.ones((4, 4), "float32"))
+
+    @paddle.jit.to_static
+    def logged_step(x):
+        return (x @ w).sum()
+
+    return logged_step, paddle.to_tensor(np.ones((4, 4), "float32"))
+
+
+def _spans(name):
+    return [e for e in obs.tail()
+            if e["kind"] == "span.begin" and e["name"] == name]
+
+
+def test_compiled_calls_write_rows_and_the_eager_first_call_none(fresh_logs):
+    step, x = _tiny_step()
+    step(x)                                 # eager: discovery
+    assert len(steptimer.call_log()) == 0
+    assert _spans("to_static.call") == []
+    for _ in range(5):
+        step(x)
+    log = steptimer.call_log()
+    assert len(log) == 5 and list(log["n"]) == [1, 2, 3, 4, 5]
+    names = steptimer.call_fn_names()
+    assert {names[i] for i in log["fn"]} == {"logged_step"}
+    marks = np.stack([log[f] for f in steptimer.CALL_FIELDS[2:]], axis=1)
+    assert (np.diff(marks, axis=1) > 0).all()       # five ordered marks
+    assert (log["enter"][1:] > log["done"][:-1]).all()
+    # a row and its span pair by number, not by position
+    assert [e["n"] for e in _spans("to_static.call")] == [1, 2, 3, 4, 5]
+    assert len(_spans("to_static.launch")) == 5
+
+
+def test_two_programs_of_one_name_keep_their_rows_apart(fresh_logs):
+    (one, x), (two, _) = _tiny_step(), _tiny_step()
+    one(x), two(x)                          # eager
+    one(x), two(x), two(x), one(x)
+    log, names = steptimer.call_log(), steptimer.call_fn_names()
+    a, b = int(log["fn"][0]), int(log["fn"][1])
+    assert a != b and names[a] == names[b] == "logged_step"
+    assert list(log["fn"]) == [a, b, b, a]
+
+
+def test_a_blocking_read_is_one_row_and_one_span(fresh_logs):
+    t = paddle.to_tensor(np.float32(2.5))
+    assert len(steptimer.read_log()) == 0
+    assert float(t) == 2.5
+    (row,) = steptimer.read_log()
+    assert row["end"] > row["begin"] > 0
+    assert row["utime_ns"] > 0 and row["nivcsw"] >= 0 and row["majflt"] >= 0
+    assert len(_spans("tensor.readback")) == 1
+    ends = [e for e in obs.tail() if e["kind"] == "span.end"]
+    assert ends[-1]["name"] == "tensor.readback"
+    # each of the eight host-interop methods is one read
+    v = paddle.to_tensor(np.arange(3))
+    s = paddle.to_tensor(np.int32(1))
+    v.numpy(), v.tolist(), np.asarray(v), s.item(), bool(s), int(s), [7, 8][s]
+    assert len(steptimer.read_log()) == 8
+    assert len(_spans("tensor.readback")) == 8
+    assert list(np.asarray(v, dtype="float32")) == [0.0, 1.0, 2.0]
+
+
+def test_metrics_off_writes_no_row(fresh_logs):
+    step, x = _tiny_step()
+    step(x), step(x)
+    assert len(steptimer.call_log()) == 1
+    hooked = gc.callbacks.count(steptimer._gc_hook)
+    paddle.set_flags({"metrics": False})
+    off = time.perf_counter_ns()
+    try:
+        for _ in range(3):
+            out = step(x)
+        assert float(out) == 64.0
+        gc.collect()
+        for log in (steptimer.call_log, steptimer.read_log,
+                    steptimer.gc_log):
+            assert len(log()) == 0              # []-like while off
+    finally:
+        on = time.perf_counter_ns()
+        paddle.set_flags({"metrics": True})
+    # nothing was written while it was off (a young collection that
+    # took a millisecond before or after may stand in the third ring)
+    assert list(steptimer.call_log()["n"]) == [1]
+    assert len(steptimer.read_log()) == 0
+    pauses = steptimer.gc_log()
+    assert not ((pauses["begin"] > off) & (pauses["begin"] < on)).any()
+    assert gc.callbacks.count(steptimer._gc_hook) == hooked
+
+
+@pytest.mark.parametrize("ring,size", [("calls", 4096), ("reads", 4096),
+                                       ("gcs", 1024)])
+def test_the_rings_wrap_at_their_size(fresh_logs, ring, size):
+    assert (steptimer.CALL_RING, steptimer.READ_RING,
+            steptimer.GC_RING) == (4096, 4096, 1024)
+    extra = 10
+    if ring == "calls":
+        for _ in range(size + extra):
+            n = next(steptimer._calls.numbers)
+            steptimer._calls.put(n, 0, n, n + 1, n + 2, n + 3, n + 4)
+        log, key = steptimer.call_log(), "n"
+    elif ring == "reads":
+        for k in range(size + extra):
+            steptimer.note_read(k + 1, k + 2)
+        log, key = steptimer.read_log(), "seq"
+    else:
+        obs.events.set_capacity(8)      # generation-2 pauses are spans
+        gc.disable()                    # no real collection in between
+        try:
+            for _ in range(size + extra):
+                steptimer._gc_hook("start", {"generation": 2})
+                steptimer._gc_hook("stop", {"generation": 2, "collected": 3,
+                                            "uncollectable": 0})
+            log, key = steptimer.gc_log(), "seq"
+        finally:
+            gc.enable()
+            obs.events.set_capacity(512)
+        assert (log["generation"] == 2).all() and (log["collected"] == 3).all()
+    assert len(log) == size
+    assert list(log[key][[0, -1]]) == [extra + 1, size + extra]
+    assert (np.diff(log[key]) == 1).all()           # in time order
+
+
+def test_the_collector_hook_is_installed_once_and_logs_long_pauses(
+        fresh_logs):
+    step, x = _tiny_step()
+    for _ in range(4):
+        step(x)
+    assert gc.callbacks.count(steptimer._gc_hook) == 1
+
+    def logged(generation, collected=None):
+        # other young collections may pause a loaded host a millisecond
+        # too: look for the ones made here
+        log = steptimer.gc_log()
+        keep = log["generation"] == generation
+        if collected is not None:
+            keep &= log["collected"] == collected
+        return log[keep]
+
+    # a young collection under a millisecond: two clock reads, no row
+    steptimer._gc_hook("start", {"generation": 0})
+    steptimer._gc_hook("stop", {"generation": 0, "collected": 77077,
+                                "uncollectable": 0})
+    assert len(logged(0, 77077)) == 0 and _spans("host.gc") == []
+    # ... and one that paused the host a millisecond is logged, no span
+    steptimer._gc_hook("start", {"generation": 1})
+    steptimer._gc_t0 -= steptimer.GC_LOG_NS
+    steptimer._gc_hook("stop", {"generation": 1, "collected": 77077,
+                                "uncollectable": 0})
+    (row,) = logged(1, 77077)
+    assert row["end"] - row["begin"] >= steptimer.GC_LOG_NS
+    assert _spans("host.gc") == []
+    gc.collect()                            # generation 2: row and span
+    (row,) = logged(2)
+    assert row["end"] > row["begin"]
+    (span,) = _spans("host.gc")
+    assert span["generation"] == 2
+    step(x)
+    assert gc.callbacks.count(steptimer._gc_hook) == 1
+
+
+def test_log_and_device_trace_share_a_clock_by_n(fresh_logs, tmp_path):
+    """With a profiler session live the marks are spans in the
+    ``.xplane.pb``: ``to_static.call`` with its ``n``, ``tensor.readback``
+    and ``host.gc``; rows and spans pair by ``n`` to one offset."""
+    import glob
+    import sys
+
+    import jax
+    from jax.profiler import ProfileData
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from perf import window_log
+    step, x = _tiny_step()
+    step(x), step(x)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(4):
+            out = step(x)
+        float(out)
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    traced = window_log.traced_calls(path)
+    assert sorted(traced) == [2, 3, 4, 5]
+    offset, spread_us, pairs = window_log.clock_offset(
+        steptimer.call_log(), traced)
+    assert pairs == 4 and spread_us < 200
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert {"to_static.call", "tensor.readback", "host.gc"} <= names
+    # the read's row lands inside its span once moved to the trace's clock
+    (read,) = steptimer.read_log()
+    spans = [(ev.start_ns, ev.start_ns + ev.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "tensor.readback"]
+    lo, hi = read["begin"] + offset, read["end"] + offset
+    assert spans[0][0] - 2e5 <= lo and hi <= spans[0][1] + 2e5
