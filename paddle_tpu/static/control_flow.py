@@ -564,7 +564,6 @@ def while_loop(cond, body, loop_vars, is_test=False, name=None,
                                                  probe_body])
     _check_same_structure([carry_tree, body_tree], "while_loop")
     reads = [t for t in reads if id(t) not in set(carry_ids)]
-    read_ids = [id(t) for t in reads]
     n_carry = len(carry_leaves)
 
     needs_grad = _needs_grad(carry_ts + reads)
@@ -579,7 +578,12 @@ def while_loop(cond, body, loop_vars, is_test=False, name=None,
             return run_python_loop()
 
     def _make_cond_body(vals):
-        inv = dict(zip(read_ids, vals[n_carry:]))
+        # keyed from the tensors themselves, which this closure so keeps
+        # alive: eagerly the op is linearised at BACKWARD time
+        # (dispatch.apply), after ``loop_vars`` died, and the id of a freed
+        # loop var is then taken by a tensor the body makes (``i + 1``'s
+        # constant read as ``i``: every trip up to the bound runs)
+        inv = dict(zip(map(id, reads), vals[n_carry:]))
 
         def wrap_vars(carry):
             ts = [Tensor(v) for v in carry]
@@ -589,7 +593,7 @@ def while_loop(cond, body, loop_vars, is_test=False, name=None,
             # closures over the ORIGINAL loop-var objects see the current
             # carry (the static-mode semantics: the var IS the loop slot)
             s = dict(inv)
-            s.update(zip(carry_ids, carry))
+            s.update(zip(map(id, carry_ts), carry))
             return s
 
         def cond_w(carry):

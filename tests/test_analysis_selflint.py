@@ -90,3 +90,26 @@ def test_program_audit_clean_on_representative_programs():
         selflint_step(paddle.to_tensor(np.ones((16,), np.float32)))
     bad = [d.format() for d in diags if d.severity >= Severity.WARN]
     assert not bad, "\n".join(bad)
+
+
+def test_no_file_ordering_grows_back_under_tests():
+    """Tier-1 runs on ``xdist`` workers by file (``--dist loadfile``), and
+    ``xdist`` re-sorts the files by their number of cases whatever order
+    collection gave: an ordering hook under ``tests/`` is dead code, and
+    a list of file names in ``conftest.py`` is one waiting to be kept."""
+    import re
+    here = os.path.dirname(os.path.abspath(__file__))
+    hooks = []
+    for root, dirs, files in os.walk(here):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    if re.search(r"^\s*def pytest_collection_modifyitems\b",
+                                 fh.read(), re.M):
+                        hooks.append(os.path.relpath(
+                            os.path.join(root, f), here))
+    assert not hooks, f"collection is re-ordered in {hooks}"
+    with open(os.path.join(here, "conftest.py")) as fh:
+        named = re.findall(r"""["']test_\w+\.py["']""", fh.read())
+    assert not named, f"tests/conftest.py lists test files: {named}"

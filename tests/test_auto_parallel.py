@@ -213,3 +213,12 @@ def test_to_static_sharded_step(mesh2d):
     # weight sharding preserved through compiled steps
     w1 = net.fc1.weight._read()
     assert {s.data.shape for s in w1.addressable_shards} == {(8, 16)}
+
+
+def test_dryrun_multichip_hybrid():
+    """One of the five layouts of ``__graft_entry__.dryrun_multichip(8)``
+    (tests/test_models.py holds it to them): dp x mp x sp with ring
+    attention, one compiled sharded step as the case above."""
+    import __graft_entry__ as g
+    g._force_virtual_cpu(8)
+    g._dryrun_hybrid(8)

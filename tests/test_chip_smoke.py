@@ -64,8 +64,11 @@ def test_chip_smoke_phases_rehearsal(capsys):
     compiles = cs._Compiles()
     cs._phase("train", compiles, lambda: cs.run_train(
         0, width=width, batch=2, seq=64, on_chip=False))
+    # six requests of three lengths: each length of the dense reference
+    # is a prefill and a decode program of its own (about 10 s a pair),
+    # and what is asserted is six streams that agree
     cs._phase("serve", compiles, lambda: cs.run_serve(
-        0, compiles, width=width, lens=(5, 17, 33, 40, 61, 90), new=18,
+        0, compiles, width=width, lens=(5, 33, 90, 5, 33, 90), new=18,
         on_chip=False))
     train, serve = (json.loads(line) for line in
                     capsys.readouterr().out.strip().splitlines())

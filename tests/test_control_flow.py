@@ -221,6 +221,38 @@ def test_while_loop_grad_data_dependent_trip_count():
     assert len(sf._cache) == 1
 
 
+def test_while_loop_keeps_its_loop_vars_for_the_backward():
+    """On a ``to_static`` function's first, eager call the loop is
+    linearised at BACKWARD time (``dispatch.apply``), from a closure that
+    finds the loop vars by their ids: it must keep them alive, or a tensor
+    the body makes takes a freed one's id (``i + 1``'s constant read as
+    ``i``: every trip live, PDT206, the gradient of w^8 where w^2's was
+    due, as tier-1 saw it once the files ran in another order)."""
+    import gc
+    import weakref
+    w = _t(np.array([2.0], np.float32), stop_gradient=False)
+    seen = {}
+
+    @paddle.jit.to_static
+    def fn(x, n):
+        w.clear_grad()
+        i0 = _t(0)
+        gone = weakref.ref(i0)
+        i, y = while_loop(lambda i, y: i < n,
+                          lambda i, y: (i + 1, y * w),
+                          [i0, x], max_trip_count=8)
+        del i0, i
+        gc.collect()
+        seen.setdefault("alive", gone() is not None)
+        loss = y.sum()
+        loss.backward()
+        return loss
+
+    fn(_t(np.array([1.0], np.float32)), _t(2))
+    assert seen["alive"]
+    np.testing.assert_allclose(w.grad.numpy(), [4.0])
+
+
 def test_while_loop_grads_opt_out_falls_back():
     """max_trip_count=0 opts out of the scan lowering: the Python loop
     unrolls and to_static degrades to eager, staying correct."""

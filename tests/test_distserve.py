@@ -539,8 +539,10 @@ def test_serving_bench_rows_smoke(gpt):
     suite's tiny geometry and report sane accounting (absolute times
     are TPU claims; the gates here are outputs_equal, byte counts and
     pool conservation)."""
+    import os
     import sys
-    sys.path.insert(0, "/root/repo/benchmarks")
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks"))
     import serving_bench as sb
     cfg = gpt.cfg
     row = sb._measure_tp(cfg, gpt, 819.0, 2, slots=2, prompt_len=10,

@@ -191,3 +191,77 @@ def test_group_sharded_parallel_stage3():
         opt.step()
     finally:
         fleet.set_hybrid_communicate_group(hcg_prev)
+
+
+def test_zero_mp_pp_1f1b_single_layout():
+    """ZeRO-2 (sharding axis = batch axis) composed with Megatron TP and
+    the FUSED 1F1B pipeline schedule in one device layout (VERDICT r4
+    item 7; reference bar: semi_auto_llama dp+mp+pp with sharding
+    stages + pipeline_parallel.py:663 train_batch)."""
+    from paddle_tpu.distributed.fleet.sharding_optimizer import \
+        DygraphShardingOptimizer
+    from paddle_tpu.distributed.fleet.topology import \
+        HybridCommunicateGroup
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
+
+    # the 64-wide toy of tests/test_scale5.py's ``_pipe_run``; two steps: the first call of a
+    # ``to_static`` function runs eagerly, the second is the compiled one
+    cfg = GPTConfig(vocab_size=128, hidden_size=64, num_layers=2,
+                    num_heads=4, max_seq_len=32, dropout=0.0)
+    pp, shd, mp = 2, 2, 2
+    hcg = HybridCommunicateGroup(dp_degree=1, pp_degree=pp,
+                                 sharding_degree=shd, sep_degree=1,
+                                 mp_degree=mp)
+    mesh = dist.ProcessMesh(np.arange(8).reshape(pp, shd, mp),
+                            ["pp", "sharding", "mp"])
+    paddle.seed(0)
+    model = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp",
+                               dp_axis="sharding", num_microbatches=2)
+    model.blocks.shard(mesh, "pp", tp_axis="mp", tp_rules={
+        "attn.qkv.weight": 2, "attn.qkv.bias": 1,
+        "mlp.fc1.weight": 2, "mlp.fc1.bias": 1,
+        "attn.proj.weight": 1, "mlp.fc2.weight": 1,
+    })
+    model.train()
+    inner = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                   parameters=model.parameters())
+    opt = DygraphShardingOptimizer(inner, hcg, stage=2)
+
+    @paddle.jit.to_static
+    def train_step(ids, labels):
+        loss = model.train_batch(ids, labels)   # fused 1F1B
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    rng = np.random.default_rng(0)
+    pl = [dist.Replicate(), dist.Shard(0), dist.Replicate()]
+    losses = []
+    for _ in range(2):
+        ids = dist.shard_tensor(
+            rng.integers(0, 128, (4, 16)).astype(np.int32), mesh, pl)
+        labels = dist.shard_tensor(
+            rng.integers(0, 128, (4, 16)).astype(np.int32), mesh, pl)
+        losses.append(float(train_step(ids, labels)))
+    assert all(np.isfinite(l) for l in losses), losses
+
+    # ZeRO: moments sharded over `sharding`; TP: stacked qkv keeps mp;
+    # and the stacked weights keep their pp sharding through updates
+    accs = inner._accumulators["moment1"]
+    assert any("sharding" in str(getattr(a._read().sharding, "spec", ""))
+               for a in accs.values())
+    w = model.blocks.stacked_parameter("attn.qkv.weight")._read()
+    spec = str(getattr(w.sharding, "spec", ""))
+    assert "mp" in spec and "pp" in spec, spec
+
+
+def test_dryrun_multichip_zero_mp_pp_1f1b():
+    """One of the five layouts of ``__graft_entry__.dryrun_multichip(8)``
+    (tests/test_models.py holds it to them): ZeRO-2 through
+    ``DygraphShardingOptimizer`` and a ``HybridCommunicateGroup`` of its
+    own, whatever this module's is, x mp x pp under fused 1F1B: the layout
+    of ``test_zero_mp_pp_1f1b_single_layout`` above at the entry's toy."""
+    import __graft_entry__ as g
+    g._force_virtual_cpu(8)
+    g._dryrun_zero_mp_pp_1f1b(8)

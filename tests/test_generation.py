@@ -4,6 +4,7 @@ argmax sequence exactly."""
 import numpy as np
 import pytest
 
+import _traced
 import paddle_tpu as paddle
 from paddle_tpu.models import generate
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
@@ -11,11 +12,12 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 
 def _greedy_nocache(model, ids, steps):
-    """Reference decoding: full forward each step, argmax last logits."""
+    """Reference decoding: full forward each step, argmax last logits.
+    Each step is a length of its own, so op by op every op compiles
+    again at every step: one program a length instead."""
     out = ids.copy()
     for _ in range(steps):
-        with paddle.no_grad():
-            logits = model(paddle.to_tensor(out)).numpy()
+        logits = np.asarray(_traced.forward(model, out))
         nxt = logits[:, -1].argmax(-1).astype(out.dtype)
         out = np.concatenate([out, nxt[:, None]], axis=1)
     return out

@@ -21,7 +21,8 @@ def test_mp_workers_match_inprocess_order_and_values():
     a = list(DataLoader(_SquareDS(), batch_size=4, shuffle=False,
                         num_workers=0))
     b = list(DataLoader(_SquareDS(), batch_size=4, shuffle=False,
-                        num_workers=3, use_shared_memory=True))
+                        num_workers=3, use_shared_memory=True,
+                        timeout=60))
     assert len(a) == len(b) == 6
     for (xa, ya), (xb, yb) in zip(a, b):
         np.testing.assert_array_equal(xa.numpy(), xb.numpy())
@@ -44,7 +45,7 @@ def test_mp_workers_expose_worker_info():
 
     assert get_worker_info() is None  # trainer process
     out = list(DataLoader(_WorkerProbeDS(), batch_size=2, shuffle=False,
-                          num_workers=2))
+                          num_workers=2, timeout=60))
     assert len(out) == 4
 
 
@@ -86,7 +87,7 @@ def test_mp_workers_iterable_dataset_shards_itself():
     from paddle_tpu.io import DataLoader
 
     out = list(DataLoader(_ShardedIterable(), batch_size=3,
-                          num_workers=2))
+                          num_workers=2, timeout=60))
     got = sorted(int(b.numpy()[r, 0]) for b in out
                  for r in range(b.shape[0]))
     assert got == list(range(12))
@@ -107,7 +108,7 @@ def test_mp_workers_accept_tensor_datasets():
     from paddle_tpu.io import DataLoader
 
     out = list(DataLoader(_TensorDS(), batch_size=2, shuffle=False,
-                          num_workers=2))
+                          num_workers=2, timeout=60))
     assert len(out) == 3
     np.testing.assert_array_equal(out[0].numpy()[:, 0], [0.0, 1.0])
 
@@ -119,7 +120,7 @@ def test_mp_workers_early_break_leaks_no_shm():
 
     before = set(glob.glob("/dev/shm/psm_*"))
     loader = DataLoader(_SquareDS(), batch_size=2, shuffle=False,
-                        num_workers=2)
+                        num_workers=2, timeout=60)
     for step, _batch in enumerate(loader):
         if step == 1:
             break
@@ -143,7 +144,7 @@ def test_mp_workers_large_dataset_no_deadlock():
 
     n = 0
     for batch in DataLoader(Big(), batch_size=8, shuffle=False,
-                            num_workers=2, timeout=120):
+                            num_workers=2, timeout=60):
         n += 1
     assert n == 500
 

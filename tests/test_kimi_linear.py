@@ -78,13 +78,20 @@ def close(got, want, tol=TOL):
     assert gap <= tol, gap
 
 
-def seeded(recompute):
+def _seeded(recompute):
     """(the program's model, the reference's leaves) on one seed."""
     weights = C.make_weights(R.table(CFG), seed=11)
     model = A._model(CFG, recompute=recompute,
                      recompute_policy="dots_and_kernels_saveable")
     M.load_weights(model, M.unstack(weights, A.program_name))
     return model, weights
+
+
+# One model a ``recompute`` for the cases that leave it as it was
+# (parameters, buffers, no gradients), built by the first that asks: inside
+# the case, so that ``_leave_no_block_behind`` sees its blocks come and go.
+# A case that trains a model or reads its tally builds its own (``_seeded``).
+seeded = functools.lru_cache(maxsize=None)(_seeded)
 
 
 def batch(rows=2, seq=24, seed=5):
@@ -126,6 +133,7 @@ def test_logits_loss_and_every_gradient(recompute):
     close(float(loss), float(want_loss))
     loss.backward()
     grads = {n: p.grad._read() for n, p in model.named_parameters()}
+    model.clear_gradients()     # the model is the file's (``seeded``)
     assert set(grads) == {A.program_name(k, None) for k in want_grads}
     for leaf, want in want_grads.items():
         close(grads[A.program_name(leaf, None)], want)
@@ -319,10 +327,11 @@ def test_the_reference_s_head_groups_add_up_to_the_operator(monkeypatch):
                                ("o", (HEADS * VDIM, H)))})
     a = jnp.asarray(rng.standard_normal((1, 20, H)), jnp.float32)
     for op in (R.kda, R.latent_attention):
-        def both(a, w):
-            return jax.value_and_grad(
+        def both(a, w):     # a new function a call: traced at the
+            # group size of the moment, as one program and not op by op
+            return jax.jit(jax.value_and_grad(
                 lambda a, w: jnp.sum(op(a, w, CFG, C.Matmul()) ** 2),
-                argnums=(0, 1))(a, w)
+                argnums=(0, 1)))(a, w)
         whole, (da, dw) = both(a, w)
         monkeypatch.setattr(R, "HEADS_AT_A_TIME", 1)
         parts, (da1, dw1) = both(a, w)

@@ -1,9 +1,9 @@
 """Launcher tests (reference pattern: test_launch_coverage / the
 fleet elastic watchdog tests)."""
-import os
-import subprocess
 import sys
 import textwrap
+
+import _children
 
 
 def _write(tmp_path, name, body):
@@ -20,11 +20,10 @@ def test_launch_sets_env_contract(tmp_path):
             " ".join([r, os.environ["PADDLE_TRAINERS_NUM"],
                       os.environ["PADDLE_LOCAL_RANK"]]))
     """)
-    out = subprocess.run(
+    out = _children.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nproc_per_node", "2", script],
-        capture_output=True, text=True, cwd="/root/repo",
-        env={**os.environ, "PYTHONPATH": "/root/repo"})
+        env=_children.env())
     assert out.returncode == 0, out.stderr
     assert (tmp_path / "out0").read_text() == "0 2 0"
     assert (tmp_path / "out1").read_text() == "1 2 1"
@@ -39,11 +38,10 @@ def test_launch_elastic_restart(tmp_path):
         m.write_text(str(n + 1))
         sys.exit(1 if n == 0 else 0)   # fail once, succeed on restart
     """)
-    out = subprocess.run(
+    out = _children.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--max_restart_times", "2", script],
-        capture_output=True, text=True, cwd="/root/repo",
-        env={**os.environ, "PYTHONPATH": "/root/repo"})
+        env=_children.env())
     assert out.returncode == 0, (out.stdout, out.stderr)
     assert marker.read_text() == "2"  # initial failure + 1 restart
     assert "restart 1/2" in out.stderr
@@ -51,10 +49,9 @@ def test_launch_elastic_restart(tmp_path):
 
 def test_launch_propagates_persistent_failure(tmp_path):
     script = _write(tmp_path, "dead.py", "import sys; sys.exit(3)")
-    out = subprocess.run(
+    out = _children.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch", script],
-        capture_output=True, text=True, cwd="/root/repo",
-        env={**os.environ, "PYTHONPATH": "/root/repo"})
+        env=_children.env())
     assert out.returncode == 3
 
 
@@ -66,11 +63,10 @@ def test_multinode_env(tmp_path):
               os.environ["JAX_NUM_PROCESSES"],
               os.environ["JAX_PROCESS_ID"])
     """)
-    out = subprocess.run(
+    out = _children.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
          "--nnodes", "4", "--node_rank", "2",
          "--master", "10.0.0.1:8476", script],
-        capture_output=True, text=True, cwd="/root/repo",
-        env={**os.environ, "PYTHONPATH": "/root/repo"})
+        env=_children.env())
     assert out.returncode == 0, out.stderr
     assert "R 2 10.0.0.1:8476 4 2" in out.stdout

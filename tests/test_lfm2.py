@@ -75,13 +75,20 @@ def close(got, want, tol=TOL):
     assert gap <= tol, gap
 
 
-def seeded(recompute):
+def _seeded(recompute):
     """(the program's model, the reference's leaves) on one seed."""
     weights = C.make_weights(R.table(CFG), seed=11)
     model = A._model(CFG, recompute=recompute,
                      recompute_policy="dots_and_kernels_saveable")
     M.load_weights(model, M.unstack(weights, A.program_name))
     return model, weights
+
+
+# One model a ``recompute`` for the cases that leave it as it was
+# (parameters, buffers, no gradients), built by the first that asks: inside
+# the case, so that ``_leave_no_block_behind`` sees its blocks come and go.
+# A case that trains a model or reads its tally builds its own (``_seeded``).
+seeded = functools.lru_cache(maxsize=None)(_seeded)
 
 
 def batch(rows=2, seq=24, seed=5):
@@ -116,6 +123,7 @@ def test_logits_loss_and_every_gradient(recompute):
     close(float(loss), float(want_loss))
     loss.backward()
     grads = {n: p.grad._read() for n, p in model.named_parameters()}
+    model.clear_gradients()     # the model is the file's (``seeded``)
     assert set(grads) == {A.program_name(k, None) for k in want_grads}
     for leaf, want in want_grads.items():
         close(grads[A.program_name(leaf, None)], want)
@@ -347,7 +355,7 @@ def test_one_compiled_step_linearises_as_it_records_and_feeds_the_tally(
     from paddle_tpu.incubate.distributed.models import moe
     from paddle_tpu.observability import metrics
     monkeypatch.setattr(moe, "_SLOTS_AT_A_TIME", 16)    # 6 chunks a call
-    model, _ = seeded(True)
+    model, _ = _seeded(True)
     opt = paddle.optimizer.AdamW(learning_rate=1e-3,
                                  parameters=model.parameters())
     model, opt = amp.decorate(models=model, optimizers=opt, level="O2",

@@ -524,11 +524,14 @@ def test_serving_bench_migration_smoke(gpt):
     tokens are saved, and the streams gate bitwise (absolute times are
     TPU claims)."""
     import sys
-    sys.path.insert(0, "/root/repo/benchmarks")
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks"))
     import serving_bench as sb
     cfg = gpt.cfg
-    row = sb._measure_migration(cfg, gpt, prompt_len=16, new_tokens=6,
-                                n_requests=3, page_size=8,
+    # ``KW``'s geometry (two slots: the row's default of four was a set
+    # of programs of its own, 30 s of tier-1)
+    row = sb._measure_migration(cfg, gpt, slots=2, prompt_len=16,
+                                new_tokens=6, n_requests=3, page_size=8,
                                 decode_window=4, prefill_chunk=8,
                                 max_seq_len=32, q_block=2, warm=False)
     assert row["outputs_equal"]

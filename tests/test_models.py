@@ -141,19 +141,39 @@ def test_recompute_sequential():
     assert x.grad is not None
 
 
-def test_shard_gpt_multichip_dryrun():
-    """The driver's dryrun_multichip contract, exercised in CI."""
-    import sys
-    sys.path.insert(0, "/root/repo")
+LAYOUTS = ("_dryrun_hybrid", "_dryrun_pipeline", "_dryrun_moe",
+           "_dryrun_dp_mp_pp", "_dryrun_zero_mp_pp_1f1b")
+
+
+def test_shard_gpt_multichip_dryrun(monkeypatch):
+    """The driver's ``dryrun_multichip`` contract: the eight virtual CPU
+    devices, then exactly the five layouts, in this order (recorders
+    stand in their place).  Each layout is a case of its own,
+    ``test_dryrun_multichip_<layout>``, beside cases of the same
+    subsystem: here, in test_auto_parallel, test_overlap, test_moe and
+    test_fleet."""
     import __graft_entry__ as g
+    called = []
+    for name in LAYOUTS:
+        monkeypatch.setattr(
+            g, name, lambda n, name=name: called.append((name, n)))
     g.dryrun_multichip(8)
+    assert called == [(name, 8) for name in LAYOUTS]
+    assert sorted(n for n in vars(g) if n.startswith("_dryrun_")) == \
+        sorted(LAYOUTS)
+
+
+def test_dryrun_multichip_dp_mp_pp():
+    """GPipe over pp x Megatron TP over mp x dp on one mesh of eight,
+    AdamW steps at ``__graft_entry__``'s toy."""
+    import __graft_entry__ as g
+    g._force_virtual_cpu(8)
+    g._dryrun_dp_mp_pp(8)
 
 
 def test_entry_compiles():
-    import sys
-
     import jax
-    sys.path.insert(0, "/root/repo")
+
     import __graft_entry__ as g
     fn, args = g.entry()
     out = jax.jit(fn)(*args)

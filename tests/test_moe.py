@@ -121,3 +121,12 @@ def test_moe_layer_api():
     assert layer.moe.top_k == 1
     with pytest.raises(ValueError):
         MoELayer(d_model=8, d_hidden=16, num_experts=2, gate="bogus")
+
+
+def test_dryrun_multichip_moe():
+    """One of the five layouts of ``__graft_entry__.dryrun_multichip(8)``
+    (tests/test_models.py holds it to them): dp x ep, the experts
+    sharded as in ``test_gpt_moe_expert_parallel_step``."""
+    import __graft_entry__ as g
+    g._force_virtual_cpu(8)
+    g._dryrun_moe(8)

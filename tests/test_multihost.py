@@ -3,10 +3,11 @@ federate through the JAX coordination service via init_parallel_env
 (using the launcher's env contract), and a pod-wide psum must see both
 processes' contributions — the ``test_dist_base.py`` pattern of SURVEY
 §4 (N local processes standing in for N hosts)."""
-import os
 import subprocess
 import sys
 import textwrap
+
+import _children
 
 
 def test_two_process_allreduce(tmp_path):
@@ -41,8 +42,7 @@ def test_two_process_allreduce(tmp_path):
     """))
 
     def start(rank):
-        env = {**os.environ, "PYTHONPATH": "/root/repo",
-               "JAX_PLATFORMS": "cpu",
+        env = {**_children.env(), "JAX_PLATFORMS": "cpu",
                # the contract paddle_tpu.distributed.launch sets per host
                "JAX_COORDINATOR_ADDRESS": "127.0.0.1:19284",
                "JAX_NUM_PROCESSES": "2",
@@ -50,13 +50,18 @@ def test_two_process_allreduce(tmp_path):
                "PADDLE_TRAINER_ID": str(rank),
                "PADDLE_TRAINERS_NUM": "2"}
         env.pop("XLA_FLAGS", None)  # one real device per process
-        return subprocess.Popen(
-            [sys.executable, str(script)], env=env, cwd="/root/repo",
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+        return _children.spawn(
+            [sys.executable, str(script)], env=env,
+            stderr=subprocess.STDOUT)
 
-    p0, p1 = start(0), start(1)
-    out0, _ = p0.communicate(timeout=180)
-    out1, _ = p1.communicate(timeout=180)
+    procs = []
+    try:
+        procs += [start(0), start(1)]
+        (out0, _), (out1, _) = _children.outputs(
+            procs, what="two federated processes")
+    finally:
+        _children.kill(*procs)
+    p0, p1 = procs
     assert p0.returncode == 0, out0
     assert p1.returncode == 0, out1
     assert (tmp_path / "ok0").read_text() == "3.0"

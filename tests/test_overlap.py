@@ -219,6 +219,13 @@ def test_topology_process_mesh_bridge():
     assert g.nranks == 2 and g.ranks == [0, 4]
 
 
+# slow: 120-140 s, the longest case outside tests/perf, for the columns
+# of a bench row (benchmarks/hybrid_bench.py, ROADMAP D4).  Tier-1 runs what
+# the row times (the 1F1B GPT step: test_pipeline_schedules::
+# test_gpt_pipe_1f1b_trains; the overlap telemetry:
+# test_overlap_bitwise_vs_serialized_per_param above) and NOT the row's own
+# code
+@pytest.mark.slow
 def test_gpt_3d_bench_row_smoke():
     """CPU-mesh accounting smoke of the gpt_3d row: topology recorded,
     scaling + overlap fields present, overlap_frac within [0, 1]."""
@@ -247,3 +254,12 @@ def test_gpt_3d_bench_row_smoke():
     assert ov["buckets"] >= 1 and ov["comm_ms"] > 0
     assert 0.0 <= ov["overlap_frac"] <= 1.0
     assert row["pp_overlap_p2p"] is True
+
+
+def test_dryrun_multichip_pipeline():
+    """One of the five layouts of ``__graft_entry__.dryrun_multichip(8)``
+    (tests/test_models.py holds it to them): GPipe over pp x dp, the
+    activation hops whose overlap the cases above measure."""
+    import __graft_entry__ as g
+    g._force_virtual_cpu(8)
+    g._dryrun_pipeline(8)

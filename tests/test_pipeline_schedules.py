@@ -1,6 +1,8 @@
 """1F1B and interleaved-VPP pipeline schedules (SURVEY D15; reference
 pipeline_parallel.py:663 train_batch 1F1B, :912 interleaved). Parity model:
-same outputs/grads/losses as the identical weights run sequentially."""
+same outputs/grads/losses as the identical weights run sequentially.  The
+same schedule under ``GPTForCausalLMPipe`` is
+tests/test_pipeline_schedules_gpt.py."""
 import numpy as np
 import pytest
 
@@ -163,76 +165,3 @@ def test_1f1b_trains_under_jit(mesh):
 
     losses = [float(step(x, y)) for _ in range(6)]
     assert losses[-1] < losses[0] * 0.9, losses
-
-
-def test_gpt_pipe_1f1b_train_batch_parity(mesh):
-    """GPT 1F1B train_batch (epilogue inside the schedule via post_params,
-    tied embeddings getting BOTH grad paths) matches the plain GPT."""
-    from paddle_tpu.models.gpt import (GPTConfig, GPTForCausalLM,
-                                       GPTForCausalLMPipe)
-
-    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=4,
-                    num_heads=4, max_seq_len=16, dropout=0.0)
-    rng = np.random.default_rng(5)
-    ids = rng.integers(0, 64, (4, 16)).astype(np.int32)
-    labels = rng.integers(0, 64, (4, 16)).astype(np.int32)
-
-    paddle.seed(0)
-    pipe = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp", dp_axis="dp",
-                              num_microbatches=2)
-    paddle.seed(0)
-    ref = GPTForCausalLM(cfg)
-    ref.gpt.wte.weight._write(pipe.wte.weight._read())
-    ref.gpt.wpe.weight._write(pipe.wpe.weight._read())
-    ref.gpt.ln_f.weight._write(pipe.ln_f.weight._read())
-    ref.gpt.ln_f.bias._write(pipe.ln_f.bias._read())
-    for li, blk in enumerate(ref.gpt.blocks):
-        for n, p in blk.named_parameters():
-            p._write(pipe.blocks.stacked_parameter(n)._read()[li])
-
-    loss = pipe.train_batch(paddle.to_tensor(ids), paddle.to_tensor(labels))
-    loss.backward()
-    ref_loss = ref(paddle.to_tensor(ids), paddle.to_tensor(labels))
-    ref_loss.backward()
-
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-4)
-    # tied embedding grad = embedding path + head path
-    np.testing.assert_allclose(
-        np.asarray(pipe.wte.weight.grad._read()),
-        np.asarray(ref.gpt.wte.weight.grad._read()), atol=2e-4)
-    np.testing.assert_allclose(
-        np.asarray(pipe.ln_f.weight.grad._read()),
-        np.asarray(ref.gpt.ln_f.weight.grad._read()), atol=2e-4)
-    for n in [n for n, _ in ref.gpt.blocks[0].named_parameters()]:
-        gs = np.asarray(pipe.blocks.stacked_parameter(n).grad._read())
-        ge = np.stack([np.asarray(dict(b.named_parameters())[n]
-                                  .grad._read())
-                       for b in ref.gpt.blocks])
-        np.testing.assert_allclose(gs, ge, atol=2e-4)
-
-
-def test_gpt_pipe_1f1b_trains(mesh):
-    """jit-compiled GPT 1F1B steps drive the loss down."""
-    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
-
-    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=4,
-                    num_heads=4, max_seq_len=16, dropout=0.0)
-    paddle.seed(1)
-    pipe = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp", dp_axis="dp",
-                              num_microbatches=2)
-    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                 parameters=pipe.parameters())
-    rng = np.random.default_rng(6)
-    ids = paddle.to_tensor(rng.integers(0, 64, (4, 16)).astype(np.int32))
-    labels = paddle.to_tensor(rng.integers(0, 64, (4, 16)).astype(np.int32))
-
-    @paddle.jit.to_static
-    def step(i, l):
-        loss = pipe.train_batch(i, l)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
-
-    losses = [float(step(ids, labels)) for _ in range(6)]
-    assert losses[-1] < losses[0], losses

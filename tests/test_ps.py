@@ -12,6 +12,8 @@ import threading
 import numpy as np
 import pytest
 
+import _children
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 import paddle_tpu as paddle
@@ -95,6 +97,7 @@ def test_sync_mode_waits_for_all_workers():
     assert not got
     c2.push_dense("w", np.full(2, 3.0, np.float32))  # completes the step
     t.join(timeout=30)
+    assert got, "the pull at the version of a completed step blocked 30 s"
     value, version = got[0]
     # sync applies the WORKER-MEAN grad: (1 + 3)/2 = 2 -> w = -0.5*2
     np.testing.assert_allclose(value, -1.0)
@@ -167,27 +170,23 @@ def test_fleet_ps_role_flow(tmp_path):
             "PADDLE_TRAINERS_NUM": "1"}
     server = worker = None
     try:
-        server = subprocess.Popen(
+        server = _children.spawn(
             [sys.executable, str(script)],
             env={**base, "TRAINING_ROLE": "PSERVER",
                  "PADDLE_PORT": str(port)},
-            cwd=str(tmp_path), stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True)
-        worker = subprocess.Popen(
+            cwd=str(tmp_path), stderr=subprocess.STDOUT)
+        worker = _children.spawn(
             [sys.executable, str(script)],
             env={**base, "TRAINING_ROLE": "TRAINER",
                  "PADDLE_TRAINER_ID": "0"},
-            cwd=str(tmp_path), stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True)
-        wout, _ = worker.communicate(timeout=300)
-        sout, _ = server.communicate(timeout=180)
+            cwd=str(tmp_path), stderr=subprocess.STDOUT)
+        (wout, _), (sout, _) = _children.outputs(
+            [worker, server], what="a trainer and its parameter server")
         assert worker.returncode == 0, wout
         assert "PS_ROLE_OK" in wout
         assert server.returncode == 0, sout
     finally:
-        for p in (server, worker):
-            if p is not None and p.poll() is None:
-                p.kill()
+        _children.kill(server, worker)
 
 
 def test_ssd_table_exceeds_memory_budget(cluster):

@@ -551,7 +551,8 @@ def test_serving_bench_fleet_smoke(gpt):
     recovery is lossless and bitwise, and no survivor leaks pages
     (absolute times are TPU claims)."""
     import sys
-    sys.path.insert(0, "/root/repo/benchmarks")
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks"))
     import serving_bench as sb
     cfg = gpt.cfg
     row = sb._measure_fleet(cfg, gpt, slots=2, prompt_len=16,

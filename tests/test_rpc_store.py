@@ -9,14 +9,16 @@ import threading
 
 import pytest
 
+import _children
+
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from paddle_tpu.distributed.store import TCPStore
 
 
 def test_tcp_store_basics():
-    master = TCPStore("127.0.0.1", 0, world_size=2, is_master=True)
-    client = TCPStore("127.0.0.1", master.port, world_size=2)
+    master = TCPStore("127.0.0.1", 0, world_size=2, is_master=True, timeout=30)
+    client = TCPStore("127.0.0.1", master.port, world_size=2, timeout=30)
     master.set("k", b"v")
     assert client.get("k") == b"v"
     assert client.add("ctr", 3) == 3
@@ -37,8 +39,9 @@ def test_tcp_store_basics():
 
 
 def test_tcp_store_barrier():
-    master = TCPStore("127.0.0.1", 0, world_size=3, is_master=True)
-    clients = [TCPStore("127.0.0.1", master.port) for _ in range(2)]
+    master = TCPStore("127.0.0.1", 0, world_size=3, is_master=True, timeout=30)
+    clients = [TCPStore("127.0.0.1", master.port, timeout=30)
+               for _ in range(2)]
     done = []
 
     def arrive(s, i):
@@ -115,18 +118,15 @@ def test_rpc_three_workers(tmp_path):
             env = {**os.environ, "PYTHONPATH": _REPO_ROOT,
                    "PADDLE_TRAINER_ID": str(rank),
                    "MASTER": f"127.0.0.1:{port}"}
-            procs.append(subprocess.Popen(
+            procs.append(_children.spawn(
                 [sys.executable, str(script)], env=env, cwd=str(tmp_path),
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                text=True))
-        outs = [p.communicate(timeout=120)[0] for p in procs]
-        for p, out in zip(procs, outs):
+                stderr=subprocess.STDOUT))
+        outs = _children.outputs(procs, what="three rpc workers")
+        for p, (out, _) in zip(procs, outs):
             assert p.returncode == 0, out
             assert "RPC_OK" in out
     finally:
-        for p in procs:
-            if p.poll() is None:
-                p.kill()
+        _children.kill(*procs)
 
 
 def test_native_store_backend():
@@ -135,9 +135,9 @@ def test_native_store_backend():
     from paddle_tpu.distributed import native
     if native._load() is None:
         pytest.skip("no C++ toolchain for the native store")
-    master = TCPStore("127.0.0.1", 0, is_master=True)
+    master = TCPStore("127.0.0.1", 0, is_master=True, timeout=30)
     assert master.is_native
-    client = TCPStore("127.0.0.1", master.port)
+    client = TCPStore("127.0.0.1", master.port, timeout=30)
     master.set("k", b"v1")
     assert client.get("k") == b"v1"
     client.set("k", b"v2")
@@ -168,9 +168,9 @@ def test_native_store_backend():
 
 def test_python_fallback_store(monkeypatch):
     monkeypatch.setenv("PDTPU_NATIVE_STORE", "0")
-    master = TCPStore("127.0.0.1", 0, is_master=True)
+    master = TCPStore("127.0.0.1", 0, is_master=True, timeout=30)
     assert not master.is_native
-    client = TCPStore("127.0.0.1", master.port)
+    client = TCPStore("127.0.0.1", master.port, timeout=30)
     master.set("k", b"v")
     assert client.get("k") == b"v"
     assert client.add("c", 2) == 2

@@ -14,59 +14,18 @@ import pytest
 import paddle_tpu as paddle
 
 
-def test_dp_mp_pp_single_mesh():
-    """GPipe over pp + Megatron TP over mp (GSPMD inside the pipeline
-    shard_map via auto axes) + dp batch sharding, one mesh, full train
-    step."""
-    import paddle_tpu.distributed as dist
-    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
-
-    cfg = GPTConfig(vocab_size=256, hidden_size=128, num_layers=2,
-                    num_heads=4, max_seq_len=64, dropout=0.0)
-    dp, mp, pp = 2, 2, 2
-    mesh = dist.ProcessMesh(np.arange(8).reshape(dp, mp, pp),
-                            ["dp", "mp", "pp"])
-    paddle.seed(0)
-    model = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp", dp_axis="dp",
-                               num_microbatches=2)
-    model.blocks.shard(mesh, "pp", tp_axis="mp", tp_rules={
-        "attn.qkv.weight": 2, "attn.qkv.bias": 1,
-        "mlp.fc1.weight": 2, "mlp.fc1.bias": 1,
-        "attn.proj.weight": 1, "mlp.fc2.weight": 1,
-    })
-    model.train()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                 parameters=model.parameters())
-
-    @paddle.jit.to_static
-    def train_step(ids, labels):
-        loss = model(ids, labels)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
-
-    rng = np.random.default_rng(0)
-    pl = [dist.Shard(0), dist.Replicate(), dist.Replicate()]
-    losses = []
-    for _ in range(3):
-        ids = dist.shard_tensor(
-            rng.integers(0, 256, (2 * dp, 16)).astype(np.int32), mesh,
-            pl)
-        labels = dist.shard_tensor(
-            rng.integers(0, 256, (2 * dp, 16)).astype(np.int32), mesh,
-            pl)
-        losses.append(float(train_step(ids, labels)))
-    assert all(np.isfinite(l) for l in losses)
-    # stacked qkv must carry BOTH pp (dim 0) and mp (dim 2) sharding
-    w = model.blocks.stacked_parameter("attn.qkv.weight")._read()
-    spec = str(getattr(w.sharding, "spec", ""))
-    assert "pp" in spec and "mp" in spec, spec
+TP_RULES = {
+    "attn.qkv.weight": 2, "attn.qkv.bias": 1,
+    "mlp.fc1.weight": 2, "mlp.fc1.bias": 1,
+    "attn.proj.weight": 1, "mlp.fc2.weight": 1,
+}
 
 
-def test_dp_mp_pp_matches_dp_only():
-    """The 3-axis hybrid must compute the same losses as plain dp on the
-    same seed/data (parallelism is an implementation detail)."""
+def _pipe_run(mesh_shape, names, tp, pl):
+    """(the losses of two compiled train steps, the model) of one seeded
+    two-layer ``GPTForCausalLMPipe`` on the eight devices laid out as
+    ``mesh_shape``: the data, the seed and the optimizer are the same
+    whatever the layout."""
     import paddle_tpu.distributed as dist
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
 
@@ -77,42 +36,59 @@ def test_dp_mp_pp_matches_dp_only():
     data = [(rng0.integers(0, 128, (8, 16)).astype(np.int32),
              rng0.integers(0, 128, (8, 16)).astype(np.int32))
             for _ in range(2)]
+    mesh = dist.ProcessMesh(np.arange(8).reshape(*mesh_shape), names)
+    paddle.seed(0)
+    model = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp", dp_axis="dp",
+                               num_microbatches=2)
+    if tp:
+        model.blocks.shard(mesh, "pp", tp_axis="mp", tp_rules=TP_RULES)
+    model.train()
+    opt = paddle.optimizer.SGD(learning_rate=0.1,
+                               parameters=model.parameters())
 
-    def run(mesh_shape, names, tp, pl):
-        mesh = dist.ProcessMesh(
-            np.arange(8).reshape(*mesh_shape), names)
-        paddle.seed(0)
-        model = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp",
-                                   dp_axis="dp", num_microbatches=2)
-        if tp:
-            model.blocks.shard(mesh, "pp", tp_axis="mp", tp_rules={
-                "attn.qkv.weight": 2, "attn.qkv.bias": 1,
-                "mlp.fc1.weight": 2, "mlp.fc1.bias": 1,
-                "attn.proj.weight": 1, "mlp.fc2.weight": 1,
-            })
-        model.train()
-        opt = paddle.optimizer.SGD(learning_rate=0.1,
-                                   parameters=model.parameters())
+    @paddle.jit.to_static
+    def step(ids, labels):
+        loss = model(ids, labels)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
 
-        @paddle.jit.to_static
-        def step(ids, labels):
-            loss = model(ids, labels)
-            loss.backward()
-            opt.step()
-            opt.clear_grad()
-            return loss
+    return [float(step(dist.shard_tensor(ids, mesh, pl),
+                       dist.shard_tensor(labels, mesh, pl)))
+            for ids, labels in data], model
 
-        out = []
-        for ids, labels in data:
-            out.append(float(step(
-                dist.shard_tensor(ids, mesh, pl),
-                dist.shard_tensor(labels, mesh, pl))))
-        return out
 
-    ref = run((4, 2), ["dp", "pp"], False,
-              [dist.Shard(0), dist.Replicate()])
-    got = run((2, 2, 2), ["dp", "mp", "pp"], True,
-              [dist.Shard(0), dist.Replicate(), dist.Replicate()])
+@pytest.fixture(scope="module")
+def dp_mp_pp():
+    """The 3-axis hybrid's run, built and stepped once for the two cases
+    that read it.  The same layout at ``__graft_entry__``'s 128-wide toy
+    under AdamW is tests/test_models.py's
+    ``test_dryrun_multichip_dp_mp_pp``."""
+    import paddle_tpu.distributed as dist
+    return _pipe_run((2, 2, 2), ["dp", "mp", "pp"], True,
+                     [dist.Shard(0), dist.Replicate(), dist.Replicate()])
+
+
+def test_dp_mp_pp_single_mesh(dp_mp_pp):
+    """GPipe over pp + Megatron TP over mp (GSPMD inside the pipeline
+    shard_map via auto axes) + dp batch sharding, one mesh, full train
+    step."""
+    losses, model = dp_mp_pp
+    assert all(np.isfinite(l) for l in losses)
+    # stacked qkv must carry BOTH pp (dim 0) and mp (dim 2) sharding
+    w = model.blocks.stacked_parameter("attn.qkv.weight")._read()
+    spec = str(getattr(w.sharding, "spec", ""))
+    assert "pp" in spec and "mp" in spec, spec
+
+
+def test_dp_mp_pp_matches_dp_only(dp_mp_pp):
+    """The 3-axis hybrid must compute the same losses as plain dp on the
+    same seed/data (parallelism is an implementation detail)."""
+    import paddle_tpu.distributed as dist
+    got, _ = dp_mp_pp
+    ref, _ = _pipe_run((4, 2), ["dp", "pp"], False,
+                       [dist.Shard(0), dist.Replicate()])
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -131,68 +107,6 @@ def test_gpt13b_aot_lowering_fits_v5e():
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "AOT 13B OK" in r.stdout
     assert "tiny equivalence" in r.stdout
-
-
-def test_zero_mp_pp_1f1b_single_layout():
-    """ZeRO-2 (sharding axis = batch axis) composed with Megatron TP and
-    the FUSED 1F1B pipeline schedule in one device layout (VERDICT r4
-    item 7; reference bar: semi_auto_llama dp+mp+pp with sharding
-    stages + pipeline_parallel.py:663 train_batch)."""
-    import paddle_tpu.distributed as dist
-    from paddle_tpu.distributed.fleet.sharding_optimizer import \
-        DygraphShardingOptimizer
-    from paddle_tpu.distributed.fleet.topology import \
-        HybridCommunicateGroup
-    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLMPipe
-
-    cfg = GPTConfig(vocab_size=256, hidden_size=128, num_layers=2,
-                    num_heads=4, max_seq_len=64, dropout=0.0)
-    pp, shd, mp = 2, 2, 2
-    hcg = HybridCommunicateGroup(dp_degree=1, pp_degree=pp,
-                                 sharding_degree=shd, sep_degree=1,
-                                 mp_degree=mp)
-    mesh = dist.ProcessMesh(np.arange(8).reshape(pp, shd, mp),
-                            ["pp", "sharding", "mp"])
-    paddle.seed(0)
-    model = GPTForCausalLMPipe(cfg, mesh, pp_axis="pp",
-                               dp_axis="sharding", num_microbatches=2)
-    model.blocks.shard(mesh, "pp", tp_axis="mp", tp_rules={
-        "attn.qkv.weight": 2, "attn.qkv.bias": 1,
-        "mlp.fc1.weight": 2, "mlp.fc1.bias": 1,
-        "attn.proj.weight": 1, "mlp.fc2.weight": 1,
-    })
-    model.train()
-    inner = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                   parameters=model.parameters())
-    opt = DygraphShardingOptimizer(inner, hcg, stage=2)
-
-    @paddle.jit.to_static
-    def train_step(ids, labels):
-        loss = model.train_batch(ids, labels)   # fused 1F1B
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
-
-    rng = np.random.default_rng(0)
-    pl = [dist.Replicate(), dist.Shard(0), dist.Replicate()]
-    losses = []
-    for _ in range(3):
-        ids = dist.shard_tensor(
-            rng.integers(0, 256, (4, 16)).astype(np.int32), mesh, pl)
-        labels = dist.shard_tensor(
-            rng.integers(0, 256, (4, 16)).astype(np.int32), mesh, pl)
-        losses.append(float(train_step(ids, labels)))
-    assert all(np.isfinite(l) for l in losses), losses
-
-    # ZeRO: moments sharded over `sharding`; TP: stacked qkv keeps mp;
-    # and the stacked weights keep their pp sharding through updates
-    accs = inner._accumulators["moment1"]
-    assert any("sharding" in str(getattr(a._read().sharding, "spec", ""))
-               for a in accs.values())
-    w = model.blocks.stacked_parameter("attn.qkv.weight")._read()
-    spec = str(getattr(w.sharding, "spec", ""))
-    assert "mp" in spec and "pp" in spec, spec
 
 
 @pytest.mark.slow

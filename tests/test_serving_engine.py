@@ -19,6 +19,19 @@ def gpt(serving_gpt):
     return serving_gpt
 
 
+# The file's engine geometries.  Each is a set of compiled programs
+# (10-20 s under tier-1's load), so a case takes one of these three
+# unless it says why not: two slots with room for every page, one slot
+# (the cases that count what queues behind it), and three slots over a
+# pool BELOW their working set (8 usable pages of 4 tokens where three
+# requests of up to 16 need 12).
+_STEP = dict(decode_window=4, prefill_chunk=8, q_block=2)
+ROOMY = dict(max_slots=2, page_size=8, max_seq_len=32, **_STEP)
+ONE_SLOT = dict(max_slots=1, page_size=8, max_seq_len=16, **_STEP)
+CONTENDED = dict(max_slots=3, page_size=4, max_seq_len=16, total_pages=9,
+                 **_STEP)
+
+
 def _refs(model, prompts, new):
     return [generate(model, p[None, :], max_new_tokens=n).numpy()[0]
             for p, n in zip(prompts, new)]
@@ -54,9 +67,7 @@ def test_engine_matches_generate_with_slot_contention(gpt):
                for n in (5, 9, 3, 12)]
     new = [6, 4, 7, 5]
     refs = _refs(gpt, prompts, new)
-    eng = ContinuousBatchingEngine(gpt, max_slots=2, page_size=8,
-                                   max_seq_len=32, decode_window=4,
-                                   prefill_chunk=8, q_block=2)
+    eng = ContinuousBatchingEngine(gpt, **ROOMY)
     rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
     done = eng.run()
     assert sorted(done) == sorted(rids)
@@ -75,9 +86,7 @@ def test_engine_page_reuse_and_free_list_restore(gpt):
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, 96, (6,)).astype(np.int32)
                for _ in range(4)]
-    eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=16, decode_window=4,
-                                   prefill_chunk=8, q_block=2)
+    eng = ContinuousBatchingEngine(gpt, **ONE_SLOT)
     refs = _refs(gpt, prompts, [4] * 4)
     rids = [eng.add_request(p, 4) for p in prompts]
     done = eng.run()
@@ -116,9 +125,7 @@ def test_engine_eos_early_retire(gpt):
     eos = int(full[prompt.size + 1])       # 2nd generated token
     ref = generate(gpt, prompt[None, :], max_new_tokens=8,
                    eos_token_id=eos).numpy()[0]
-    eng = ContinuousBatchingEngine(gpt, max_slots=2, page_size=8,
-                                   max_seq_len=32, decode_window=4,
-                                   prefill_chunk=8, q_block=2)
+    eng = ContinuousBatchingEngine(gpt, **ROOMY)
     rid = eng.add_request(prompt, 8, eos_token_id=eos)
     done = eng.run()
     got = done[rid].sequence
@@ -134,6 +141,8 @@ def test_engine_llama_gqa(serving_llama_gqa):
                for n in (7, 4, 11)]
     new = [5, 6, 4]
     refs = _refs(m, prompts, new)
+    # a geometry of its own: another model shares no program anyway,
+    # and ``pages_per_block`` must be seen to reach the kernel
     eng = ContinuousBatchingEngine(m, max_slots=2, page_size=8,
                                    max_seq_len=32, decode_window=3,
                                    prefill_chunk=6, q_block=2,
@@ -145,8 +154,7 @@ def test_engine_llama_gqa(serving_llama_gqa):
 
 
 def test_engine_rejects_oversize_request(gpt):
-    eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=16)
+    eng = ContinuousBatchingEngine(gpt, **ONE_SLOT)
     with pytest.raises(ValueError, match="max_seq_len"):
         eng.add_request(np.zeros(12, np.int32), 8)
 
@@ -178,10 +186,7 @@ def test_engine_preempt_requeue_bitwise(gpt):
     refs = _paged_refs(gpt, prompts, new)
     # each request needs <= 4 pages (<= 16 tokens, page_size 4); three
     # slots' worst case is 12 pages but the pool only holds 8 usable
-    eng = ContinuousBatchingEngine(gpt, max_slots=3, page_size=4,
-                                   max_seq_len=16, total_pages=9,
-                                   decode_window=4, prefill_chunk=8,
-                                   q_block=2)
+    eng = ContinuousBatchingEngine(gpt, **CONTENDED)
     rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
     done = eng.run()
     assert sorted(done) == sorted(rids)
@@ -210,10 +215,8 @@ def test_engine_serving_fault_drill(gpt):
     clock = [0.0]
     faults.clear()
     try:
-        eng = ContinuousBatchingEngine(gpt, max_slots=3, page_size=4,
-                                       max_seq_len=16, total_pages=9,
-                                       decode_window=4, prefill_chunk=8,
-                                       q_block=2, clock=lambda: clock[0])
+        eng = ContinuousBatchingEngine(gpt, **CONTENDED,
+                                       clock=lambda: clock[0])
         rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
         r_nan, r_cancel = rids[1], rids[2]
         r_dead = eng.add_request(prompts[0], 8, deadline_ms=100.0)
@@ -258,9 +261,7 @@ def test_engine_injected_page_pressure(gpt):
     ref1, ref2 = _paged_refs(gpt, [p1, p2], [8, 8])
     faults.clear()
     try:
-        eng = ContinuousBatchingEngine(gpt, max_slots=2, page_size=8,
-                                       max_seq_len=32, decode_window=4,
-                                       prefill_chunk=8, q_block=2)
+        eng = ContinuousBatchingEngine(gpt, **ROOMY)
         r1 = eng.add_request(p1, 8)
         r2 = eng.add_request(p2, 8)
         faults.inject("engine_page_pressure", match=str(r1))
@@ -286,9 +287,7 @@ def test_engine_nan_decode_mid_stream(gpt):
     (ref2,) = _paged_refs(gpt, [p2], [8])
     faults.clear()
     try:
-        eng = ContinuousBatchingEngine(gpt, max_slots=2, page_size=8,
-                                       max_seq_len=32, decode_window=4,
-                                       prefill_chunk=8, q_block=2)
+        eng = ContinuousBatchingEngine(gpt, **ROOMY)
         r1 = eng.add_request(p1, 8)
         r2 = eng.add_request(p2, 8)
         # at=2: first guarded dispatch for r1 is its prefill step; the
@@ -312,8 +311,10 @@ def test_engine_page_budget_eager_reject(gpt):
     backstop."""
     from paddle_tpu.core import errors
 
+    # a geometry of its own: a request the sequence limit admits (24 of
+    # 32 tokens) over a pool that can never hold it (2 usable pages)
     eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=32, total_pages=3)
+                                   max_seq_len=32, total_pages=3, **_STEP)
     with pytest.raises(errors.PageBudgetError,
                        match="PDT-E016") as ei:
         eng.add_request(np.zeros(12, np.int32), 12)   # 3 pages > 2
@@ -333,8 +334,7 @@ def test_engine_queue_policies(gpt):
 
     rng = np.random.default_rng(17)
     p = rng.integers(0, 96, (5,)).astype(np.int32)
-    eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=16, max_queue=1,
+    eng = ContinuousBatchingEngine(gpt, **ONE_SLOT, max_queue=1,
                                    queue_policy="reject")
     eng.add_request(p, 4)
     with pytest.raises(errors.QueueFullError, match="PDT-E017") as ei:
@@ -343,8 +343,7 @@ def test_engine_queue_policies(gpt):
     assert eng.stats["rejected"] == 1
     eng.run()
 
-    eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=16, max_queue=1,
+    eng = ContinuousBatchingEngine(gpt, **ONE_SLOT, max_queue=1,
                                    queue_policy="block")
     rids = [eng.add_request(p, 4) for _ in range(3)]  # adds 2+ block
     done = eng.run()
@@ -361,9 +360,7 @@ def test_engine_run_budget_warns_and_surfaces_pending(gpt):
     rng = np.random.default_rng(19)
     prompts = [rng.integers(0, 96, (6,)).astype(np.int32)
                for _ in range(3)]
-    eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=16, decode_window=4,
-                                   prefill_chunk=8, q_block=2)
+    eng = ContinuousBatchingEngine(gpt, **ONE_SLOT)
     rids = [eng.add_request(p, 4) for p in prompts]
     with pytest.warns(RuntimeWarning, match="pending_requests"):
         done = eng.run(max_steps=2)
@@ -380,9 +377,7 @@ def test_engine_cancel_after_final_token_honored(gpt):
     "length" retirement that silently outruns the cancellation."""
     rng = np.random.default_rng(23)
     prompt = rng.integers(0, 96, (6,)).astype(np.int32)
-    eng = ContinuousBatchingEngine(gpt, max_slots=1, page_size=8,
-                                   max_seq_len=16, decode_window=4,
-                                   prefill_chunk=8, q_block=2)
+    eng = ContinuousBatchingEngine(gpt, **ONE_SLOT)
     rid = eng.add_request(prompt, 4)
     done = {}
     for _ in range(50):
@@ -397,190 +392,3 @@ def test_engine_cancel_after_final_token_honored(gpt):
     assert done[rid].finish_reason == "cancelled"
     assert eng.stats["cancelled"] == 1 and eng.stats["retired"] == 0
     assert eng.stats["pages_in_use"] == 0
-
-
-# ----------------------------------------------------------------------
-# Cross-request KV prefix cache (ISSUE 6): a radix index over the page
-# pool maps shared prefixes onto already-written pages (block-table
-# indirection only), with copy-on-write at the divergence page and LRU
-# eviction — bitwise-identical to generate(kv_cache='paged') and to the
-# cache-off engine in every mix, including preempt-requeue restore and
-# post-eviction re-admission.
-# ----------------------------------------------------------------------
-
-def _engine(gpt, **kw):
-    args = dict(max_slots=2, page_size=4, max_seq_len=32,
-                decode_window=4, prefill_chunk=8, q_block=2)
-    args.update(kw)
-    return ContinuousBatchingEngine(gpt, **args)
-
-
-def test_engine_prefix_cache_shared_prefix_bitwise(serving_lm):
-    """Requests sharing a long prompt prefix: later admissions map the
-    shared pages from the index (prefill tokens computed drops below
-    tokens requested) and every output is bitwise-identical to the
-    uncached reference AND to a cache-off engine."""
-    rng = np.random.default_rng(29)
-    shared = rng.integers(0, 96, (12,)).astype(np.int32)  # 3 full pages
-    tails = [rng.integers(0, 96, (n,)).astype(np.int32)
-             for n in (3, 2, 5, 1)]
-    prompts = [np.concatenate([shared, t]) for t in tails]
-    new = [6, 5, 4, 6]
-    refs = _paged_refs(serving_lm, prompts, new)
-
-    outs = {}
-    for mode in (True, False):
-        eng = _engine(serving_lm, prefix_cache=mode)
-        rids = [eng.add_request(p, n) for p, n in zip(prompts, new)]
-        done = eng.run()
-        outs[mode] = [done[r].sequence for r in rids]
-        st = eng.stats
-        if mode:
-            # the first two admissions run concurrently (2 slots) and
-            # prefill the shared prefix independently; both later
-            # admissions hit the published pages
-            assert st["cache_hits"] >= 2
-            assert st["cache_hit_tokens"] >= 2 * 12
-            assert (st["prefill_tokens_computed"]
-                    < st["prefill_tokens_requested"])
-            _assert_pool_conserved(eng)
-        else:
-            # cache off restores the uncached meter exactly
-            assert st["cache_hits"] == 0 and st["cached_pages"] == 0
-            assert (st["prefill_tokens_computed"]
-                    == st["prefill_tokens_requested"])
-            assert len(eng._free_pages) == eng.total_pages - 1
-    for got_on, got_off, ref in zip(outs[True], outs[False], refs):
-        np.testing.assert_array_equal(got_on, ref)
-        np.testing.assert_array_equal(got_off, ref)
-
-
-def test_engine_prefix_cache_cow_full_prompt(serving_lm):
-    """A fully-cached page-aligned prompt takes the copy-on-write
-    path: the divergence page is duplicated, exactly ONE token is
-    recomputed for the last position's logits, the shared page is
-    never written, and the output stays bitwise."""
-    rng = np.random.default_rng(31)
-    prompt = rng.integers(0, 96, (8,)).astype(np.int32)  # 2 full pages
-    (ref,) = _paged_refs(serving_lm, [prompt], [6])
-    eng = _engine(serving_lm)
-    r1 = eng.add_request(prompt, 6)
-    done = eng.run()
-    np.testing.assert_array_equal(done[r1].sequence, ref)
-    # retirement published the full prompt pages
-    assert eng.stats["cached_pages"] >= 2
-    base = eng.stats["prefill_tokens_computed"]
-    r2 = eng.add_request(prompt, 6)           # identical prompt: full hit
-    done = eng.run()
-    np.testing.assert_array_equal(done[r2].sequence, ref)
-    st = eng.stats
-    assert st["cache_hit_tokens"] >= prompt.size - 1   # COW: all but one
-    assert st["prefill_tokens_computed"] - base == 1   # 1 recomputed tok
-    _assert_pool_conserved(eng)
-
-
-def test_engine_preempt_requeue_recompute_drop(gpt):
-    """The PR5 recompute gap, closed: a preempted victim's pages are
-    PUBLISHED to the index (not freed), so its re-admission restores
-    from its own just-published pages — prefill-tokens-computed drops
-    versus the cache-off engine on the identical forced-preemption
-    workload, outputs bitwise both ways.  (In a truly starved pool the
-    LRU may reclaim some of the victim's pages for the grower — that
-    path is covered by test_engine_preempt_requeue_bitwise; here the
-    pool is roomy and the ``engine_page_pressure`` drill forces the
-    preemption, so the published pages survive to the re-admission.)"""
-    from paddle_tpu.resilience import faults
-
-    rng = np.random.default_rng(41)
-    p1 = rng.integers(0, 96, (6,)).astype(np.int32)
-    p2 = rng.integers(0, 96, (7,)).astype(np.int32)
-    refs = _paged_refs(gpt, [p1, p2], [8, 8])
-    computed = {}
-    faults.clear()
-    try:
-        for mode in (False, True):
-            eng = _engine(gpt, prefix_cache=mode)
-            r1 = eng.add_request(p1, 8)
-            r2 = eng.add_request(p2, 8)
-            # r1's growth hits injected pressure -> r2 (latest) preempts
-            faults.inject("engine_page_pressure", match=str(r1))
-            done = eng.run()
-            np.testing.assert_array_equal(done[r1].sequence, refs[0])
-            np.testing.assert_array_equal(done[r2].sequence, refs[1])
-            st = eng.stats
-            assert st["preemptions"] >= 1
-            computed[mode] = st["prefill_tokens_computed"]
-            if mode:
-                # prompts are DISTINCT, so every hit is the victim's
-                # re-admission restoring from its own published pages
-                assert st["cache_hits"] >= 1
-                assert st["evictions"] == 0    # roomy pool: none lost
-                _assert_pool_conserved(eng)
-            else:
-                assert st["cache_hits"] == 0
-    finally:
-        faults.clear()
-    assert computed[True] < computed[False]
-
-
-def test_engine_cache_evict_drill_bitwise(gpt):
-    """The deterministic engine_cache_evict drill: cached prefix pages
-    are evicted under the injected pressure, and a re-admission of the
-    evicted prefix transparently re-prefills with bitwise-identical
-    output (the cache can only ever cost recompute, never
-    correctness)."""
-    from paddle_tpu.resilience import faults
-
-    rng = np.random.default_rng(37)
-    p1 = rng.integers(0, 96, (9,)).astype(np.int32)
-    p2 = rng.integers(0, 96, (6,)).astype(np.int32)
-    ref1, ref2 = _paged_refs(gpt, [p1, p2], [6, 5])
-    faults.clear()
-    try:
-        eng = _engine(gpt)
-        r1 = eng.add_request(p1, 6)
-        assert eng.run()[r1].finish_reason == "length"
-        assert eng.stats["cached_pages"] >= 2   # p1's prefix published
-        # every allocation for p2 forcibly evicts the LRU cached page
-        faults.inject("engine_cache_evict", times=0)
-        r2 = eng.add_request(p2, 5)
-        done = eng.run()
-        np.testing.assert_array_equal(done[r2].sequence, ref2)
-        faults.clear()
-        st = eng.stats
-        assert st["evictions"] >= 2             # drill actually evicted
-        hits_before = st["cache_hits"]
-        # p1 again: its prefix was evicted -> full re-prefill, bitwise
-        r3 = eng.add_request(p1, 6)
-        done = eng.run()
-        np.testing.assert_array_equal(done[r3].sequence, ref1)
-        assert eng.stats["cache_hits"] == hits_before  # true miss
-        _assert_pool_conserved(eng)
-    finally:
-        faults.clear()
-
-
-def test_serving_bench_shared_prefix_accounting(gpt):
-    """CPU tiny-model smoke for the serving_bench ``shared_prefix``
-    row: the accounting must show prefill tokens computed < tokens
-    requested at a high prefix-hit rate, zero leaked pages, and a
-    sane saved fraction (absolute times are TPU-only claims)."""
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "benchmarks", "serving_bench.py")
-    spec = importlib.util.spec_from_file_location(
-        "serving_bench_smoke", path)
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
-    row = sb._measure_shared_prefix(
-        gpt.cfg, gpt, slots=2, max_seq_len=64, shared_len=12,
-        tail_range=(2, 7), new_tokens=4, n_requests=6, hit_every=3,
-        page_size=4, decode_window=4, prefill_chunk=8, warm=False)
-    assert (row["prefill_tokens_computed"]
-            < row["prefill_tokens_requested"])
-    assert row["prefill_saved_frac"] > 0
-    assert row["cache_hits"] >= 2 and row["cache_hit_tokens"] >= 2 * 12
-    assert row["pages_leaked"] == 0
-    assert row["ttft_ms_avg"] > 0 and row["ttft_ms_avg_nocache"] > 0

@@ -203,14 +203,14 @@ def test_model_prepare_remat_flags_blocks():
 def test_remat_static_peak_drop():
     """The acceptance gauge: on a multi-layer GPT block stack the
     captured train step's ``static_peak_bytes`` drops >= 25% with remat
-    on (measured 54% on this geometry, 56% at the full gpt124m
-    hidden=768/seq=256/batch=8 shape).  Single-layer stacks can go the
-    OTHER way (nothing upstream to free); the saving is a multi-layer
-    property, which is why this config has 4 layers."""
+    on (measured 52% on this geometry, 47% at four times its width, 56%
+    at the full gpt124m hidden=768/seq=256/batch=8 shape).  Single-layer
+    stacks can go the OTHER way (nothing upstream to free); the saving
+    is a multi-layer property, which is why this config has 4 layers."""
     def peak(remat):
         paddle.seed(0)
-        cfg = _gpt_cfg(vocab_size=128, hidden_size=256, num_layers=4,
-                       num_heads=8, max_seq_len=128,
+        cfg = _gpt_cfg(vocab_size=128, hidden_size=64, num_layers=4,
+                       num_heads=4, max_seq_len=128,
                        use_flash_attention=False, recompute=remat,
                        recompute_policy="dots_and_kernels_saveable")
         m = GPTForCausalLM(cfg)
@@ -248,7 +248,7 @@ def test_train_batch_headroom_walk():
 
     out = calibrate.train_batch_headroom(
         budget_gb=1.0, hidden=64, layers=2, heads=4, vocab=128,
-        seq=32, batches=(1, 2, 4))
+        seq=32, batches=(1, 2))     # two sizes order the peaks as three do
     rows = out["rows"]
     assert rows and all(r["static_peak_bytes"] > 0 for r in rows)
     peaks = [r["static_peak_bytes"] for r in rows]

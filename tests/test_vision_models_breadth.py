@@ -1,27 +1,30 @@
 """Forward-shape + trainability tests for the round-3 vision model batch
 (VERDICT r2 missing #6): densenet, squeezenet, shufflenetv2, inceptionv3,
 googlenet, mobilenetv1/v3. Reference test model:
-test/legacy_test/test_vision_models.py (forward on random input)."""
+test/legacy_test/test_vision_models.py (forward on random input).  The two
+Inception networks, at input sizes of their own, are
+tests/test_vision_models_breadth_inception.py."""
 import numpy as np
 import pytest
 
+import _traced
 import paddle_tpu as paddle
 from paddle_tpu.vision import models
 
 
 def _fwd(model, size=64, batch=2):
-    x = paddle.to_tensor(np.random.default_rng(0).normal(
-        size=(batch, 3, size, size)).astype("float32"))
-    model.eval()
-    with paddle.no_grad():
-        return model(x)
+    """The eval forward as ONE compiled program (``_traced.forward``): a
+    published network is hundreds of ops.  The eager forward of these
+    layers is ``test_new_models_train_step``'s, below."""
+    x = np.random.default_rng(0).normal(
+        size=(batch, 3, size, size)).astype("float32")
+    return _traced.forward(model, x)
 
 
-# The two heaviest forward builds are `slow` (tier-1 budget audit,
-# PR7: the 870s run was clipping this file and its trailing siblings):
-# each family keeps a tier-1 representative — densenet169 for densenet,
-# mobilenet_v3_large for v3 — so per-model coverage survives the gate
-# and the marked variants still run under ``-m slow``.
+# densenet121 and mobilenet_v3_small are `slow`: each family keeps a tier-1
+# representative, densenet169 and mobilenet_v3_large, and the file is at
+# its 200 s of tier-1 without them (a constructor's initialisers are three
+# quarters of a traced case).
 @pytest.mark.parametrize("ctor,kw", [
     pytest.param(models.densenet121, {}, marks=pytest.mark.slow),
     (models.densenet169, {}),
@@ -40,22 +43,7 @@ def test_forward_shape(ctor, kw):
     model = ctor(num_classes=10, **kw)
     out = _fwd(model)
     assert tuple(out.shape) == (2, 10)
-    assert np.isfinite(out.numpy()).all()
-
-
-def test_inception_v3_forward():
-    paddle.seed(0)
-    model = models.inception_v3(num_classes=7)
-    out = _fwd(model, size=299, batch=1)
-    assert tuple(out.shape) == (1, 7)
-
-
-def test_googlenet_aux_heads():
-    paddle.seed(0)
-    model = models.GoogLeNet(num_classes=6)
-    out, aux1, aux2 = _fwd(model, size=96)
-    assert tuple(out.shape) == (2, 6)
-    assert tuple(aux1.shape) == (2, 6) and tuple(aux2.shape) == (2, 6)
+    assert np.isfinite(np.asarray(out)).all()
 
 
 def test_new_models_train_step():
@@ -70,7 +58,7 @@ def test_new_models_train_step():
         size=(2, 3, 64, 64)).astype("float32"))
     y = paddle.to_tensor(np.array([1, 3], np.int64))
     losses = []
-    for _ in range(3):
+    for _ in range(2):      # the loss before an update, and after it
         loss = paddle.nn.functional.cross_entropy(model(x), y)
         loss.backward()
         opt.step()

@@ -1,5 +1,7 @@
 """vision.ops / inference / utils namespace tests (reference patterns:
 ``test_nms_op.py``, ``test_roi_align_op.py``, ``test_inference_api.py``)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -8,6 +10,7 @@ import paddle_tpu.nn as nn
 from paddle_tpu.vision import ops as vops
 
 R = np.random.default_rng(17)
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _rand_boxes(n, size=64):
@@ -236,9 +239,8 @@ print("SERVED", out.shape)
 """
     np.save(tmp_path / "x.npy", x)
     r = subprocess.run([sys.executable, "-c", script],
-                       capture_output=True, text=True, cwd="/root/repo",
-                       env={**__import__("os").environ,
-                            "PYTHONPATH": "/root/repo",
+                       capture_output=True, text=True, cwd=_REPO_ROOT,
+                       env={**os.environ, "PYTHONPATH": _REPO_ROOT,
                             "JAX_PLATFORMS": "cpu"}, timeout=300)
     assert r.returncode == 0, (r.stdout, r.stderr)
     assert "SERVED" in r.stdout
