@@ -296,7 +296,8 @@ _routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
 
 
 def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
-               scaling=1.0, norm_eps=1e-6):
+               scaling=1.0, norm_eps=1e-6, scoring="sigmoid",
+               train_router=True, slots_at_a_time=None):
     """Values in, ``(out, tally, chunks)`` out; the math of
     ``SparseMoEBlock``.
 
@@ -305,7 +306,12 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
     ``expert_offset .. expert_offset + held`` ; ``bias`` [E] float32;
     ``norm_eps`` is added to the selected scores' sum before they are
     divided by it (the caller's family's: 1e-6 ``lfm2_moe``, 1e-20
-    ``deepseek_v3``).
+    ``deepseek_v3``, 0 ``mellum``); ``scoring`` is the caller's family's
+    too: ``"sigmoid"`` of each logit, or ``"softmax"`` over all E.
+    ``train_router`` False makes the combine weights constants of the
+    backward, so that neither ``gate`` nor ``x`` takes a gradient
+    through them (``SparseMoEBlock`` says when); ``slots_at_a_time``
+    is the most sorted slots a chunk holds (None: ``_SLOTS_AT_A_TIME``).
     ``tally`` is int32 [held + 1]: the slots routed to each held expert
     and, last, the slots the router filled (N * top_k).  ``chunks`` is
     int32 [2]: the chunks of sorted slots whose rows were worked on, and
@@ -315,13 +321,16 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
     n = flat.shape[0]
     with _scope.phase("router"):
         logits = jnp.dot(flat, gate, preferred_element_type=jnp.float32)
-        scores = jax.nn.sigmoid(logits)
+        scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
         # the bias decides WHICH experts, never how much of each
         _, chosen = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         weight = picked / (picked.sum(-1, keepdims=True) + norm_eps) \
             * scaling
+        if not train_router:
+            weight = jax.lax.stop_gradient(weight)
     with _scope.phase("dispatch"):
         local = chosen - expert_offset                        # [N, k]
         here = (local >= 0) & (local < held)
@@ -334,7 +343,7 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
         # all N * k sorted slots would be worked on only if every token
         # chose all its experts here: the chunks, and the conditions
         # the row work runs under
-        chunks = -(-n * k // _SLOTS_AT_A_TIME)
+        chunks = -(-n * k // (slots_at_a_time or _SLOTS_AT_A_TIME))
         while n * k % chunks:
             chunks += 1
         slots = n * k // chunks
@@ -382,7 +391,9 @@ def routed_by_call():
 class SparseMoEBlock(Layer):
     """Dropless top-k block that is told which experts it holds.
 
-    The router scores ALL ``num_experts`` (sigmoid, float32); selection
+    The router scores ALL ``num_experts`` in float32 (``scoring``:
+    ``"sigmoid"`` of each logit, the default, or ``"softmax"`` over them
+    all); selection
     adds ``expert_bias`` (a float32 buffer no gradient reaches: the
     trainer's balancing rule owns it), the combine weights are the
     selected scores normalised to sum to one (their sum plus
@@ -395,6 +406,18 @@ class SparseMoEBlock(Layer):
     no capacity and no dropped token.  What the absent experts would
     add is left out: under expert parallelism the shares are summed
     across chips, and on one chip the block runs without that exchange.
+
+    Two arguments are for a block that runs as such a lone share.
+    ``train_router`` False: the combine weights are constants of the
+    backward.  A share knows ``dL/dw_e`` of the experts it holds and
+    reads the absent ones' as zero, so the router's gradient it can
+    form is not its part of the deployment's but one that moves the
+    routed share itself (PERF.md section 6, PR 42: 6 x in 130 steps, or
+    to nothing); the deployment's balancing rule, which a share has
+    not, is what holds it.  ``slots_at_a_time``: a chunk's rows are
+    worked on or skipped together, so a chunk must not end AT the load
+    the share expects (an even spread's ``N * top_k * held /
+    num_experts``), or one slot more doubles the row work.
 
     ``tally()`` is what was routed here since construction: the compiled
     step adds ``held + 1`` integers to a small buffer, and the
@@ -410,8 +433,13 @@ class SparseMoEBlock(Layer):
                  expert_offset=0, experts_held=None,
                  routed_scaling_factor=1.0, expert_bias=None,
                  weight_attr=None, down_attr=None, name=None,
-                 norm_eps=1e-6):
+                 norm_eps=1e-6, scoring="sigmoid", train_router=True,
+                 slots_at_a_time=None):
         super().__init__()
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax'")
+        self.scoring, self.train_router = scoring, bool(train_router)
+        self.slots_at_a_time = slots_at_a_time
         held = num_experts - expert_offset if experts_held is None \
             else experts_held
         if not (0 <= expert_offset and 0 < held
@@ -518,6 +546,9 @@ class SparseMoEBlock(Layer):
             functools.partial(sparse_moe, top_k=self.top_k,
                               expert_offset=self.expert_offset,
                               scaling=self.routed_scaling_factor,
-                              norm_eps=self.norm_eps),
+                              norm_eps=self.norm_eps,
+                              scoring=self.scoring,
+                              train_router=self.train_router,
+                              slots_at_a_time=self.slots_at_a_time),
             x, self.gate.weight, self.w1, self.w3, self.w2,
             bias=self.expert_bias)

@@ -21,3 +21,5 @@ from . import deepseek_v3  # noqa: F401
 from .deepseek_v3 import DeepseekV3Config, DeepseekV3ForCausalLM  # noqa: F401
 from . import kimi_linear  # noqa: F401
 from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa: F401
+from . import mellum  # noqa: F401
+from .mellum import MellumConfig, MellumForCausalLM  # noqa: F401

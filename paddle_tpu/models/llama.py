@@ -61,11 +61,36 @@ def _glue_fusion() -> bool:
     return bool(state.get_flag("train_glue_fusion"))
 
 
-def rope_angles(positions, d, theta):
+def yarn_inv_freq(d, theta, factor, original_max_position_embeddings,
+                  beta_fast=32.0, beta_slow=1.0):
+    """YaRN's blended rotary frequencies, float64 [d // 2], as HF's
+    ``_compute_yarn_parameters``: pair ``i`` turns at the plain
+    ``theta ** (-2i / d)`` where it makes more than ``beta_fast`` turns
+    over the original context (extrapolation), at that divided by
+    ``factor`` where it makes fewer than ``beta_slow`` (interpolation),
+    and at a linear blend between."""
+    import math
+
+    import numpy as np
+    extra = 1.0 / theta ** (np.arange(0, d // 2) * 2.0 / d)
+
+    def pair_of(turns):     # the pair that makes ``turns`` turns
+        return d * math.log(original_max_position_embeddings
+                            / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return extra / factor * ramp + extra * (1 - ramp)
+
+
+def rope_angles(positions, d, theta, inv_freq=None, scale=1.0):
     """Half-rotation rope tables: (cos, sin) [..., d] for ``positions``
     (numpy or traced jnp values). SINGLE home of the LLaMA rope
     convention — the training path (_rope_tables) and the KV-cache decode
-    path (generation.rope_at) both read it.
+    path (generation.rope_at) both read it.  ``inv_freq`` [d // 2]
+    replaces the plain ``theta ** (-2i / d)`` (``yarn_inv_freq``) and
+    ``scale`` multiplies cos and sin alike (YaRN's ``attention_factor``).
 
     Concrete positions compute in float64 (f32 loses ~1e-4 rad at
     position 2048 — enough to drift checkpoints); traced positions (the
@@ -75,15 +100,19 @@ def rope_angles(positions, d, theta):
     import jax.numpy as jnp
     import numpy as np
     if not isinstance(positions, jax.core.Tracer):
-        inv = 1.0 / theta ** (np.arange(0, d // 2) * 2.0 / d)
+        inv = (1.0 / theta ** (np.arange(0, d // 2) * 2.0 / d)
+               if inv_freq is None else np.asarray(inv_freq, np.float64))
         ang = np.asarray(positions, np.float64)[..., None] * inv
         ang = np.concatenate([ang, ang], axis=-1)
-        return (jnp.asarray(np.cos(ang), jnp.float32),
-                jnp.asarray(np.sin(ang), jnp.float32))
-    inv = 1.0 / theta ** (jnp.arange(0, d // 2) * 2.0 / d)
+        return (jnp.asarray(np.cos(ang) * scale, jnp.float32),
+                jnp.asarray(np.sin(ang) * scale, jnp.float32))
+    inv = (1.0 / theta ** (jnp.arange(0, d // 2) * 2.0 / d)
+           if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     ang = positions[..., None].astype(jnp.float32) * inv
     ang = jnp.concatenate([ang, ang], axis=-1)
-    return jnp.cos(ang), jnp.sin(ang)
+    if scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
 class LlamaAttention(Layer):

@@ -1,14 +1,21 @@
-"""The half of a decoder that ``models/deepseek_v3.py`` and
-``models/kimi_linear.py`` share: a layer ``h = h + operator(RMSNorm(h));
-h = h + feed_forward(RMSNorm(h))`` whose operator is its family's and
-whose feed-forward is
+"""The half of a decoder that ``models/deepseek_v3.py``,
+``models/kimi_linear.py`` and ``models/mellum.py`` share: a layer ``h =
+h + operator(RMSNorm(h)); h = h + feed_forward(RMSNorm(h))`` whose
+operator is its family's and whose feed-forward is
 
-* a dense SwiGLU MLP in the leading ``first_k_dense_replace`` layers;
-* after them the dropless sigmoid-routed ``SparseMoEBlock``
-  (``incubate/distributed/models/moe.py``), which holds
+* a dense SwiGLU MLP in the leading ``first_k_dense_replace`` layers
+  (``deepseek_v3`` and ``kimi_linear`` lead with one; ``mellum`` has
+  none, ``first_k_dense_replace`` 0);
+* after them the dropless ``SparseMoEBlock``
+  (``incubate/distributed/models/moe.py``; a family's ``routed_block``,
+  where it has one, is what it asks of the block beyond the fields
+  below: ``deepseek_v3`` and ``kimi_linear`` have none and take its
+  sigmoid scores, ``mellum`` a softmax over all the experts), which holds
   ``experts_held`` of the router's ``n_routed_experts`` from
   ``expert_offset`` on (one chip's share under expert parallelism),
-  PLUS a shared expert: one SwiGLU of ``n_shared_experts *
+  PLUS, where ``n_shared_experts`` is not 0 (``deepseek_v3`` 2,
+  ``kimi_linear`` 1; ``mellum`` has none and builds none), a shared
+  expert: one SwiGLU of ``n_shared_experts *
   moe_intermediate_size`` that every token passes.  The shared expert
   lives here and not in the block: under expert parallelism every chip
   computes it alike, and a sum over the chips' shares counts it once;
@@ -68,9 +75,11 @@ class SparseDecoderLayer(Layer):
                 routed_scaling_factor=cfg.routed_scaling_factor,
                 expert_bias=biases[at] if at < len(biases) else None,
                 weight_attr=init(), down_attr=init(out_std(cfg)),
-                name=f"layer_{index}", norm_eps=cfg.router_norm_eps)
-            self.shared_expert = mlp(
-                cfg.n_shared_experts * cfg.moe_intermediate_size)
+                name=f"layer_{index}", norm_eps=cfg.router_norm_eps,
+                **getattr(cfg, "routed_block", {}))
+            if cfg.n_shared_experts:
+                self.shared_expert = mlp(
+                    cfg.n_shared_experts * cfg.moe_intermediate_size)
         else:
             self.mlp = mlp(cfg.intermediate_size)
         self._recompute = cfg.recompute
@@ -83,8 +92,10 @@ class SparseDecoderLayer(Layer):
         if not self.is_sparse:
             return x + self.mlp(f)
         # this chip's part of the routed experts' result, and the shared
-        # expert whole
+        # expert whole where the family has one
         part, *counts = self.routed_experts(f)
+        if not hasattr(self, "shared_expert"):
+            return (x + part, *counts)
         return (x + part + self.shared_expert(f), *counts)
 
     def forward(self, x):

@@ -29,7 +29,7 @@ def _dropout_probs(probs, dropout, key):
 
 
 def _sdpa_xla(q, k, v, mask=None, dropout=0.0, causal=False, scale=None,
-              dropout_key=None):
+              dropout_key=None, window=None):
     # q,k,v: [B, S, H, D] (paddle layout) -> compute in [B, H, S, D]
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
@@ -49,6 +49,8 @@ def _sdpa_xla(q, k, v, mask=None, dropout=0.0, causal=False, scale=None,
         idx_q = jnp.arange(q_len)[:, None] + (k_len - q_len)
         idx_k = jnp.arange(k_len)[None, :]
         cmask = idx_q >= idx_k
+        if window is not None:
+            cmask = cmask & (idx_q - idx_k < window)
         logits = jnp.where(cmask, logits, -jnp.inf)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -66,16 +68,23 @@ def _sdpa_xla(q, k, v, mask=None, dropout=0.0, causal=False, scale=None,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None, backend=None):
+                                 training=True, name=None, backend=None,
+                                 window=None):
     """paddle.nn.functional.scaled_dot_product_attention parity
     (layout [batch, seq, num_heads, head_dim]).
 
     ``backend`` (extension over the reference signature): None = auto
     (Pallas flash attention on TPU when eligible), "xla" forces the
-    unfused fallback, "pallas" requires the flash kernel."""
+    unfused fallback, "pallas" requires the flash kernel.
+    ``window`` (extension; with ``is_causal`` only): sliding-window
+    attention, query ``i`` sees the ``window`` keys ``i - window < j <=
+    i``; the flash kernels and the fallback take it alike."""
     if backend not in (None, "xla", "pallas"):
         raise ValueError(
             f"backend must be None, 'xla' or 'pallas'; got {backend!r}")
+    if window is not None and (not is_causal or window < 1):
+        raise ValueError(f"window={window!r} needs is_causal=True and at "
+                         f"least one key")
     args = [query, key, value]
     has_mask = attn_mask is not None
     if has_mask:
@@ -101,9 +110,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                   (backend is None and _use_pallas(q) and eligible))
         if use_pl:
             from ...ops.pallas import flash_attention as fa
-            return fa.flash_attention(q, k, v, causal=is_causal)
+            return fa.flash_attention(q, k, v, causal=is_causal,
+                                      window=window)
         return _sdpa_xla(q, k, v, mask=m, dropout=drop, causal=is_causal,
-                         dropout_key=dk)
+                         dropout_key=dk, window=window)
 
     return apply("scaled_dot_product_attention", impl, *args)
 

@@ -27,6 +27,15 @@ Capability analog of the reference FlashAttention-2 integration
   ``_fwd_k_blocks`` / ``_fwd_masks`` / ``_bwd_tile_kinds`` decide it for
   the kernels, the twin and the ``flash.tiles{kernel, kind, shape}``
   gauges of the ``observability`` registry alike;
+* a sliding ``window`` (causal calls only: query ``i`` sees the
+  ``window`` keys ``i - window < j <= i``) gives the band a lower edge:
+  the forward's k loop STARTS at the first block that holds a visible
+  key (``_fwd_k_first``), and the backward's grid walks, for each k
+  block, only the q blocks of that block's band (``_bwd_q_first`` /
+  ``_bwd_q_steps``), masking the tiles either edge crosses.  A windowed
+  call's two kernels are named ``flash_window_fwd`` / ``flash_window_bwd``
+  in a device trace; ``window=None`` and a window no shorter than the
+  keys build the programs they built before there was one;
 * grouped-query attention maps q-head -> kv-head in the BlockSpec index map
   (no materialized ``repeat`` of K/V, unlike the XLA fallback);
 * backward recomputes the softmax from the saved logsumexp (flash-attn
@@ -75,8 +84,9 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 _LANE = 8  # trailing lane width for per-row stats (Mosaic tile alignment)
 
 
-def _block_sizes(sq, sk, causal):
-    """Default forward (block_q, block_k): 512 x 512 whatever ``causal``.
+def _block_sizes(sq, sk, causal, window=None):
+    """Default forward (block_q, block_k): 512 x 512 whatever ``causal``
+    (a windowed call's: ``_window_block_sizes``).
     Measured 2026-09-29 on one TPU v5e (PR 32; the kernel alone in a
     jitted scan of grad calls under the profiler, bfloat16, ms a call,
     this kernel / the one before the PR at 512 x 512):
@@ -91,7 +101,30 @@ def _block_sizes(sq, sk, causal):
     What did NOT pay there: a mask-free loop over the tiles wholly
     under the diagonal beside the masked one made the forward 11%
     (1,024 positions) and 4% (8,192) slower, so it has one body."""
+    if window is not None:
+        return _window_block_sizes(sq, sk)[0]
     return min(512, sq), min(512, sk)
+
+
+def _window_block_sizes(sq, sk):
+    """A windowed call's default ((forward block_q, block_k), (backward
+    block_q, block_k)): 512 x 512 both.  A q block of ``bq`` rows sees a
+    band of ``bq + window - 1`` keys, so a tile's masked share grows
+    with the blocks while the per-block costs of ``_block_sizes`` fall
+    with them.  Measured 2026-10-02 on one TPU v5e (PR 42; as in
+    ``_block_sizes``, bfloat16, ms a call) at 1 x 32/4 x 8192 x 128, a
+    window of 1024.  Forward: 512 x 512 2.32, 256 x 512 2.32, 256 x 1024
+    2.89, 512 x 1024 2.95, 1024 x 1024 2.95, 1024 x 512 2.98, 128 x 512
+    3.06, 512 x 256 3.21, 256 x 256 3.23 (the plain causal call at that
+    shape 5.21: a band of 0.234 of its pairs in 0.45 of its time; at
+    512 x 512 a q block visits three key blocks of which two are half
+    behind an edge).  Backward (the forward at 512 x 512 taken out):
+    512 x 512 5.24, 256 x 512 5.92, 512 x 256 6.40, 1024 x 512 6.46,
+    256 x 1024 6.59, 512 x 1024 6.63, 256 x 256 6.74, 1024 x 1024 6.76,
+    1024 x 256 7.34 (plain causal at 1024 x 1024: 10.77).  The plain
+    backward's 1024 x 1024 loses here: two of its every two tiles are
+    crossed by an edge."""
+    return ((min(512, sq), min(512, sk)), (min(512, sq), min(512, sk)))
 
 
 def _pad_to(x, axis, mult):
@@ -129,6 +162,16 @@ def _fwd_k_blocks(iq, *, causal, sq, sk, bq, bk):
     return xp.clip(((iq + 1) * bq + (sk - sq) + bk - 1) // bk, 0, nk)
 
 
+def _fwd_k_first(iq, *, window, sq, sk, bq, bk):
+    """The first k block q block ``iq`` visits: the one that holds the
+    oldest key the block's FIRST row sees through the window (0 without
+    a window); the blocks before it lie wholly behind the window."""
+    if window is None:
+        return 0 if not isinstance(iq, np.ndarray) else np.zeros_like(iq)
+    xp = np if isinstance(iq, np.ndarray) else jnp
+    return xp.clip((iq * bq + (sk - sq) - window + 1) // bk, 0, -(-sk // bk))
+
+
 def _fwd_masks(*, causal, has_seg, sk, bk):
     """Whether the forward's tile body builds a mask: it does when some
     tile of the call can need one (the diagonal, a padded k tail,
@@ -138,17 +181,25 @@ def _fwd_masks(*, causal, has_seg, sk, bk):
     return bool(causal or has_seg or sk % bk)
 
 
-def _bwd_tile_kinds(ik, iq, *, causal, has_seg, sq, sk, bq, bk):
-    """(plain, masked) for the backward's (k block, q block) grid step;
-    a step that is neither is skipped.  Each is ``True`` / ``False``
-    where the shapes alone decide it for every step of the call."""
+def _bwd_tile_kinds(ik, iq, *, causal, has_seg, sq, sk, bq, bk,
+                    window=None):
+    """(plain, masked) for the backward's (k block, q block) tile; a
+    tile that is neither is skipped: it lies wholly above the diagonal
+    or wholly behind the ``window``.  Each is ``True`` / ``False``
+    where the shapes alone decide it for every tile of the call."""
     offset = sk - sq
     # the tile's last row sees its first column
     active = ((iq + 1) * bq - 1 + offset >= ik * bk) if causal else True
+    if window is not None:
+        # the tile's first row still sees its last column
+        active = active & (iq * bq + offset - (ik + 1) * bk + 1 < window)
     if has_seg:
         return False, active
     # the tile's first row sees its last column
     plain = ((ik + 1) * bk - 1 <= iq * bq + offset) if causal else True
+    if window is not None:
+        # the tile's last row still sees its first column
+        plain = plain & ((iq + 1) * bq - 1 + offset - ik * bk < window)
     if sq % bq:
         plain = plain & ((iq + 1) * bq <= sq)
     if sk % bk:
@@ -158,22 +209,45 @@ def _bwd_tile_kinds(ik, iq, *, causal, has_seg, sq, sk, bq, bk):
     return plain, active & ~plain
 
 
-def _shape_sig(q_shape, sk, causal, dv=None):
+def _bwd_q_first(ik, *, sq, sk, bq, bk):
+    """The first q block of k block ``ik``'s band in a windowed call's
+    backward: the one that holds the first row that sees the block's
+    first column."""
+    xp = np if isinstance(ik, np.ndarray) else jnp
+    return xp.maximum((ik * bk - (sk - sq)) // bq, 0)
+
+
+def _bwd_q_steps(*, window, sq, sk, bq, bk):
+    """How many q blocks a windowed call's backward walks for each k
+    block: the most that any k block's band of ``bk + window - 1`` rows
+    touches."""
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    first = _bwd_q_first(np.arange(nk), sq=sq, sk=sk, bq=bq, bk=bk)
+    last = (np.arange(1, nk + 1) * bk + window - 2 - (sk - sq)) // bq
+    return int(np.clip(np.minimum(last, nq - 1) - first + 1, 1, nq).max())
+
+
+def _shape_sig(q_shape, sk, causal, dv=None, window=None):
     """A call's shape as the autotune cache and the gauges key it; the
-    values' width ``dv`` is named only where it is not the keys'."""
+    values' width ``dv`` is named only where it is not the keys', the
+    ``window`` only where there is one: a windowed and a plain call of
+    one shape share neither a tuned entry nor a counter series."""
     b, h, sq, d = q_shape
     width = f"d{d}" if dv in (None, d) else f"d{d}v{dv}"
-    return f"b{b}h{h}sq{sq}sk{sk}{width}c{int(causal)}"
+    band = "" if window is None else f"w{window}"
+    return f"b{b}h{h}sq{sq}sk{sk}{width}c{int(causal)}{band}"
 
 
 def _publish_tiles(kernel, q_shape, dv, sk, causal, has_seg, blocks,
-                   **kinds):
+                   window=None, **kinds):
     """Gauges ``flash.tiles{kernel, kind, shape}``: the tiles of each
     kind one call of this program shape runs (batch x heads x a row's),
-    set while the call is traced — static counts, nothing in the step."""
+    set while the call is traced — static counts, nothing in the step.
+    ``skipped`` are the tiles no product is made for: those above the
+    diagonal and those behind the window."""
     from ...observability import metrics
-    shape = (f"{_shape_sig(q_shape, sk, causal, dv)}s{int(has_seg)}"
-             f".{blocks[0]}x{blocks[1]}")
+    shape = (f"{_shape_sig(q_shape, sk, causal, dv, window)}"
+             f"s{int(has_seg)}.{blocks[0]}x{blocks[1]}")
     for kind, n in kinds.items():
         metrics.registry().gauge(
             "flash.tiles",
@@ -186,7 +260,7 @@ def _publish_tiles(kernel, q_shape, dv, sk, causal, has_seg, blocks,
 # forward
 # --------------------------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
-                sq, sk, bq, bk):
+                sq, sk, bq, bk, window=None):
     """One (batch, q-head, q-block) program: stream k/v blocks with online
     softmax. Block shapes: q [1,1,bq,D]; k [1,1,Skp,D]; v [1,1,Skp,Dv];
     o [1,1,bq,Dv]; lse [1,1,bq,LANE] (Mosaic needs the trailing dims
@@ -207,6 +281,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
     q = _scaled(q_ref[0, 0], scale)                    # [bq, D]
     offset = sk - sq                                   # causal diagonal shift
     hi = _fwd_k_blocks(iq, causal=causal, sq=sq, sk=sk, bq=bq, bk=bk)
+    lo = _fwd_k_first(iq, window=window, sq=sq, sk=sk, bq=bq, bk=bk)
     masked = _fwd_masks(causal=causal, has_seg=has_seg, sk=sk, bk=bk)
 
     def body(j, carry):
@@ -222,6 +297,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
             mask = cols < sk                           # k padding
             if causal:
                 mask = mask & (rows + offset >= cols)
+            if window is not None:
+                mask = mask & (rows + offset - cols < window)
             if has_seg:
                 qs = qs_ref[0]                         # [bq, 1]
                 ks = ks_ref[0, :, pl.ds(j * bk, bk)]   # [1, bk]
@@ -239,14 +316,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, has_seg,
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
     a0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
-    m_f, l_f, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, a0))
+    m_f, l_f, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
 
     l_safe = jnp.where(l_f == 0.0, 1.0, l_f)           # padded q rows
     o_ref[0, 0] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[0, 0] = jnp.broadcast_to(m_f + jnp.log(l_safe), (bq, _LANE))
 
 
-def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
+def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None,
+         window=None):
     """q [B,Hq,Sq,D]; k [B,Hk,Sk,D]; v [B,Hk,Sk,Dv]; seg_q/seg_k
     optional [B,Sq]/[B,Sk] int32 segment ids -> (o [B,Hq,Sq,Dv], lse
     [B,Hq,Sq])."""
@@ -255,22 +333,26 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
     rep = hq // hk
     has_seg = seg_q is not None
     bq, bk = (blocks if blocks is not None
-              else _block_sizes(sq, sk, causal))
+              else _block_sizes(sq, sk, causal, window))
     bq, bk = min(bq, sq), min(bk, sk)
     qp = _pad_to(q, 2, bq)
     kp = _pad_to(k, 2, bk)
     vp = _pad_to(v, 2, bk)
     sqp, skp = qp.shape[2], kp.shape[2]
     grid = (b, hq, sqp // bq)
-    ran = _fwd_k_blocks(np.arange(sqp // bq), causal=causal, sq=sq, sk=sk,
-                        bq=bq, bk=bk).sum()
+    geom = dict(sq=sq, sk=sk, bq=bq, bk=bk)
+    blocks_q = np.arange(sqp // bq)
+    ran = np.maximum(
+        _fwd_k_blocks(blocks_q, causal=causal, **geom)
+        - _fwd_k_first(blocks_q, window=window, **geom), 0).sum()
     masked = _fwd_masks(causal=causal, has_seg=has_seg, sk=sk, bk=bk)
     _publish_tiles("fwd", q.shape, dv, sk, causal, has_seg, (bq, bk),
+                   window,
                    plain=0 if masked else ran, masked=ran if masked else 0,
                    skipped=(sqp // bq) * (skp // bk) - ran)
 
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk)
+                               has_seg=has_seg, window=window, **geom)
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq: (ib, ih, iq, 0)),
         pl.BlockSpec((1, 1, skp, d),
@@ -300,7 +382,7 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
             jax.ShapeDtypeStruct((b, hq, sqp, _LANE), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_attention_fwd",
+        name="flash_attention_fwd" if window is None else "flash_window_fwd",
     )(*args)
     return o[:, :, :sq], lse[:, :, :sq, 0]
 
@@ -308,9 +390,10 @@ def _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks=None):
 # --------------------------------------------------------------------------
 # backward
 # --------------------------------------------------------------------------
-def _bwd_block_sizes(sq, sk, causal):
+def _bwd_block_sizes(sq, sk, causal, window=None):
     """Default backward (block_q, block_k): 1024 x 1024 whatever
-    ``causal``.  Measured as in ``_block_sizes`` (ms a call, bfloat16):
+    ``causal`` (a windowed call's: ``_window_block_sizes``).  Measured
+    as in ``_block_sizes`` (ms a call, bfloat16):
     causal 8 x 16 x 1024 x 64: 1024 x 1024 (one tile a row) 0.773,
     1024 x 512 0.972, 512 x 1024 0.977, 512 x 512 1.061, 256 x 512
     1.399, 256 x 256 1.748 (before the PR, 512 x 512: 1.186); causal
@@ -322,6 +405,8 @@ def _bwd_block_sizes(sq, sk, causal):
     512, 120 of a row's 256 are skipped ones) and more independent work
     for the scheduler inside a step.  The whole-row q/do/dq buffers are
     there regardless of the pair (``_bwd_vmem_limit``)."""
+    if window is not None:
+        return _window_block_sizes(sq, sk)[1]
     return min(1024, sq), min(1024, sk)
 
 
@@ -340,7 +425,8 @@ def _bwd_vmem_limit(sqp, d, itemsize, bq, bk, dv=None):
     128 MiB); 6.5 KB a position at 192-wide keys and 128-wide values
     (q, dq and the accumulator padded to 256 lanes), 54.5 MB at 8192.
     Beside the rows, room for a tile's s, p, dp and ds: 32 bytes a score,
-    ``_TILE_VMEM`` up to 512 x 512."""
+    ``_TILE_VMEM`` up to 512 x 512.  A window changes neither: the rows
+    stay whole and a tile is a tile."""
     def lanes(n):
         return -(-n // 128) * 128
     dv = d if dv is None else dv
@@ -353,7 +439,7 @@ def _bwd_vmem_limit(sqp, d, itemsize, bq, bk, dv=None):
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *refs, scale, causal, has_seg, sq, sk, bq, bk,
-                      nq, nk):
+                      nq, nk, window=None, steps=None):
     """One (batch, q-head, k-block, q-block) tile of the FUSED backward.
 
     The grid's two inner dims walk k-blocks (outer) x q-blocks (inner);
@@ -374,6 +460,14 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     never-attending rows still produce zeros.  The five products take
     q, k, v, do as the refs hold them and ``p`` / ``ds`` cast to that
     dtype, and accumulate in float32.
+
+    With a ``window`` the grid's last dim has ``steps`` steps, not
+    ``nq``: for each k block it walks the q blocks of that block's band
+    from ``_bwd_q_first`` on, so no grid step is spent on a tile behind
+    the window (at 8,192 positions and a window of 1,024 they would be
+    four fifths of the steps).  A q block then meets its k blocks at
+    steps the shapes do not fix, so the dq rows are zeroed whole in the
+    program's first step and flushed whole in its last.
     """
     if has_seg:
         qs_ref, ks_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc \
@@ -384,16 +478,31 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ik = pl.program_id(2)
     iq = pl.program_id(3)
     offset = sk - sq
+    if window is not None:
+        step, last_step = iq, steps - 1
+        iq = _bwd_q_first(ik, sq=sq, sk=sk, bq=bq, bk=bk) + step
+        inside = iq < nq        # the band of the last k blocks ends early
+        iq = jnp.minimum(iq, nq - 1)
+    else:
+        step, last_step = iq, nq - 1
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _zero_kv_acc():
         dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
         dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    @pl.when(ik == 0)
-    def _zero_dq_slice():
-        dq_acc[pl.ds(iq * bq, bq), :] = jnp.zeros(
-            (bq, dq_acc.shape[-1]), jnp.float32)
+    if window is None:
+        @pl.when(ik == 0)
+        def _zero_dq_slice():
+            dq_acc[pl.ds(iq * bq, bq), :] = jnp.zeros(
+                (bq, dq_acc.shape[-1]), jnp.float32)
+    else:
+        @pl.when((ik == 0) & (step == 0))
+        def _zero_dq_rows():
+            def zero(i, _):
+                dq_acc[pl.ds(i * bq, bq), :] = jnp.zeros(
+                    (bq, dq_acc.shape[-1]), jnp.float32)
+            jax.lax.fori_loop(0, nq, zero, None)
 
     dt = q_ref.dtype                                   # the products' operands
 
@@ -414,6 +523,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             mask = (cols < sk) & (rows < sq)
             if causal:
                 mask = mask & (rows + offset >= cols)
+            if window is not None:
+                mask = mask & (rows + offset - cols < window)
             if has_seg:
                 qs = qs_ref[0, pl.ds(iq * bq, bq), :]  # [bq, 1]
                 ks = ks_ref[0, :, pl.ds(ik * bk, bk)]  # [1, bk]
@@ -443,39 +554,52 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    # a grid step is plain (wholly under the diagonal, inside sq and sk,
-    # no segment ids: no iota, compare or where), masked, or skipped
-    # (wholly above the diagonal); a kind no step of this call can have
-    # is decided here and its body is not emitted
+    # a grid step is plain (wholly under the diagonal, within the
+    # window, inside sq and sk, no segment ids: no iota, compare or
+    # where), masked, or skipped (wholly above the diagonal or behind
+    # the window); a kind no step of this call can have is decided here
+    # and its body is not emitted
     plain, masked = _bwd_tile_kinds(ik, iq, causal=causal, has_seg=has_seg,
-                                    sq=sq, sk=sk, bq=bq, bk=bk)
+                                    sq=sq, sk=sk, bq=bq, bk=bk,
+                                    window=window)
     for kind, is_masked in ((plain, False), (masked, True)):
+        if window is not None and kind is not False:
+            kind = inside & kind
         if kind is True:
             tile(is_masked)
         elif kind is not False:
             pl.when(kind)(functools.partial(tile, is_masked))
 
-    # flush dq once this q row's LAST attending k block has run. hi can
-    # be <= 0 for rows that attend nothing (sq > sk rectangles): clamp
-    # to 1 so the zeroed slice still flushes at ik == 0.
-    if causal:
-        hi = jnp.minimum(nk, ((iq + 1) * bq + offset + bk - 1) // bk)
-        hi = jnp.maximum(hi, 1)
+    if window is not None:
+        @pl.when((ik == nk - 1) & (step == last_step))
+        def _flush_dq_rows():
+            def flush(i, _):
+                dq_ref[0, 0, pl.ds(i * bq, bq), :] = \
+                    (dq_acc[pl.ds(i * bq, bq), :] * scale).astype(
+                        dq_ref.dtype)
+            jax.lax.fori_loop(0, nq, flush, None)
     else:
-        hi = nk
+        # flush dq once this q row's LAST attending k block has run. hi
+        # can be <= 0 for rows that attend nothing (sq > sk rectangles):
+        # clamp to 1 so the zeroed slice still flushes at ik == 0.
+        if causal:
+            hi = jnp.minimum(nk, ((iq + 1) * bq + offset + bk - 1) // bk)
+            hi = jnp.maximum(hi, 1)
+        else:
+            hi = nk
 
-    @pl.when(ik == hi - 1)
-    def _flush_dq():
-        dq_ref[0, 0, pl.ds(iq * bq, bq), :] = \
-            (dq_acc[pl.ds(iq * bq, bq), :] * scale).astype(dq_ref.dtype)
+        @pl.when(ik == hi - 1)
+        def _flush_dq():
+            dq_ref[0, 0, pl.ds(iq * bq, bq), :] = \
+                (dq_acc[pl.ds(iq * bq, bq), :] * scale).astype(dq_ref.dtype)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == last_step)
     def _flush_kv():
         dk_ref[0, 0] = (dk_acc[...].T * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].T.astype(dv_ref.dtype)
 
 
-def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
+def _bwd(scale, causal, interpret, blocks, bwd_blocks, window, res, g):
     q, k, v, seg_q, seg_k, o, lse = res
     do = g
     b, hq, sq, d = q.shape
@@ -487,7 +611,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     # gets the pre-split behavior of one pair driving both directions
     bq, bk = (bwd_blocks if bwd_blocks is not None
               else blocks if blocks is not None
-              else _bwd_block_sizes(sq, sk, causal))
+              else _bwd_block_sizes(sq, sk, causal, window))
     bq, bk = min(bq, sq), min(bk, sk)
 
     # delta_i = rowsum(dO * O): the FA2 precompute — one fused XLA reduce
@@ -508,9 +632,13 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     # ONE fused kernel; grid (b, hq, k-blocks, q-blocks). dk/dv come out
     # per q head (B*Hq programs write disjoint slices) and are summed
     # over the GQA group afterwards.
+    geom = dict(sq=sq, sk=sk, bq=bq, bk=bk)
+    # the q blocks the grid walks for each k block: all of them, or with
+    # a window those of the k block's band
+    steps = nq if window is None else _bwd_q_steps(window=window, **geom)
     kernel = functools.partial(_bwd_fused_kernel, scale=scale,
-                               causal=causal, has_seg=has_seg, sq=sq,
-                               sk=sk, bq=bq, bk=bk, nq=nq, nk=nk)
+                               causal=causal, has_seg=has_seg, **geom,
+                               nq=nq, nk=nk, window=window, steps=steps)
 
     def kv_spec(width):
         return pl.BlockSpec(
@@ -541,16 +669,16 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
     plain, masked = (
         np.broadcast_to(kind, (nk, nq)).sum() for kind in _bwd_tile_kinds(
             np.arange(nk)[:, None], np.arange(nq)[None, :], causal=causal,
-            has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk))
+            has_seg=has_seg, window=window, **geom))
     _publish_tiles("bwd", q.shape, dv, sk, causal, has_seg, (bq, bk),
-                   plain=plain, masked=masked,
+                   window, plain=plain, masked=masked,
                    skipped=nk * nq - plain - masked)
     # dq leaves in q's dtype; dk and dv too unless a GQA group is summed
     # outside, which wants them float32 until that sum
     kv_dtype = k.dtype if rep == 1 else jnp.float32
     dqh, dkh, dvh = pl.pallas_call(
         kernel,
-        grid=(b, hq, nk, nq),
+        grid=(b, hq, nk, steps),
         in_specs=in_specs,
         out_specs=[row_spec(d), kv_out_spec(d), kv_out_spec(dv)],
         out_shape=[
@@ -564,7 +692,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
             pltpu.VMEM((dv, bk), jnp.float32),   # dv accumulator, transposed
         ],
         interpret=interpret,
-        name="flash_attention_bwd",
+        name="flash_attention_bwd" if window is None else "flash_window_bwd",
         **({} if limit is None else {
             "compiler_params": pltpu.CompilerParams(
                 vmem_limit_bytes=limit)}),
@@ -577,7 +705,7 @@ def _bwd(scale, causal, interpret, blocks, bwd_blocks, res, g):
 
 
 def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
-                            segment_ids=None, blocks=None):
+                            segment_ids=None, blocks=None, window=None):
     """UNJITTED jnp twin of the fused Pallas backward (the
     ``quant_matmul_jnp`` parity contract).
 
@@ -596,6 +724,7 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     scale = float(scale)
+    window = _window(window, causal, k.shape[1])
     seg_q = seg_k = None
     if segment_ids is not None:
         if isinstance(segment_ids, (tuple, list)):
@@ -614,7 +743,7 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
     rep = hq // hk
     has_seg = seg_q is not None
     bq, bk = (blocks if blocks is not None
-              else _bwd_block_sizes(sq, sk, causal))
+              else _bwd_block_sizes(sq, sk, causal, window))
     bq, bk = min(bq, sq), min(bk, sk)
     dt = q.dtype
 
@@ -649,7 +778,8 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
                 for iq in range(nq):
                     plain, masked = _bwd_tile_kinds(
                         np.asarray(ik), np.asarray(iq), causal=causal,
-                        has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk)
+                        has_seg=has_seg, sq=sq, sk=sk, bq=bq, bk=bk,
+                        window=window)
                     if not (plain or masked):
                         continue
                     qb = qp[ib, ih, iq * bq:(iq + 1) * bq]
@@ -668,6 +798,8 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
                         mask = (cols < sk) & (rows < sq)
                         if causal:
                             mask = mask & (rows + offset >= cols)
+                        if window is not None:
+                            mask = mask & (rows + offset - cols < window)
                         if has_seg:
                             qs = qsp[ib, iq * bq:(iq + 1) * bq]
                             ks = ksp[ib, ik * bk:(ik + 1) * bk]
@@ -704,16 +836,32 @@ def flash_attention_bwd_jnp(q, k, v, do, o, lse, scale=None, causal=False,
 # --------------------------------------------------------------------------
 # public API
 # --------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _window(window, causal, sk):
+    """A call's window as the kernels take it: None where there is none
+    or where it is no shorter than the keys (every key a causal row can
+    see is then within it: the plain causal programs)."""
+    if window is None:
+        return None
+    if not causal:
+        raise ValueError("flash_attention: a window is a lower edge under "
+                         "the causal diagonal; it needs causal=True")
+    if window < 1:
+        raise ValueError(f"flash_attention: a window of {window} keys")
+    return None if window >= sk else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash_bhsd(q, k, v, seg_q, seg_k, scale, causal, interpret,
-                blocks=None, bwd_blocks=None):
-    o, _ = _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks)
+                blocks=None, bwd_blocks=None, window=None):
+    o, _ = _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks,
+                window)
     return o
 
 
 def _flash_fwd_rule(q, k, v, seg_q, seg_k, scale, causal, interpret,
-                    blocks=None, bwd_blocks=None):
-    o, lse = _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks)
+                    blocks=None, bwd_blocks=None, window=None):
+    o, lse = _fwd(q, k, v, seg_q, seg_k, scale, causal, interpret, blocks,
+                  window)
     return o, (q, k, v, seg_q, seg_k, o, lse)
 
 
@@ -758,7 +906,7 @@ def _scan_slope(make_runner, args, r1=4, r2=24):
 
 
 def _tuned_entry(entry, candidates, qt, kt, vt, causal, make_runner,
-                 validate):
+                 validate, window=None):
     """Shared cache-probe / sweep / fallback protocol for both flash
     autotune entries. Under a trace (tracer inputs) only cache HITS
     apply — the shapes are static so the key is known; the measuring
@@ -772,7 +920,7 @@ def _tuned_entry(entry, candidates, qt, kt, vt, causal, make_runner,
     cands = [c for c in candidates if c[0] <= sq and c[1] <= sk]
     if len(cands) <= 1:
         return None
-    sig = _shape_sig(qt.shape, sk, causal, vt.shape[-1])
+    sig = _shape_sig(qt.shape, sk, causal, vt.shape[-1], window)
     cached = at._load_cache().get(f"{at._device_kind()}|{entry}|{sig}")
     if cached is not None:
         for c in cands:
@@ -799,7 +947,7 @@ def _tuned_entry(entry, candidates, qt, kt, vt, causal, make_runner,
         return None
 
 
-def _autotuned_blocks(qt, kt, scale, causal, vt=None):
+def _autotuned_blocks(qt, kt, scale, causal, vt=None, window=None):
     """FORWARD block-size selection through the autotune cache (SURVEY
     C14; see autotune.py). The backward tunes separately
     (``_autotuned_bwd_blocks``) — its fused kernel has different VMEM
@@ -812,7 +960,7 @@ def _autotuned_blocks(qt, kt, scale, causal, vt=None):
             def body(c, i):
                 o = _flash_bhsd(a + i.astype(a.dtype) * 1e-6, bb, cc,
                                 None, None, scale, causal, False,
-                                _cand)
+                                _cand, None, window)
                 return c + o.astype(a.dtype), None
             z = jnp.zeros((*a.shape[:-1], cc.shape[-1]), a.dtype)
             return jax.lax.scan(body, z, jnp.arange(_n))[0]
@@ -824,14 +972,15 @@ def _autotuned_blocks(qt, kt, scale, causal, vt=None):
         # context — a scoped-vmem overflow disqualifies the candidate
         # and the next-best wins.
         o = _flash_bhsd(qt, kt, vt, None, None, scale, causal, False,
-                        tuple(cand))
+                        tuple(cand), None, window)
         float(jax.device_get(o.ravel()[0]))  # force execution
 
     return _tuned_entry("flash_attention", _TUNE_CANDIDATES, qt, kt, vt,
-                        causal, make_runner, validate)
+                        causal, make_runner, validate, window)
 
 
-def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks, vt=None):
+def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks, vt=None,
+                          window=None):
     """BACKWARD block-size selection: its own ``flash_attention_bwd``
     autotune entry over backward-specific candidates
     (``_TUNE_BWD_CANDIDATES``). The timed program is the full fwd+bwd chain
@@ -844,7 +993,7 @@ def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks, vt=None):
         grad = jax.grad(
             lambda a, bb, cc, _cand=tuple(cand): _flash_bhsd(
                 a, bb, cc, None, None, scale, causal, False,
-                fwd_blocks, _cand).astype(jnp.float32).sum(),
+                fwd_blocks, _cand, window).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))
 
         def chained(a, bb, cc, _n=reps):
@@ -869,16 +1018,17 @@ def _autotuned_bwd_blocks(qt, kt, scale, causal, fwd_blocks, vt=None):
         def f(a, bb, cc):
             return _flash_bhsd(
                 a, bb, cc, None, None, scale, causal, False, fwd_blocks,
-                tuple(cand)).astype(jnp.float32).sum()
+                tuple(cand), window).astype(jnp.float32).sum()
         grads = jax.grad(f, argnums=(0, 1, 2))(qt, kt, vt)
         float(jax.device_get(grads[0].ravel()[0]))  # force execution
 
     return _tuned_entry("flash_attention_bwd", _TUNE_BWD_CANDIDATES,
-                        qt, kt, vt, causal, make_runner, validate)
+                        qt, kt, vt, causal, make_runner, validate, window)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
-                    blocks=None, segment_ids=None, bwd_blocks=None):
+                    blocks=None, segment_ids=None, bwd_blocks=None,
+                    window=None):
     """Flash attention in paddle layout [batch, seq, num_heads, head_dim].
 
     ``num_heads(q)`` may be a multiple of ``num_heads(k) == num_heads(v)``
@@ -899,7 +1049,14 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
     [batch, seq] (shared q/kv when lengths match) or a pair
     ``(q_seg [B,Sq], kv_seg [B,Sk])``; attention is confined to positions
     with equal segment id, composing with ``causal``.
+    ``window``: sliding-window attention (HF ``sliding_window``), with
+    ``causal=True`` only: query ``i`` sees the ``window`` keys ``j`` with
+    ``i - window < j <= i``, itself among them (positions counted from
+    the END of the keys where ``seq_q != seq_k``, as the diagonal is).
+    The kernels visit no tile wholly behind the window; a window no
+    shorter than the keys is the plain causal call.
     """
+    window = _window(window, causal, k.shape[1])
     if interpret is None:
         from . import use_interpret
         interpret = use_interpret()
@@ -943,10 +1100,11 @@ def flash_attention(q, k, v, causal=False, scale=None, interpret=None,
             # backward through _bwd's fallback chain)
             if blocks is None:
                 blocks = _autotuned_blocks(qt, kt, float(scale),
-                                           bool(causal), vt)
+                                           bool(causal), vt, window)
                 if bwd_blocks is None:
                     bwd_blocks = _autotuned_bwd_blocks(
-                        qt, kt, float(scale), bool(causal), blocks, vt)
+                        qt, kt, float(scale), bool(causal), blocks, vt,
+                        window)
     o = _flash_bhsd(qt, kt, vt, seg_q, seg_k, float(scale), bool(causal),
-                    bool(interpret), blocks, bwd_blocks)
+                    bool(interpret), blocks, bwd_blocks, window)
     return jnp.swapaxes(o, 1, 2)

@@ -317,6 +317,39 @@ def test_flash_attention_latent_8k_fwd_bwd_compiles(chip, heads):
     assert 54 << 20 < limit < 128 << 20
 
 
+def test_sliding_window_flash_pair_8k_compiles(chip):
+    """Mellum2's sliding-window layer at the benchmark's shape: 32 query
+    heads on 4 key/value heads of 128, 8192 positions, a window of 1024,
+    one row.  The two kernels carry their own names (the accepted
+    ``flash_attention_roofline.train`` reads the full layers alone), the
+    backward's grid walks a k block's band and not the row, and its
+    whole-row buffers ask for the VMEM the plain call's ask for."""
+    q, kv = chip((1, 8192, 32, 128), BF16), chip((1, 8192, 4, 128), BF16)
+
+    def grads(window):
+        def fn(q, k, v):
+            return jax.grad(
+                lambda q, k, v: FA.flash_attention(
+                    q, k, v, causal=True, window=window,
+                    interpret=False).astype(F32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+        return fn
+
+    text = chip.compile(grads(1024), q, kv, kv).as_text()
+    assert "flash_window_fwd" in text and "flash_window_bwd" in text
+    assert "flash_attention_fwd" not in text
+    assert "flash_attention_bwd" not in text
+    # a window no shorter than the keys is the plain causal pair
+    text = chip.compile(grads(8192), q, kv, kv).as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "flash_window_fwd" not in text
+    assert "flash_window_bwd" not in text
+    bq, bk = FA._bwd_block_sizes(8192, 8192, True, 1024)
+    assert FA._bwd_q_steps(window=1024, sq=8192, sk=8192, bq=bq, bk=bk) \
+        == (bk + 1024 - 2) // bq + 1 < 8192 // bq
+    assert FA._bwd_vmem_limit(8192, 128, 2, bq, bk) > 37 << 20
+
+
 @pytest.mark.parametrize("chunk", [64, 128])
 def test_kda_chunk_fwd_bwd_compiles(chip, chunk):
     """The gated delta rule's two kernels at Kimi-Linear-48B-A3B's shape
