@@ -102,7 +102,10 @@ def _gated_delta(q, k, v, f, b, a_log, dt_bias, heads, chunk):
     which hands every op its inputs in the compute type, the gate and
     its running sums are made in float32 from the projections: the
     gate here, the running sums inside ``F.kda_chunk``'s chunk kernels
-    from the float32 ``g`` it is handed."""
+    from the float32 ``g`` it is handed.  All of it stays [B, S, H * d]
+    as projected (``A_log`` repeated over its head's channels): the
+    four dimensions ``F.kda_chunk`` takes and returns are reshapes that
+    cancel against its own."""
 
     def impl(q, k, v, f, b, a_log, dt_bias):
         import jax
@@ -110,29 +113,30 @@ def _gated_delta(q, k, v, f, b, a_log, dt_bias, heads, chunk):
         shape = (*q.shape[:2], heads, -1)
         with _scope.phase("decay_gate"):
             f32 = jnp.float32
-            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
-                (f.astype(f32) + dt_bias.astype(f32)).reshape(shape))
+            decay = jnp.repeat(-jnp.exp(a_log.astype(f32)),
+                               q.shape[-1] // heads)
+            g = decay * jax.nn.softplus(f.astype(f32) + dt_bias.astype(f32))
             beta = jax.nn.sigmoid(b.astype(f32))
         with _scope.phase("kda_chunk"):
-            return F.kda_chunk.raw(q.reshape(shape), k.reshape(shape),
-                                   v.reshape(shape), g, beta, chunk=chunk)
+            return F.kda_chunk.raw(
+                q.reshape(shape), k.reshape(shape), v.reshape(shape),
+                g.reshape(shape), beta, chunk=chunk).reshape(q.shape)
 
     return apply("gated_delta_attention", impl, q, k, v, f, b, a_log,
                  dt_bias)
 
 
-def _gated_norm(o, weight, gate, eps):
-    """Per head ``RMSNorm(o) * weight * sigmoid(gate)``: ``o`` [B, S, H,
-    d], ``gate`` [B, S, H * d], ``weight`` [d]; float32 inside."""
+def _gated_norm(o, weight, gate, heads, eps):
+    """Per head ``RMSNorm(o) * weight * sigmoid(gate)``: ``o`` and
+    ``gate`` [B, S, H * d], as the operator and the projection write
+    them, ``weight`` [d]; float32 inside
+    (``ops/pallas/kda.py`` ``gated_head_norm``: the per-head mean and
+    the ``rsqrt``'s way back over the head are products with a 0/1
+    indicator, so that no [B, S, H, d] is ever laid out)."""
 
     def impl(o, w, gate):
-        import jax
-        import jax.numpy as jnp
-        x = o.astype(jnp.float32)
-        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-        x = x * w.astype(jnp.float32) * jax.nn.sigmoid(
-            gate.astype(jnp.float32).reshape(o.shape))
-        return x.astype(o.dtype).reshape(*o.shape[:2], -1)
+        from ..ops.pallas import kda
+        return kda.gated_head_norm(o, w, gate, heads, eps)
 
     return apply("gated_rms_norm", impl, o, weight, gate)
 
@@ -180,7 +184,7 @@ class KimiDeltaAttention(Layer):
                          cfg.num_heads, cfg.kda_chunk)
         with _scope.phase("out_gate_norm"):
             o = _gated_norm(o, self.o_norm, self.g_b(self.g_a(x)),
-                            cfg.norm_eps)
+                            cfg.num_heads, cfg.norm_eps)
         return self.o_proj(o)
 
 

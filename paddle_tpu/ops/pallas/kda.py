@@ -71,9 +71,14 @@ is a second whole forward sweep, a third of the operator's work, for
 memory a step has to spare.
 
 On a TPU v5e at [1, 8192, 32, 128] bfloat16 with a float32 decay, the
-operator with its glue, forward / forward + backward: chunk 32 14.04 /
-36.58 ms, 64 11.38 / 31.51, 128 9.68 / 27.12 (2026-10-01, PERF.md
-PR 38).  The shifted adds are 0.08 ms of the forward kernel's 7.77 at
+operator with its glue as it then was (reductions over the last of four
+axes), forward / forward + backward: chunk 32 14.04 / 36.58 ms, 64
+11.38 / 31.51, 128 9.68 / 27.12 (2026-10-01, PERF.md PR 38); with the
+glue in the flat layout (below), the operator at chunk 128 with the
+layer's decay gate and gated norm, forward + recompute + backward under
+the cell's recompute policy: 43.6 -> 30.1 ms (2026-10-03, PERF.md
+PR 43, read while a recompute still kept the spreads; the kernels are
+19.6 of either).  The shifted adds are 0.08 ms of the forward kernel's 7.77 at
 chunk 128 and 0.10 of the backward's 11.91, where XLA's two window
 scans take 1.31 ms a layer; as one product with the triangle of ones,
 the decay cut into three bfloat16 parts, the same sums cost the
@@ -81,12 +86,21 @@ kernels 0.35 + 0.74 ms, at ``HIGHEST`` 0.46 + 1.07.  The body is
 bound by its column-at-a-time score loops and its chain of small
 float32 products, not by the MXU or the memory.
 
-The L2 normalisation of q and k, the scale and the folding of ``b``
-into k and v are plain jnp round the core (``kda_chunk`` below) and
-left to autodiff.  Each per-head reduction over the 128 lanes there
-costs a layout copy of the whole tensor: the kernels read [B, S, H *
-d] (8 positions to a tile), XLA reduces [B, S, H, d] with the heads
-on the sublanes (PERF.md, PR 38).
+**The glue round the core stays in the kernels' layout.**  The L2
+normalisation of q and k, the scale and the folding of ``b`` into k and
+v (``_fold``), and the layer's gated output norm (``gated_head_norm``),
+are jnp on [B, S, H * d] as the projections write it and the kernels
+read it (8 positions to a tile).  A sum over a head's ``d`` channels is
+a product with the 0/1 indicator ``E`` [H * d, H] (``head_sum``), a
+per-head value spread back over them a product with its transpose
+(``head_spread``), float32 at ``HIGHEST`` (the indicator is exact in
+every part of the split).  Reduced over the last of four axes instead,
+XLA lays [B, S, H, d] out with the HEADS on the sublanes and pays a
+copy of the whole float32 tensor in and a reshape out of every
+reduction, forward, recompute and backward (PERF.md, PR 38 and PR 43).
+Both carry a hand-written backward whose residuals are the operands
+and the [B, S, H] statistics: a spread left to autodiff is a 134 MB
+product that a recompute policy which keeps products saves.
 """
 from __future__ import annotations
 
@@ -516,9 +530,146 @@ def _core_bwd(chunk, how, res, do):
 _core.defvjp(_core_fwd, _core_bwd)
 
 
-def _l2(x):
-    x = x.astype(_F32)
-    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+# --------------------------------------------------------------------------
+# the glue round the core, in the kernels' own [B, S, H * d] layout
+# --------------------------------------------------------------------------
+def _head_of(width, heads):
+    """``E`` [H * d, H] float32, 1 where a channel is its head's: a sum
+    over a head's channels is a product with it, a per-head value spread
+    over them a product with its transpose."""
+    return (_iota((width, heads), 0) // (width // heads)
+            == _iota((width, heads), 1)).astype(_F32)
+
+
+def head_sum(x, heads):
+    """[B, S, H * d] float32 -> [B, S, H]: each head's sum over its ``d``
+    channels, float32 (``HIGHEST``: the indicator is exact in any part
+    of a split), with the positions left on the sublanes."""
+    return lax.dot_general(
+        x, _head_of(x.shape[-1], heads), (((2,), (0,)), ((), ())),
+        precision=lax.Precision.HIGHEST, preferred_element_type=_F32)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def head_spread(r, width):
+    """[B, S, H] float32 -> [B, S, H * d]: a head's value on each of its
+    channels, exactly.
+
+    A ``custom_jvp`` (it is linear: its tangent is itself) so that
+    inside the hand-written rules below, which nothing differentiates,
+    it stays ONE operation under its own name.  Left a bare
+    ``dot_general``, a recompute policy that keeps products keeps this
+    one wherever a value made from it is wanted again in the backward
+    (the core's operands, the gated norm's result): 128 copies of what
+    [B, S, H] says, four 134 MB float32 residuals a layer at the cell's
+    shape (PERF.md, PR 43)."""
+    return lax.dot_general(
+        r, _head_of(width, r.shape[-1]), (((2,), (1,)), ((), ())),
+        precision=lax.Precision.HIGHEST, preferred_element_type=_F32)
+
+
+@head_spread.defjvp
+def _head_spread_jvp(width, primals, tangents):
+    return head_spread(*primals, width), head_spread(*tangents, width)
+
+
+def _l2_bwd(dxn, xn, r, heads):
+    """The gradient of ``x`` through ``xn = x * r``, ``r = rsqrt(sum_head
+    x^2 + eps)`` spread over the head, from that of ``xn``."""
+    return r * (dxn - xn * head_spread(head_sum(dxn * xn, heads),
+                                       xn.shape[-1]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _fold(q, k, v, beta, scale, cd):
+    """The core's operands from the caller's, all [B, S, H * d] (``beta``
+    [B, S, H]): q and k L2-normalised per head, q times ``scale``,
+    ``beta`` folded into k and v; float32 inside, the results in ``cd``.
+
+    Its own ``custom_vjp`` so that what a backward keeps is q, k, v,
+    beta and the two [B, S, H] norms: left to autodiff, each spread (a
+    product) is a 134 MB float32 residual that a recompute policy which
+    keeps products would save."""
+    return _fold_fwd(q, k, v, beta, scale, cd)[0]
+
+
+def _beta_spread(beta, k, v):
+    """``beta`` on k's channels and on v's (one product where the two
+    widths are one)."""
+    b = beta.astype(_F32)
+    bk = head_spread(b, k.shape[-1])
+    return bk, bk if v.shape[-1] == k.shape[-1] else head_spread(
+        b, v.shape[-1])
+
+
+def _fold_fwd(q, k, v, beta, scale, cd):
+    heads, width = beta.shape[-1], q.shape[-1]
+    q32, k32 = q.astype(_F32), k.astype(_F32)
+    rq = lax.rsqrt(head_sum(q32 * q32, heads) + L2_EPS)
+    rk = lax.rsqrt(head_sum(k32 * k32, heads) + L2_EPS)
+    kn = k32 * head_spread(rk, width)
+    bk, bv = _beta_spread(beta, k, v)
+    out = ((q32 * head_spread(rq, width) * scale).astype(cd), kn.astype(cd),
+           (kn * bk).astype(cd), (v.astype(_F32) * bv).astype(cd))
+    return out, (q, k, v, beta, rq, rk)
+
+
+def _fold_bwd(scale, cd, res, grads):
+    q, k, v, beta, rq, rk = res
+    heads, width = beta.shape[-1], q.shape[-1]
+    dqn, dkn, dkb, dvb = (x.astype(_F32) for x in grads)
+    rq, rk = head_spread(rq, width), head_spread(rk, width)
+    bk, bv = _beta_spread(beta, k, v)
+    kn = k.astype(_F32) * rk
+    by_k, by_v = dkb * kn, dvb * v.astype(_F32)
+    dbeta = (head_sum(by_k + by_v, heads) if bk is bv
+             else head_sum(by_k, heads) + head_sum(by_v, heads))
+    dq = _l2_bwd(dqn * scale, q.astype(_F32) * rq, rq, heads)
+    dk = _l2_bwd(dkn + dkb * bk, kn, rk, heads)
+    return (dq.astype(q.dtype), dk.astype(k.dtype),
+            (dvb * bv).astype(v.dtype), dbeta.astype(beta.dtype))
+
+
+_fold.defvjp(_fold_fwd, _fold_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def gated_head_norm(o, weight, gate, heads, eps):
+    """Per head ``RMSNorm(o) * weight * sigmoid(gate)``, the KDA layer's
+    output norm: ``o`` and ``gate`` [B, S, H * d], ``weight`` [d];
+    float32 inside, the result in o's dtype.  A ``custom_vjp`` for
+    ``_fold``'s reason: the backward keeps o, the gate and the [B, S,
+    H] ``rsqrt``, not its spread."""
+    return _gated_head_norm_fwd(o, weight, gate, heads, eps)[0]
+
+
+def _gated_head_norm_fwd(o, weight, gate, heads, eps):
+    x = o.astype(_F32)
+    r = lax.rsqrt(head_sum(x * x, heads) / weight.shape[0] + eps)
+    y = (x * head_spread(r, x.shape[-1])
+         * jnp.tile(weight.astype(_F32), heads)
+         * jax.nn.sigmoid(gate.astype(_F32)))
+    return y.astype(o.dtype), (o, weight, gate, r)
+
+
+def _gated_head_norm_bwd(heads, eps, res, dy):
+    o, weight, gate, r = res
+    d = weight.shape[0]
+    r = head_spread(r, o.shape[-1])
+    xn = o.astype(_F32) * r
+    s = jax.nn.sigmoid(gate.astype(_F32))
+    w = jnp.tile(weight.astype(_F32), heads)
+    dy = dy.astype(_F32)
+    t = dy * xn
+    dxn = dy * w * s
+    dx = r * (dxn - xn * head_spread(head_sum(dxn * xn, heads) / d,
+                                     xn.shape[-1]))
+    dw = jnp.sum(t * s, axis=(0, 1)).reshape(heads, d).sum(0)
+    return (dx.astype(o.dtype), dw.astype(weight.dtype),
+            (t * w * s * (1.0 - s)).astype(gate.dtype))
+
+
+gated_head_norm.defvjp(_gated_head_norm_fwd, _gated_head_norm_bwd)
 
 
 def kda_chunk(q, k, v, g, beta, *, chunk=None, scale=None, how=None):
@@ -552,19 +703,17 @@ def kda_chunk(q, k, v, g, beta, *, chunk=None, scale=None, how=None):
         raise ValueError(
             f"kda_chunk: v {tuple(v.shape)} and beta {tuple(beta.shape)} "
             f"must match q {tuple(q.shape)} in batch, positions and heads")
-    cd = q.dtype
     if scale is None:
         scale = 1.0 / math.sqrt(dk)
-    bf = beta.astype(_F32)[..., None]
-    kn = _l2(k)
     pad = -s % chunk
 
-    def padded(x):
+    def by_head(x):
         # k = 0 and g = 0: the state passes a padded position unchanged
-        # (the running sum of 0 is flat)
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
+        # (the running sum of 0 is flat).  Four dimensions again for the
+        # core, which flattens them: the two reshapes cancel
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
+        return x.reshape(b, s + pad, h, -1)
 
-    return _core(padded((_l2(q) * scale).astype(cd)), padded(kn.astype(cd)),
-                 padded((kn * bf).astype(cd)),
-                 padded((v.astype(_F32) * bf).astype(cd)),
-                 padded(g.astype(_F32)), chunk, how)[:, :s]
+    folded = _fold(_flat(q), _flat(k), _flat(v), beta, scale, q.dtype)
+    return _core(*(by_head(x) for x in folded),
+                 by_head(_flat(g).astype(_F32)), chunk, how)[:, :s]

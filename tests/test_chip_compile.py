@@ -12,6 +12,8 @@ and these tests stay in ONE file, because only one process may load the
 TPU's library.  Shapes are GPT-124M's (12 heads, head_dim 64) and the
 8B-class GQA geometry (32/8 heads, head_dim 128).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -350,22 +352,36 @@ def test_sliding_window_flash_pair_8k_compiles(chip):
     assert FA._bwd_vmem_limit(8192, 128, 2, bq, bk) > 37 << 20
 
 
+# a 134 MB float32 tensor of the KDA layer laid out again: heads on the
+# sublanes ([B, S, H, d] as XLA tiles it) where the projections and the
+# kernels hold 8 positions to a tile ([B, S, H * d])
+def _retiled(text):
+    """The entry computation's own copies, reshapes and broadcasts of
+    that size (inside a fusion a broadcast writes nothing)."""
+    return re.findall(
+        r"= f32\[(?:1024,8,32,128|1,8192,4096|8192,32,128)\]\S* "
+        r"(?:copy|reshape|broadcast)\(", text[text.index("\nENTRY "):])
+
+
 @pytest.mark.parametrize("chunk", [64, 128])
 def test_kda_chunk_fwd_bwd_compiles(chip, chunk):
     """The gated delta rule's two kernels at Kimi-Linear-48B-A3B's shape
     (32 heads of 128, 8192 positions, one row, bfloat16 operands and a
-    float32 decay): the sub-blocks' single-row slices, the transposed
-    products, the ``HIGHEST`` products of the triangular inverse and the
-    blocks cut from the [B, S, H * d] layout all pass the chip's
-    compiler; chunk 128 is the configuration's."""
+    float32 decay, [B, S, H * d] as the projections write them and four
+    dimensions by a reshape): the sub-blocks' single-row slices, the
+    transposed products, the ``HIGHEST`` products of the triangular
+    inverse and the blocks cut from the [B, S, H * d] layout all pass
+    the chip's compiler; chunk 128 is the configuration's."""
     from paddle_tpu.ops.pallas import kda
-    x = chip((1, 8192, 32, 128), BF16)
-    g, beta = chip((1, 8192, 32, 128), F32), chip((1, 8192, 32), F32)
+    x = chip((1, 8192, 32 * 128), BF16)
+    g, beta = chip((1, 8192, 32 * 128), F32), chip((1, 8192, 32), F32)
 
     def grads(q, k, v, g, beta):
-        return jax.grad(
-            lambda *a: kda.kda_chunk(*a, chunk=chunk, how="pallas").astype(
-                F32).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+        def loss(q, k, v, g, beta):
+            q, k, v, g = (a.reshape(1, 8192, 32, 128) for a in (q, k, v, g))
+            return kda.kda_chunk(q, k, v, g, beta, chunk=chunk,
+                                 how="pallas").astype(F32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
 
     compiled = chip.compile(grads, x, x, x, g, beta)
     text = compiled.as_text()
@@ -374,9 +390,54 @@ def test_kda_chunk_fwd_bwd_compiles(chip, chunk):
     # scan and no decay cut [B, S / chunk, chunk, H, d] for one
     assert "reduce-window" not in text
     assert f"f32[1,{8192 // chunk},{chunk},32,128]" not in text
+    # the per-head sums and spreads round the kernels are products on
+    # [B, S, H * d] (PR 43): nothing is laid out again for one (the
+    # parent held 6 copies, 6 reshapes and 6 broadcasts here)
+    assert not _retiled(text)
     assert [o.shape for o in compiled.out_info] == [
         x.shape, x.shape, x.shape, g.shape, beta.shape]
     assert [o.dtype for o in compiled.out_info] == [BF16] * 3 + [F32] * 2
+
+
+def test_kimi_delta_attention_fwd_bwd_compiles(chip, monkeypatch):
+    """``KimiDeltaAttention`` at the fourth cell's widths (hidden 2304,
+    32 heads of 128, one row of 8192, chunk 128), forward + backward
+    under the cell's recompute policy and AMP O2, as a pure function of
+    its input and parameters: round the two kernels no 134 MB float32
+    tensor is laid out again, in the forward, in the recompute or in
+    the backward, and the program holds no more than the parent's did
+    (``_archive/pr43_kda_glue_ops.py`` prints both sides)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet.pipeline import functional_call
+    from paddle_tpu.distributed.fleet.recompute import _POLICIES
+    from paddle_tpu.models.kimi_linear import (KimiDeltaAttention,
+                                               KimiLinearConfig)
+    layer = paddle.amp.decorate(KimiDeltaAttention(KimiLinearConfig(
+        hidden_size=2304, num_heads=32, kda_head_dim=128, kda_chunk=128)),
+        level="O2", dtype="bfloat16")
+    vals = {n: chip(p._data.shape, p._data.dtype)
+            for n, p in layer.named_parameters()}
+    # the kernels, not their XLA form and not interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def block(x, vals):
+        with paddle.amp.auto_cast(level="O2", dtype="bfloat16"):
+            return functional_call(layer, vals, x)
+
+    block = jax.checkpoint(
+        block, policy=_POLICIES["dots_and_kernels_saveable"])
+    compiled = chip.compile(
+        jax.grad(lambda x, vals: block(x, vals).astype(F32).sum(),
+                 argnums=(0, 1)), chip((1, 8192, 2304), BF16), vals)
+    text = compiled.as_text()
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    assert not _retiled(text)
+    # the parent's (PR 42's commit) read 2,291,551,232 here on 2026-10-03
+    # with 9 + 4 copies, 10 reshapes and 11 broadcasts, this tree
+    # 2,219,611,136.  One layer in one program cannot show what a
+    # policy keeps from forward to backward over a whole step:
+    # tests/test_kda.py holds the spreads out of the residuals
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2_291_551_232
 
 
 def test_sparse_moe_grouped_products_compile(chip):

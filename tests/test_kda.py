@@ -240,3 +240,138 @@ def test_the_chunks_gauge_is_set_while_a_kernel_call_is_traced():
     shape = "b2h2s48dk16dv16c16"
     want = {k for k in got if shape in str(k)}
     assert len(want) == 2 and all(got[k] == 2 * 2 * 3 for k in want)
+
+
+# ------------------------------------------- the glue in the flat layout
+def four_dimensional_chunk(q, k, v, g, beta, chunk):
+    """``kda_chunk``'s glue as it was written on [B, S, H, d], each
+    per-head reduction over the last axis: the witness of the flat form
+    (``K._fold``), round the same core."""
+    f32, cd = jnp.float32, q.dtype
+
+    def l2(x):
+        x = x.astype(f32)
+        return x * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + K.L2_EPS)
+
+    s = q.shape[1]
+    pad = -s % chunk
+    bf = beta.astype(f32)[..., None]
+    kn = l2(k)
+
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+
+    return K._core(
+        padded((l2(q) * q.shape[-1] ** -0.5).astype(cd)),
+        padded(kn.astype(cd)), padded((kn * bf).astype(cd)),
+        padded((v.astype(f32) * bf).astype(cd)), padded(g.astype(f32)),
+        chunk, "xla")[:, :s]
+
+
+def four_dimensional_norm(o, weight, gate, eps):
+    """The gated RMS norm as ``models/kimi_linear.py`` wrote it on
+    ``o`` [B, S, H, d]."""
+    x = o.astype(jnp.float32)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    x = x * weight.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32).reshape(o.shape))
+    return x.astype(o.dtype).reshape(*o.shape[:2], -1)
+
+
+# float32: two orders of one float32 sum.  bfloat16: both sides cast
+# the same float32 values but for their last bit, so an entry here and
+# there lands on the other side of a rounding (2^-8 of it)
+GLUE_TOL = {"float32": 1e-6, "bfloat16": 2 ** -7}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [2, 3])
+def test_the_flat_glue_is_the_four_dimensional_formulas(heads, dtype):
+    """L2 norms, scale and the ``beta`` fold as products with the 0/1
+    head indicator on [B, S, H * d], with their hand-written backward
+    (``dbeta`` and the norms' among it), against the reductions over
+    the last of four axes left to autodiff: the result and every
+    gradient, on a row (40) that is no multiple of the chunk (16)."""
+    args, w = operands(40, dv=32, seed=10, heads=heads)
+    low = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    got = value_and_grads(
+        lambda *a: K.kda_chunk(*a, chunk=16, how="xla").astype("f4"), low, w)
+    want = value_and_grads(
+        lambda *a: four_dimensional_chunk(*a, chunk=16).astype("f4"), low, w)
+    close(got[0], want[0], tol=GLUE_TOL[dtype])
+    for g, r, like in zip(got[1], want[1], low):
+        assert g.shape == like.shape and g.dtype == like.dtype
+        close(g.astype("f4"), r.astype("f4"), tol=GLUE_TOL[dtype])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [2, 3])
+def test_the_flat_gated_norm_is_the_four_dimensional_one(heads, dtype):
+    """``gated_head_norm`` on [B, S, H * d] against the norm over the
+    last of four axes: the result and the gradients of o, the weight and
+    the gate."""
+    d, eps = 16, 1e-5
+    rng = np.random.default_rng(11)
+    o, gate, w = (jnp.asarray(rng.standard_normal((2, 24, heads * d)), dtype)
+                  for _ in range(3))
+    weight = jnp.asarray(1 + 0.1 * rng.standard_normal(d), "f4")
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype("f4") * w.astype("f4")),
+            argnums=(0, 1, 2)))(o, weight, gate)
+
+    got = both(lambda o, wt, g: K.gated_head_norm(o, wt, g, heads, eps))
+    want = both(lambda o, wt, g: four_dimensional_norm(
+        o.reshape(2, 24, heads, d), wt, g, eps))
+    close(got[0], want[0], tol=GLUE_TOL[dtype])
+    for g, r, like in zip(got[1], want[1], (o, weight, gate)):
+        assert g.shape == like.shape and g.dtype == like.dtype
+        close(g.astype("f4"), r.astype("f4"), tol=GLUE_TOL[dtype])
+
+
+def test_the_head_sums_and_spreads_are_float32_and_exact():
+    """A spread is the value itself on each of the head's channels, a
+    sum each head's float32 sum: no bfloat16 pass."""
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.standard_normal((2, 8, 3 * 16)) * 1e3, "f4")
+    want = np.asarray(x, "f8").reshape(2, 8, 3, 16).sum(-1)
+    got = jax.jit(lambda x: K.head_sum(x, 3))(x)
+    assert got.dtype == jnp.float32
+    close(got, want, tol=1e-6)
+    r = jnp.asarray(rng.standard_normal((2, 8, 3)), "f4")
+    spread = jax.jit(lambda r: K.head_spread(r, 48))(r)
+    assert spread.dtype == jnp.float32
+    assert np.array_equal(np.asarray(spread),
+                          np.repeat(np.asarray(r), 16, axis=-1))
+
+
+def test_a_policy_that_keeps_products_keeps_no_spread(capsys):
+    """Under the fourth cell's recompute policy (products and kernel
+    results are kept, the rest is made again) the core's operands and
+    the gated norm's result are made again from the [B, S, H] sums, not
+    from their spreads: a spread seen as a product is a float32
+    [B, S, H * d] residual, four a layer."""
+    from jax.ad_checkpoint import print_saved_residuals
+
+    from paddle_tpu.distributed.fleet.recompute import _POLICIES
+    s, h, d = 32, 2, 16
+    rng = np.random.default_rng(13)
+
+    def layer(q, k, v, g, beta, gate, weight, proj):
+        q, k, v = (jnp.sin(x) for x in (q, k, v))       # made in the block
+        o = K.kda_chunk(*(x.reshape(1, s, h, d) for x in (q, k, v, g)), beta,
+                        chunk=16, how="xla").reshape(1, s, h * d)
+        return K.gated_head_norm(o, weight, gate, h, 1e-5) @ proj
+
+    flat = [jnp.asarray(rng.standard_normal((1, s, h * d)), "f4")
+            for _ in range(3)]
+    print_saved_residuals(
+        jax.checkpoint(layer, policy=_POLICIES["dots_and_kernels_saveable"]),
+        *flat, -jnp.ones((1, s, h * d)), jnp.full((1, s, h), 0.5),
+        flat[0], jnp.ones(d), jnp.ones((h * d, 8)))
+    made = [line for line in capsys.readouterr().out.splitlines()
+            if "from the argument" not in line]
+    assert len([m for m in made if m.startswith(f"f32[1,{s},{h}]")]) == 3
+    assert not [m for m in made if m.startswith(f"f32[1,{s},{h * d}]")], made
