@@ -23,3 +23,5 @@ from . import kimi_linear  # noqa: F401
 from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa: F401
 from . import mellum  # noqa: F401
 from .mellum import MellumConfig, MellumForCausalLM  # noqa: F401
+from . import laguna  # noqa: F401
+from .laguna import LagunaConfig, LagunaForCausalLM  # noqa: F401

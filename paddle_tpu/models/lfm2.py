@@ -111,19 +111,26 @@ class Lfm2ShortConv(Layer):
 def _rotate(q, k, cos, sin):
     """Half-rotation RoPE on [B, S, H, D] heads from float32 [S, D]
     tables (constants of the program, not operands AMP would cast).
+    Tables narrower than the head, [S, r], turn each head's first
+    ``r`` dimensions (the halves within THOSE) and pass the rest
+    through: HF's ``q_rot, q_pass`` (``laguna``'s full layers).
     Plain jnp that XLA fuses into its neighbours: the Pallas kernel
     behind ``fused_rotary_position_embedding`` holds a head's whole
     [S, D] row and both tables in VMEM, which 8192 positions outgrow."""
     c, s = cos[None, :, None, :], sin[None, :, None, :]
+    r = cos.shape[-1]
 
     def impl(qv, kv):
         import jax.numpy as jnp
 
         def rot(x):
-            x32 = x.astype(jnp.float32)
+            whole = r == x.shape[-1]
+            x32 = (x if whole else x[..., :r]).astype(jnp.float32)
             x1, x2 = jnp.split(x32, 2, axis=-1)
             turned = jnp.concatenate([-x2, x1], axis=-1)
-            return (x32 * c + turned * s).astype(x.dtype)
+            out = (x32 * c + turned * s).astype(x.dtype)
+            return out if whole else jnp.concatenate(
+                [out, x[..., r:]], axis=-1)
 
         return rot(qv), rot(kv)
 

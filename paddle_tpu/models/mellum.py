@@ -30,6 +30,12 @@ Used as ``DeepseekV3ForCausalLM`` is: ``amp.decorate`` O2, ``AdamW``,
 one ``jit.to_static`` step, ``recompute`` per block.  It trains; the
 serving engine's page tables are one per model, not per layer type, and
 its paged kernel has no window bound, so ``generate`` does not take it.
+
+``MellumAttention`` and ``RopeTables`` are built by two families:
+``mellum`` (here: ``cfg.num_heads`` in every layer, the whole head
+rotated, no gate) and ``laguna`` (``models/laguna.py``: the layer's own
+head count, a layer type's ``partial_rotary_factor`` of the head
+rotated, ``gate=True``).
 """
 from __future__ import annotations
 
@@ -122,17 +128,29 @@ class MellumConfig:
 
 
 class RopeTables:
-    """(cos, sin) float32 [positions, head_dim] by layer type: made on
+    """(cos, sin) float32 [positions, width] by layer type: made on
     the host the first time a length is asked for and kept, so a stack
-    makes each of its two tables once, whatever its depth."""
+    makes each of its two tables once, whatever its depth.  A table is
+    as wide as the part of a head its layer type rotates
+    (``width(kind)``: ``head_dim`` times the group's
+    ``partial_rotary_factor``, 1 where it has none), and YaRN's
+    frequencies are blended over that width."""
 
-    def __init__(self, cfg: MellumConfig):
+    def __init__(self, cfg):
         self.cfg, self._made = cfg, {}
+
+    def width(self, kind):
+        d = self.cfg.head_dim
+        r = int(d * self.cfg.rope_parameters[kind].get(
+            "partial_rotary_factor", 1))
+        if r % 2 or not 0 < r <= d:
+            raise ValueError(f"RoPE turns halves of {r} of {d} dimensions")
+        return r
 
     def get(self, kind, positions):
         if (kind, positions) not in self._made:
             import numpy as np
-            d, p = self.cfg.head_dim, self.cfg.rope_parameters[kind]
+            d, p = self.width(kind), self.cfg.rope_parameters[kind]
             if p["rope_type"] == "default":
                 extra = {}
             elif p["rope_type"] == "yarn":
@@ -155,23 +173,34 @@ class RopeTables:
 
 class MellumAttention(Layer):
     """Grouped-query attention of one layer type (no biases, no
-    per-head norm)."""
+    per-head norm): ``num_heads`` query heads (``cfg.num_heads`` unless
+    given: a family whose head count differs by layer gives the
+    layer's), the layer type's table wide of each head rotated, and
+    with ``gate`` a per-head sigmoid gate on the attention's output,
+    ``o_proj(concat_h(sigmoid(g_proj(x))_h * A_h))``."""
 
-    def __init__(self, cfg: MellumConfig, kind: str, tables: RopeTables):
+    def __init__(self, cfg, kind: str, tables: RopeTables, num_heads=None,
+                 gate=False):
         super().__init__()
         h, d = cfg.hidden_size, cfg.head_dim
-        self.num_heads, self.num_kv_heads = cfg.num_heads, cfg.num_kv_heads
+        heads = cfg.num_heads if num_heads is None else num_heads
+        if heads % cfg.num_kv_heads:
+            raise ValueError(f"{heads} query heads over {cfg.num_kv_heads}")
+        self.num_heads, self.num_kv_heads = heads, cfg.num_kv_heads
         self.head_dim, self.kind, self._tables = d, kind, tables
         self.window = (cfg.sliding_window if kind == "sliding_attention"
                        else None)
         self.use_flash = cfg.use_flash_attention
-        self.q_proj = Linear(h, cfg.num_heads * d, bias_attr=False,
+        self.q_proj = Linear(h, heads * d, bias_attr=False,
                              weight_attr=init())
         self.k_proj = Linear(h, cfg.num_kv_heads * d, bias_attr=False,
                              weight_attr=init())
         self.v_proj = Linear(h, cfg.num_kv_heads * d, bias_attr=False,
                              weight_attr=init())
-        self.o_proj = Linear(cfg.num_heads * d, h, bias_attr=False,
+        if gate:
+            self.g_proj = Linear(h, heads, bias_attr=False,
+                                 weight_attr=init())
+        self.o_proj = Linear(heads * d, h, bias_attr=False,
                              weight_attr=init(out_std(cfg)))
 
     def forward(self, x):
@@ -189,6 +218,9 @@ class MellumAttention(Layer):
         out = F.scaled_dot_product_attention(
             q, k, v, is_causal=True, window=self.window,
             backend=None if self.use_flash else "xla")
+        if hasattr(self, "g_proj"):
+            with _scope.phase("out_gate"):
+                out = out * ops.unsqueeze(F.sigmoid(self.g_proj(x)), -1)
         return self.o_proj(ops.reshape(out, [b, s, -1]))
 
 

@@ -1,28 +1,36 @@
 """The half of a decoder that ``models/deepseek_v3.py``,
-``models/kimi_linear.py`` and ``models/mellum.py`` share: a layer ``h =
-h + operator(RMSNorm(h)); h = h + feed_forward(RMSNorm(h))`` whose
-operator is its family's and whose feed-forward is
+``models/kimi_linear.py``, ``models/mellum.py`` and ``models/laguna.py``
+share: a layer ``h = h + operator(RMSNorm(h)); h = h +
+feed_forward(RMSNorm(h))`` whose operator is its family's and whose
+feed-forward is
 
 * a dense SwiGLU MLP in the leading ``first_k_dense_replace`` layers
-  (``deepseek_v3`` and ``kimi_linear`` lead with one; ``mellum`` has
-  none, ``first_k_dense_replace`` 0);
+  (``deepseek_v3``, ``kimi_linear`` and ``laguna`` lead with one;
+  ``mellum`` has none, ``first_k_dense_replace`` 0);
 * after them the dropless ``SparseMoEBlock``
   (``incubate/distributed/models/moe.py``; a family's ``routed_block``,
   where it has one, is what it asks of the block beyond the fields
   below: ``deepseek_v3`` and ``kimi_linear`` have none and take its
-  sigmoid scores, ``mellum`` a softmax over all the experts), which holds
+  sigmoid scores, ``mellum`` a softmax over all the experts, ``laguna``
+  names the sigmoid and, as ``mellum``, a lone share's ``train_router``
+  and chunk), which holds
   ``experts_held`` of the router's ``n_routed_experts`` from
   ``expert_offset`` on (one chip's share under expert parallelism),
   PLUS, where ``n_shared_experts`` is not 0 (``deepseek_v3`` 2,
-  ``kimi_linear`` 1; ``mellum`` has none and builds none), a shared
-  expert: one SwiGLU of ``n_shared_experts *
+  ``kimi_linear`` and ``laguna`` 1; ``mellum`` has none and builds
+  none), a shared expert: one SwiGLU of ``n_shared_experts *
   moe_intermediate_size`` that every token passes.  The shared expert
   lives here and not in the block: under expert parallelism every chip
   computes it alike, and a sum over the chips' shares counts it once;
 
 and the stack round such layers: embedding, final RMSNorm, an untied
 head.  A family's config carries the fields read here under these
-names (``DeepseekV3Config`` has them all).
+names (``DeepseekV3Config`` has them all).  The operator a family
+gives a layer: ``deepseek_v3`` latent attention, ``kimi_linear`` KDA or
+un-rotated latent attention, ``mellum`` and ``laguna``
+``mellum.MellumAttention`` under the attribute ``window_attention`` or
+``full_attention`` (``laguna`` with the layer's own head count and a
+gate).
 """
 from __future__ import annotations
 

@@ -108,11 +108,20 @@ def _block_sizes(sq, sk, causal, window=None):
 
 def _window_block_sizes(sq, sk):
     """A windowed call's default ((forward block_q, block_k), (backward
-    block_q, block_k)): 512 x 512 both.  A q block of ``bq`` rows sees a
-    band of ``bq + window - 1`` keys, so a tile's masked share grows
-    with the blocks while the per-block costs of ``_block_sizes`` fall
-    with them.  Measured 2026-10-02 on one TPU v5e (PR 42; as in
-    ``_block_sizes``, bfloat16, ms a call) at 1 x 32/4 x 8192 x 128, a
+    block_q, block_k)), whatever its window.  A q block of ``bq`` rows
+    sees a band of ``bq + window - 1`` keys, so a tile's masked share
+    grows with the blocks while the per-block costs of ``_block_sizes``
+    fall with them.  THE RULE: 512 x 512 both, at every window: the
+    readings at 1,024 keys and at 512 chose the same pair but for the
+    forward's q block, where 256 x 512 ties at 1,024 and read 1.2-1.4%
+    under 512 x 512 at 512 keys (0.05 ms a call, 0.15 ms of a 370 ms
+    step: no step-level pair of runs could show it, so no fork); a key
+    block stays 512 wide however narrow the window, because halving it
+    cost more per block (the accumulator's rescale, the row statistics,
+    the grid step) than the masked area it saved, at both windows, and
+    the backward loses with either block halved.
+    Measured on one TPU v5e as in ``_block_sizes`` (bfloat16, ms a
+    call).  2026-10-02 (PR 42) at 1 x 32/4 x 8192 x 128, a
     window of 1024.  Forward: 512 x 512 2.32, 256 x 512 2.32, 256 x 1024
     2.89, 512 x 1024 2.95, 1024 x 1024 2.95, 1024 x 512 2.98, 128 x 512
     3.06, 512 x 256 3.21, 256 x 256 3.23 (the plain causal call at that
@@ -123,7 +132,22 @@ def _window_block_sizes(sq, sk):
     256 x 1024 6.59, 512 x 1024 6.63, 256 x 256 6.74, 1024 x 1024 6.76,
     1024 x 256 7.34 (plain causal at 1024 x 1024: 10.77).  The plain
     backward's 1024 x 1024 loses here: two of its every two tiles are
-    crossed by an edge."""
+    crossed by an edge.
+    2026-10-03 (PR 44) at 1 x 64/8 x 8192 x 128, a window of 512, where
+    at 512 x 512 a q block's band of 1,023 keys is exactly two key
+    blocks and an edge cuts both (31 tiles a head, none whole: twice
+    the band's pairs are visited, as at 256 x 512; at 256 x 256 one
+    tile in three is whole and 1.5 times are).  Forward: 256 x 512 3.82
+    (again, in turn with 512 x 512: 3.820 / 3.867, 3.833 / 3.874,
+    3.835 / 3.883), 512 x 512 3.88, 256 x 256 4.73, 128 x 512 4.84,
+    256 x 1024 4.94, 1024 x 512 5.03, 512 x 256 5.08, 128 x 256 6.35,
+    256 x 128 7.30, 128 x 128 9.03.  Backward (a forward at 256 x 512
+    taken out): 512 x 512 9.08, 256 x 512 9.99, 256 x 256 10.34,
+    512 x 256 10.60, 1024 x 512 11.19, 128 x 512 12.38, 256 x 128 12.70,
+    1024 x 256 12.71, 128 x 256 13.88 (the plain causal pair at 1 x 48/8
+    x 8192 x 128, its defaults: 7.88 and 16.16).  The band is 0.121 of
+    a causal layer's pairs and the pair takes 0.40 of a causal pair's
+    time a head.  A window under 512 keys has no reading."""
     return ((min(512, sq), min(512, sk)), (min(512, sq), min(512, sk)))
 
 

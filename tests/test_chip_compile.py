@@ -352,6 +352,44 @@ def test_sliding_window_flash_pair_8k_compiles(chip):
     assert FA._bwd_vmem_limit(8192, 128, 2, bq, bk) > 37 << 20
 
 
+def test_laguna_flash_pairs_8k_compile(chip):
+    """Laguna-XS.2's two attention calls at the benchmark's shapes, one
+    row of 8192 positions over 8 key/value heads of 128: the window
+    layers' 64 query heads (a group of 8) under a window of 512, at the
+    window path's defaults and at the narrower blocks the chip was read
+    at, and the full layers' 48 (a group of 6) through the plain
+    pair."""
+    kv = chip((1, 8192, 8, 128), BF16)
+
+    def grads(window, blocks=None):
+        def fn(q, k, v):
+            return jax.grad(
+                lambda q, k, v: FA.flash_attention(
+                    q, k, v, causal=True, window=window, blocks=blocks,
+                    bwd_blocks=blocks,
+                    interpret=False).astype(F32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+        return fn
+
+    q = chip((1, 8192, 64, 128), BF16)
+    for blocks in (None, (256, 512), (256, 256)):
+        text = chip.compile(grads(512, blocks), q, kv, kv).as_text()
+        assert "flash_window_fwd" in text and "flash_window_bwd" in text
+        assert "flash_attention_fwd" not in text
+    bq, bk = FA._bwd_block_sizes(8192, 8192, True, 512)
+    assert FA._bwd_q_steps(window=512, sq=8192, sk=8192, bq=bq, bk=bk) \
+        == (bk + 512 - 2) // bq + 1 < 8192 // bq
+    q = chip((1, 8192, 48, 128), BF16)
+    compiled = chip.compile(grads(None), q, kv, kv)
+    text = compiled.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "flash_window_fwd" not in text
+    dq, dk, dv = compiled.out_info
+    assert (dq.shape, dk.shape, dv.shape) == (q.shape, kv.shape, kv.shape)
+    assert FA._shape_sig((1, 64, 8192, 128), 8192, True, 128, 512) \
+        == "b1h64sq8192sk8192d128c1w512"
+
+
 # a 134 MB float32 tensor of the KDA layer laid out again: heads on the
 # sublanes ([B, S, H, d] as XLA tiles it) where the projections and the
 # kernels hold 8 positions to a tile ([B, S, H * d])

@@ -94,6 +94,16 @@ _CASES = [
     pytest.param(64, 64, 4, 1, 24, (16, 32), (32, 16), id="gqa-4-to-1"),
     pytest.param(50, 50, 2, 1, 9, (16, 16), (16, 16), id="gqa-padded-tail"),
     pytest.param(40, 64, 1, 1, 24, (16, 16), (16, 16), id="keys-longer"),
+    # Laguna's window of 512 keys at the blocks the chip was read at,
+    # a group of 6 (its full layers' 48 / 8, here under a window) and of
+    # 8 (its window layers' 64 / 8): at 512 x 512 an edge cuts every
+    # tile, at 256 x 256 one tile in three is whole
+    pytest.param(1024, 1024, 6, 1, 512, (512, 512), (512, 512),
+                 id="w512-blocks-512-group-6"),
+    pytest.param(1024, 1024, 8, 1, 512, (256, 256), (256, 256),
+                 id="w512-blocks-256-group-8"),
+    pytest.param(1024, 1024, 8, 1, 512, (256, 512), (512, 256),
+                 id="w512-unequal-group-8"),
 ]
 
 
@@ -242,6 +252,62 @@ def test_tile_gauges_skip_exactly_the_tiles_outside_the_band(
     assert not ((plain | masked) & ~visited).any()
     assert steps <= min((bk + window - 2) // bq + 2, visited.shape[1])
     assert steps < visited.shape[1] or sq < sk
+
+
+@pytest.mark.parametrize("heads,kv,window,blocks,plain,visited", [
+    # a q block's band of 1,023 keys touches exactly two key blocks of
+    # 512 and an edge crosses both: 31 tiles a head, none whole
+    pytest.param(64, 8, 512, (512, 512), 0, 31, id="w512-at-512-none-whole"),
+    # three tiles of 256 a q block, the middle one whole
+    pytest.param(64, 8, 512, (256, 256), 31, 93, id="w512-at-256"),
+    # Mellum2's window of 1,024: three tiles of 512, the middle one whole
+    pytest.param(32, 4, 1024, (512, 512), 15, 45, id="w1024-at-512"),
+])
+def test_the_gauges_of_a_traced_call_at_the_benchmarks_shapes(
+        heads, kv, window, blocks, plain, visited):
+    """``flash.tiles`` is set while a call is traced, so the shapes of
+    the chip are read here with nothing run: the window layer's call at
+    8,192 positions, by its label (heads and window in it)."""
+    from paddle_tpu.observability import metrics
+    q = jax.ShapeDtypeStruct((1, 8192, heads, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 8192, kv, 128), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda a, b, c: fa.flash_attention(
+        a, b, c, causal=True, window=window, interpret=True, blocks=blocks,
+        bwd_blocks=blocks).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        q, k, k)
+    shape = (f"b1h{heads}sq8192sk8192d128c1w{window}s0."
+             f"{blocks[0]}x{blocks[1]}")
+    got = {(kernel, kind): metrics.registry().gauge(
+        "flash.tiles", labels={"kernel": kernel, "kind": kind,
+                               "shape": shape}).value / heads
+        for kernel in ("fwd", "bwd") for kind in ("plain", "masked",
+                                                  "skipped")}
+    tiles = (8192 // blocks[0]) * (8192 // blocks[1])
+    assert got["bwd", "plain"] == plain
+    assert got["bwd", "masked"] == visited - plain
+    assert got["bwd", "skipped"] == got["fwd", "skipped"] == tiles - visited
+    assert (got["fwd", "plain"], got["fwd", "masked"]) == (0, visited)
+    # the score pairs the kernels visit against the band's
+    band = window * 8192 - window * (window - 1) // 2
+    ratio = visited * blocks[0] * blocks[1] / band
+    assert ratio == pytest.approx(
+        {(512, 512): 2.0, (512, 256): 1.5, (1024, 512): 1.5}[
+            window, blocks[0]], abs=0.01)
+
+
+def test_the_window_paths_default_blocks_are_one_pair_at_both_windows():
+    """The chip's readings at 1,024 keys (PR 42) and at 512 (PR 44)
+    chose one pair, 512 x 512 forward and backward
+    (``_window_block_sizes``): Mellum2's calls take what they took."""
+    for window in (1024, 512, 128):
+        assert fa._block_sizes(8192, 8192, True, window) == (512, 512)
+        assert fa._bwd_block_sizes(8192, 8192, True, window) == (512, 512)
+    assert fa._window_block_sizes(8192, 8192) == ((512, 512), (512, 512))
+    # a row shorter than a block is one block
+    assert fa._window_block_sizes(64, 64) == ((64, 64), (64, 64))
+    # the plain call's defaults do not move
+    assert fa._block_sizes(8192, 8192, True) == (512, 512)
+    assert fa._bwd_block_sizes(8192, 8192, True) == (1024, 1024)
 
 
 def test_a_windowed_and_a_plain_call_share_no_tuned_entry_and_no_series():
