@@ -114,9 +114,23 @@ def _rotate(q, k, cos, sin):
     Tables narrower than the head, [S, r], turn each head's first
     ``r`` dimensions (the halves within THOSE) and pass the rest
     through: HF's ``q_rot, q_pass`` (``laguna``'s full layers).
-    Plain jnp that XLA fuses into its neighbours: the Pallas kernel
-    behind ``fused_rotary_position_embedding`` holds a head's whole
-    [S, D] row and both tables in VMEM, which 8192 positions outgrow."""
+    In a ``to_static`` program captured on a TPU a head of 128 goes
+    through the position-tiled kernel ``ops/pallas/rope.py``
+    ``half_turn``, one pass over q and one over k forward and backward
+    (XLA makes float32 halves 64 lanes wide of the split and the
+    concatenation, and a recompute policy that keeps products and
+    kernels runs them again).  Any other width, any other backend, and
+    per-op dispatch (a program's eager first call: every call of a
+    kernel there is traced and lowered anew, 0.2 s each and 5.6 s of a
+    cell's set-up on the v5e, where these few elementwise programs are
+    made once) are the plain jnp form below."""
+    import jax
+    from ..ops.pallas import rope
+    if (q.shape[-1] == rope.HEAD and jax.default_backend() == "tpu"
+            and _scope.current() is not None):
+        return apply("rope", lambda qv, kv: (rope.half_turn(qv, cos, sin),
+                                             rope.half_turn(kv, cos, sin)),
+                     q, k)
     c, s = cos[None, :, None, :], sin[None, :, None, :]
     r = cos.shape[-1]
 

@@ -437,6 +437,34 @@ def test_kda_chunk_fwd_bwd_compiles(chip, chunk):
     assert [o.dtype for o in compiled.out_info] == [BF16] * 3 + [F32] * 2
 
 
+def _layer_step(chip, monkeypatch, layer, hidden):
+    """``layer`` (one operator of a decoder layer, AMP O2) forward +
+    recompute + backward at one row of 8192 under the cells' recompute
+    policy, as a pure function of its input and parameters, compiled
+    for the chip with the kernels steered on (not their XLA form and
+    not interpreted)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core import scope
+    from paddle_tpu.distributed.fleet.pipeline import functional_call
+    from paddle_tpu.distributed.fleet.recompute import _POLICIES
+    layer = paddle.amp.decorate(layer, level="O2", dtype="bfloat16")
+    vals = {n: chip(p._data.shape, p._data.dtype)
+            for n, p in layer.named_parameters()}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def block(x, vals):
+        # as a ``to_static`` step's replay: a captured program
+        with scope.capture(), paddle.amp.auto_cast(level="O2",
+                                                   dtype="bfloat16"):
+            return functional_call(layer, vals, x)
+
+    block = jax.checkpoint(
+        block, policy=_POLICIES["dots_and_kernels_saveable"])
+    return chip.compile(
+        jax.grad(lambda x, vals: block(x, vals).astype(F32).sum(),
+                 argnums=(0, 1)), chip((1, 8192, hidden), BF16), vals)
+
+
 def test_kimi_delta_attention_fwd_bwd_compiles(chip, monkeypatch):
     """``KimiDeltaAttention`` at the fourth cell's widths (hidden 2304,
     32 heads of 128, one row of 8192, chunk 128), forward + backward
@@ -445,28 +473,11 @@ def test_kimi_delta_attention_fwd_bwd_compiles(chip, monkeypatch):
     tensor is laid out again, in the forward, in the recompute or in
     the backward, and the program holds no more than the parent's did
     (``_archive/pr43_kda_glue_ops.py`` prints both sides)."""
-    import paddle_tpu as paddle
-    from paddle_tpu.distributed.fleet.pipeline import functional_call
-    from paddle_tpu.distributed.fleet.recompute import _POLICIES
     from paddle_tpu.models.kimi_linear import (KimiDeltaAttention,
                                                KimiLinearConfig)
-    layer = paddle.amp.decorate(KimiDeltaAttention(KimiLinearConfig(
-        hidden_size=2304, num_heads=32, kda_head_dim=128, kda_chunk=128)),
-        level="O2", dtype="bfloat16")
-    vals = {n: chip(p._data.shape, p._data.dtype)
-            for n, p in layer.named_parameters()}
-    # the kernels, not their XLA form and not interpreted
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-    def block(x, vals):
-        with paddle.amp.auto_cast(level="O2", dtype="bfloat16"):
-            return functional_call(layer, vals, x)
-
-    block = jax.checkpoint(
-        block, policy=_POLICIES["dots_and_kernels_saveable"])
-    compiled = chip.compile(
-        jax.grad(lambda x, vals: block(x, vals).astype(F32).sum(),
-                 argnums=(0, 1)), chip((1, 8192, 2304), BF16), vals)
+    compiled = _layer_step(chip, monkeypatch, KimiDeltaAttention(
+        KimiLinearConfig(hidden_size=2304, num_heads=32, kda_head_dim=128,
+                         kda_chunk=128)), 2304)
     text = compiled.as_text()
     assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
     assert not _retiled(text)
@@ -476,6 +487,56 @@ def test_kimi_delta_attention_fwd_bwd_compiles(chip, monkeypatch):
     # policy keeps from forward to backward over a whole step:
     # tests/test_kda.py holds the spreads out of the residuals
     assert compiled.memory_analysis().temp_size_in_bytes <= 2_291_551_232
+
+
+def _kernel_calls(text, name):
+    """The program's calls of the Pallas kernel ``name``."""
+    return len(re.findall(
+        rf" custom-call\([^\n]*tpu_custom_call[^\n]*{name}", text))
+
+
+def test_mellum_attention_rotates_by_the_kernel_once_each_way(
+        chip, monkeypatch):
+    """Laguna's window layer (``MellumAttention``: 64 query heads on 8
+    of 128, hidden 2048, a window of 512) at the cell's 8192 positions:
+    q and k are each rotated by one kernel call forward and one backward,
+    the rotated heads are what the policy keeps (no call in the
+    recompute), nothing is laid out as the float32 halves the jnp form
+    split a head into, and the program holds no more than the parent's
+    (``_archive/pr45_rope_ops.py`` prints both sides)."""
+    from paddle_tpu.models.mellum import (MellumAttention, MellumConfig,
+                                          RopeTables)
+    cfg = MellumConfig(hidden_size=2048, num_heads=64, num_kv_heads=8,
+                       head_dim=128, sliding_window=512)
+    compiled = _layer_step(
+        chip, monkeypatch,
+        MellumAttention(cfg, "sliding_attention", RopeTables(cfg)), 2048)
+    text = compiled.as_text()
+    assert _kernel_calls(text, "rope_half_turn_fwd") == 2
+    assert _kernel_calls(text, "rope_half_turn_bwd") == 2
+    assert _kernel_calls(text, "flash_window_fwd") == 1
+    assert _kernel_calls(text, "flash_window_bwd") == 1
+    for halves in ("f32[1,8192,64,64]", "f32[1,8192,64,128]",
+                   "f32[1,8192,8,64]"):
+        assert f"= {halves}" not in text
+    # the parent's (PR 44's commit) read 1,630,067,200 here on 2026-10-04
+    # (21 + 32 + 21 results of those three shapes), this tree
+    # 1,479,330,304
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1_630_067_200
+
+
+def test_lfm2_attention_keeps_the_jnp_rotation(chip, monkeypatch):
+    """LFM2's heads are 64 wide, two to a vreg: ``_rotate`` is the jnp
+    form there on the chip too, and the layer compiles without the
+    kernel."""
+    from paddle_tpu.models.lfm2 import Lfm2Attention, Lfm2MoeConfig
+    compiled = _layer_step(
+        chip, monkeypatch, Lfm2Attention(Lfm2MoeConfig(
+            hidden_size=2048, num_heads=32, num_kv_heads=8)), 2048)
+    text = compiled.as_text()
+    assert "rope_half_turn" not in text
+    assert _kernel_calls(text, "flash_attention_fwd") == 1
+    assert "= f32[1,8192,32,32]" in text    # the halves of a head of 64
 
 
 def test_sparse_moe_grouped_products_compile(chip):

@@ -373,6 +373,119 @@ def test_rope_fwd_bwd():
                                atol=2e-5, rtol=2e-5)
 
 
+# the half turn, position-tiled (``rope.half_turn``: the training path's
+# kernel at head width 128, ``models/lfm2.py`` ``_rotate`` on a TPU)
+def _half_turn_jnp(x, cos, sin):
+    """``_rotate``'s plain form on one operand."""
+    from paddle_tpu.models.lfm2 import _rotate
+    import paddle_tpu as paddle
+    return _rotate(paddle.Tensor(x), paddle.Tensor(x), cos, sin)[0]._data
+
+
+def _half_turn_tables(n, r):
+    """Plain tables 128 wide; YaRN's, scaled by its factor, 64 wide
+    (Laguna's full layers)."""
+    from paddle_tpu.models.llama import rope_angles, yarn_inv_freq
+    if r == 128:
+        return rope_angles(np.arange(n), r, 10000.0)
+    return rope_angles(np.arange(n), r, 500000.0,
+                       inv_freq=yarn_inv_freq(r, 500000.0, 64, 4096, 32, 1),
+                       scale=1.4159)
+
+
+def _assert_within_one_unit(got, want, operand, scale=1.0):
+    """Equal to the last bit, or one unit of the operands' type in the
+    last place at the size of the terms summed: the head's largest
+    operand times the tables' scale (where two products cancel, a
+    multiply-add that rounds once and one that rounds twice differ by a
+    unit of the products, not of their small sum)."""
+    assert got.dtype == want.dtype and got.shape == want.shape
+    bits = jnp.finfo(want.dtype).nmant
+    got, want, operand = (np.asarray(a, np.float64)
+                          for a in (got, want, operand))
+    size = np.maximum(np.abs(want), scale * np.abs(operand).max(
+        axis=-1, keepdims=True))
+    assert (np.abs(got - want) <= 2.0 ** (np.floor(np.log2(size)) - bits)).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads", [64, 48, 32, 8])
+@pytest.mark.parametrize("r", [128, 64])
+def test_half_turn_is_the_jnp_form_fwd_and_bwd(r, heads, dtype):
+    """A head of 128 whose first ``r`` dimensions turn, at the cells'
+    head counts: the kernel's result is the jnp form's, its gradient is
+    the jnp form's autodiff gradient, and between forward and backward
+    it keeps the two tables it reads there and nothing of ``x``."""
+    n = 64
+    cos, sin = _half_turn_tables(n, r)
+    x = _rand((1, n, heads, 128), dtype, seed=r + heads)
+    w = _rand((1, n, heads, 128), dtype, seed=1)
+
+    def kernel(x):
+        return rope.half_turn(x, cos, sin, interpret=True)
+
+    want, vjp_jnp = jax.vjp(lambda x: _half_turn_jnp(x, cos, sin), x)
+    got, vjp = jax.vjp(kernel, x)
+    scale = float(jnp.abs(cos).max())
+    _assert_within_one_unit(got, want, x, scale)
+    if r < 128:
+        assert jnp.array_equal(got[..., r:], x[..., r:])
+    _assert_within_one_unit(vjp(w)[0], vjp_jnp(w)[0], w, scale)
+    kept = jax.tree_util.tree_leaves(vjp)
+    assert sorted((a.shape, a.dtype) for a in kept) \
+        == [((n, 128), jnp.float32)] * 2
+    # one kernel each way, by the names a trace is read by
+    text = str(jax.make_jaxpr(lambda x, w: jax.vjp(kernel, x)[1](w))(x, w))
+    assert text.count("name=rope_half_turn_fwd") == 1
+    assert text.count("name=rope_half_turn_bwd") == 1
+
+
+def test_half_turn_tables_are_made_once_and_refuse_an_odd_width():
+    cos, sin = _half_turn_tables(32, 64)
+    first = rope.turn_tables(cos, sin)
+    assert all(a is b for a, b in zip(first, rope.turn_tables(cos, sin)))
+    c, s, st = (np.asarray(a) for a in first)
+    assert (c[:, 64:] == 1).all() and not s[:, 64:].any()
+    assert np.array_equal(st[:, :32], s[:, 32:64])
+    assert np.array_equal(st[:, 32:64], s[:, :32])
+    with pytest.raises(ValueError, match="halves"):
+        rope.turn_tables(cos[:, :63], sin[:, :63])
+    with pytest.raises(ValueError, match="half_turn"):
+        rope.half_turn(_rand((1, 32, 2, 64)), cos, sin, interpret=True)
+
+
+@pytest.mark.parametrize("head,calls", [(64, 0), (128, 2)])
+def test_rotate_calls_the_kernel_in_a_tpu_program_at_head_width_128_alone(
+        monkeypatch, head, calls):
+    """``_rotate`` chooses on the head's width, the backend and whether
+    a program is being captured: at 128 in a program captured on a TPU
+    q and k each go through the kernel; at 64 (LFM2's heads), off the
+    TPU and in per-op dispatch neither does."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core import scope
+    from paddle_tpu.models.lfm2 import _rotate
+    from paddle_tpu.models.llama import rope_angles
+    cos, sin = rope_angles(np.arange(32), head, 1e6)
+    q = paddle.Tensor(_rand((1, 32, 4, head), jnp.bfloat16, seed=3))
+    k = paddle.Tensor(_rand((1, 32, 2, head), jnp.bfloat16, seed=4))
+    seen, real = [], rope.half_turn
+    monkeypatch.setattr(rope, "half_turn", lambda x, c, s: seen.append(
+        x.shape) or real(x, c, s, interpret=True))
+    plain = _rotate(q, k, cos, sin)
+    with scope.capture():               # captured, off the TPU
+        _rotate(q, k, cos, sin)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _rotate(q, k, cos, sin)             # on it, per-op dispatch
+    assert not seen
+    with scope.capture():
+        steered = _rotate(q, k, cos, sin)
+    monkeypatch.undo()
+    assert len(seen) == calls
+    for got, want, x in zip(steered, plain, (q, k)):
+        _assert_within_one_unit(got._data, want._data, x._data)
+
+
 # --------------------------------------------------------------------------
 # ragged paged attention (ISSUE 3: multi-page compacted-grid serving kernel)
 # --------------------------------------------------------------------------
