@@ -183,6 +183,15 @@ _CALLS_KEPT = 1024  # calls whose own tallies a block keeps, a row each
 _calls_of = {}
 
 
+def _chunks(slots, at_a_time):
+    """The chunks ``slots`` sorted slots are cut into: the fewest of at
+    most ``at_a_time`` (None: ``_SLOTS_AT_A_TIME``) that divide them."""
+    chunks = -(-slots // (at_a_time or _SLOTS_AT_A_TIME))
+    while slots % chunks:
+        chunks += 1
+    return chunks
+
+
 def _chunk(c, slots, order, weight, routed):
     """Chunk ``c`` of the ``slots``-long cuts of the sorted slots: its
     tokens, its combine weights and which of its rows hold a slot
@@ -202,32 +211,65 @@ def _each_live_chunk(live, run, init):
     return jax.lax.fori_loop(0, live.shape[0], body, init)
 
 
-def _expert_rows(rows, w, w1, w3, w2, groups, mine):
+def takes_grouped_kernel(slots, held, hidden, width):
+    """Whether a chunk of ``slots`` rows through ``held`` experts
+    ``hidden`` -> ``width`` -> ``hidden`` makes its products by the
+    kernels of ``ops/pallas/grouped_matmul.py``: in a ``to_static``
+    program captured on a TPU, at extents the kernels tile (multiples
+    of 128).  Any other backend, any other extent and per-op dispatch
+    (a program's eager first call, where every call of a kernel is
+    traced and lowered anew: PERF.md section 6, PR 45) keep
+    ``jax.lax.ragged_dot``."""
+    from ....ops.pallas import grouped_matmul
+    return (jax.default_backend() == "tpu" and _scope.current() is not None
+            and grouped_matmul.takes(slots, held, hidden, width)
+            and grouped_matmul.takes(slots, held, width, hidden))
+
+
+def _expert_rows(rows, w, w1, w3, w2, groups, mine, kernel):
     """A chunk's token rows through their experts, group by group, each
-    scaled by its slot's combine weight: float32 [slots, H].  A row past
-    the last group is read by no product, and what the chip's kernel
-    leaves there is not a number to be multiplied, even by zero: the
-    selects take it out of the result, of the rows' gradient and of the
-    weights' before anything is scaled."""
-    with _scope.phase("dispatch"):
-        rows = jnp.where(mine[:, None], rows, 0)
+    scaled by its slot's combine weight: float32 [slots, H].
+
+    ``kernel`` True: the three products and, in the backward, their six
+    gradients are ``ops/pallas/grouped_matmul.py``'s (``grouped_dot``),
+    which reads no row past the last group and WRITES ZEROS there, in
+    the result and in the rows' gradient, and adds nothing of them to
+    the weights': no select is needed, and ``mine`` is not read.  False:
+    ``jax.lax.ragged_dot``, whose kernel on the chip leaves such rows
+    unwritten, and what is left there is not a number to be multiplied,
+    even by zero: the selects take it out of the result, of the rows'
+    gradient and of the weights' before anything is scaled."""
+    if kernel:
+        from ....ops.pallas import grouped_matmul
+        # one pair of step plans for the chunk's nine products
+        with _scope.phase("expert_mlp"):
+            dot = functools.partial(
+                grouped_matmul.grouped_dot,
+                plans=grouped_matmul.make_plans(groups, rows.shape[0]))
+    else:
+        dot = jax.lax.ragged_dot
+        with _scope.phase("dispatch"):
+            rows = jnp.where(mine[:, None], rows, 0)
     with _scope.phase("expert_mlp"):
-        a = jax.lax.ragged_dot(rows, w1, groups)
-        b = jax.lax.ragged_dot(rows, w3, groups)
-        y = jax.lax.ragged_dot(jax.nn.silu(a) * b, w2, groups)
+        a = dot(rows, w1, groups)
+        b = dot(rows, w3, groups)
+        y = dot(jax.nn.silu(a) * b, w2, groups)
     with _scope.phase("combine"):
-        return w[:, None] * jnp.where(mine[:, None], y, 0).astype(jnp.float32)
+        if not kernel:
+            y = jnp.where(mine[:, None], y, 0)
+        return w[:, None] * y.astype(jnp.float32)
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10,))
 def _routed_rows(flat, weight, w1, w3, w2, order, inverse, sizes, routed,
-                 live):
+                 live, kernel):
     """float32 [N, H]: per token, the sum over its slots routed here of
     weight x experts(row).  ``order`` sorts the N * k slots by held
     expert (absent experts last) and ``inverse`` is its inverse;
     ``sizes`` [chunks, held] cuts the groups at chunk edges; the first
     ``routed`` sorted slots are routed here and ``live`` [chunks] says
-    which chunks hold any of them.  The row work is done chunk by
+    which chunks hold any of them; ``kernel`` is ``_expert_rows``'
+    choice of products.  The row work is done chunk by
     chunk under ``live``: a chunk past the routed prefix is skipped,
     forward and backward, so the gathers, products, selects and sums
     follow the routed count and not the dropless worst case N * k.
@@ -237,18 +279,18 @@ def _routed_rows(flat, weight, w1, w3, w2, order, inverse, sizes, routed,
     the chunk's products are recomputed in its own backward, so its
     temporaries are never held for two chunks at once."""
     return _routed_rows_fwd(flat, weight, w1, w3, w2, order, inverse, sizes,
-                            routed, live)[0]
+                            routed, live, kernel)[0]
 
 
 def _routed_rows_fwd(flat, weight, w1, w3, w2, order, inverse, sizes, routed,
-                     live):
+                     live, kernel):
     slots = order.shape[0] // sizes.shape[0]
 
     def run(c, out):
         with _scope.phase("dispatch"):
             at, w, mine = _chunk(c, slots, order, weight, routed)
             rows = jnp.take(flat, at, axis=0)
-        y = _expert_rows(rows, w, w1, w3, w2, sizes[c], mine)
+        y = _expert_rows(rows, w, w1, w3, w2, sizes[c], mine, kernel)
         with _scope.phase("combine"):
             return out.at[at].add(y, mode="promise_in_bounds")
 
@@ -257,7 +299,7 @@ def _routed_rows_fwd(flat, weight, w1, w3, w2, order, inverse, sizes, routed,
                  live)
 
 
-def _routed_rows_bwd(res, g):
+def _routed_rows_bwd(kernel, res, g):
     flat, weight, w1, w3, w2, order, inverse, sizes, routed, live = res
     slots = order.shape[0] // sizes.shape[0]
 
@@ -268,8 +310,9 @@ def _routed_rows_bwd(res, g):
             rows = jnp.take(flat, at, axis=0)
         with _scope.phase("combine"):
             g_rows = jnp.take(g, at, axis=0)
-        _, back = jax.vjp(lambda *a: _expert_rows(*a, sizes[c], mine),
-                          rows, w, w1, w3, w2)
+        _, back = jax.vjp(
+            lambda *a: _expert_rows(*a, sizes[c], mine, kernel),
+            rows, w, w1, w3, w2)
         d_rows, d_w, e1, e3, e2 = back(g_rows)
         with _scope.phase("dispatch"):
             d_flat = d_flat.at[at].add(d_rows.astype(jnp.float32),
@@ -297,7 +340,8 @@ _routed_rows.defvjp(_routed_rows_fwd, _routed_rows_bwd)
 
 def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
                scaling=1.0, norm_eps=1e-6, scoring="sigmoid",
-               train_router=True, slots_at_a_time=None):
+               train_router=True, slots_at_a_time=None,
+               grouped_kernel=False):
     """Values in, ``(out, tally, chunks)`` out; the math of
     ``SparseMoEBlock``.
 
@@ -312,7 +356,10 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
     backward, so that neither ``gate`` nor ``x`` takes a gradient
     through them (``SparseMoEBlock`` says when); ``slots_at_a_time``
     is the most sorted slots a chunk holds (None: ``_SLOTS_AT_A_TIME``).
-    ``tally`` is int32 [held + 1]: the slots routed to each held expert
+    ``grouped_kernel`` True makes a chunk's products by the Pallas
+    kernels of ``ops/pallas/grouped_matmul.py`` and not by
+    ``jax.lax.ragged_dot`` (``SparseMoEBlock`` says when).  ``tally``
+    is int32 [held + 1]: the slots routed to each held expert
     and, last, the slots the router filled (N * top_k).  ``chunks`` is
     int32 [2]: the chunks of sorted slots whose rows were worked on, and
     the chunks there were."""
@@ -343,9 +390,7 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
         # all N * k sorted slots would be worked on only if every token
         # chose all its experts here: the chunks, and the conditions
         # the row work runs under
-        chunks = -(-n * k // (slots_at_a_time or _SLOTS_AT_A_TIME))
-        while n * k % chunks:
-            chunks += 1
+        chunks = _chunks(n * k, slots_at_a_time)
         slots = n * k // chunks
         lo = (jnp.arange(chunks) * slots)[:, None]
         live = lo[:, 0] < routed
@@ -353,7 +398,7 @@ def sparse_moe(x, gate, w1, w3, w2, *, bias, top_k, expert_offset,
         sizes = jnp.clip(ends[None], lo, lo + slots) \
             - jnp.clip((ends - counts)[None], lo, lo + slots)
     out = _routed_rows(flat, weight, w1, w3, w2, order, inverse, sizes,
-                       routed, live)
+                       routed, live, bool(grouped_kernel))
     tally = jnp.concatenate([counts, jnp.full((1,), n * k, jnp.int32)])
     ran = jnp.stack([live.sum(dtype=jnp.int32), jnp.int32(chunks)])
     return out.astype(x.dtype).reshape(x.shape), tally, ran
@@ -402,10 +447,18 @@ class SparseMoEBlock(Layer):
     ``expert_offset .. expert_offset + experts_held`` stacked
     ``[held, ...]`` and returns THEIR part of the layer's result: the
     slots routed here are gathered in expert order and multiplied group
-    by group (``jax.lax.ragged_dot``), whatever the imbalance; there is
-    no capacity and no dropped token.  What the absent experts would
-    add is left out: under expert parallelism the shares are summed
-    across chips, and on one chip the block runs without that exchange.
+    by group, whatever the imbalance; there is no capacity and no
+    dropped token.  The grouped products are the Pallas kernels of
+    ``ops/pallas/grouped_matmul.py`` in a ``to_static`` program
+    captured on a TPU (tiles that divide the widths they are handed;
+    zeros written past the last group) and ``jax.lax.ragged_dot`` on
+    any other backend, at an extent that is no multiple of 128 and in
+    per-op dispatch (``takes_grouped_kernel``); the gauge
+    ``moe.grouped_kernel{layer}`` reads 1 where the program captured
+    last took the kernels and 0 where it kept ``ragged_dot``.  What the
+    absent experts would add is left out: under expert parallelism the
+    shares are summed across chips, and on one chip the block runs
+    without that exchange.
 
     Two arguments are for a block that runs as such a lone share.
     ``train_router`` False: the combine weights are constants of the
@@ -483,6 +536,8 @@ class SparseMoEBlock(Layer):
         # the chunks of sorted slots that ran, and the chunks there were
         self.register_buffer("chunks", Tensor(jnp.zeros((2,), jnp.int32)),
                              persistable=False)
+        # [1]: whether the program captured last took the kernels
+        self._took_kernel = [0]
         layer = name or self._full_name
         _calls_of[layer] = self.calls
         self._register_gauges(layer)
@@ -494,6 +549,7 @@ class SparseMoEBlock(Layer):
         # alive, and a snapshot taken after the model is gone still
         # reads what was routed
         reg, routed, chunks = metrics.registry(), self.routed, self.chunks
+        took = self._took_kernel
         for e in range(self.experts_held):
             reg.gauge(
                 "moe.tokens_per_expert",
@@ -510,6 +566,12 @@ class SparseMoEBlock(Layer):
             "share of the sorted slot rows whose chunk was worked on",
             labels={"layer": layer}
         ).set_function(lambda: _run_share(chunks))
+        reg.gauge(
+            "moe.grouped_kernel",
+            "1 where the program captured last made the grouped products "
+            "by the Pallas kernels, 0 where by ragged_dot",
+            labels={"layer": layer}
+        ).set_function(lambda: took[0])
 
     def tally(self):
         """Python ints [held + 1]: slots per held expert, then the
@@ -541,6 +603,11 @@ class SparseMoEBlock(Layer):
     def forward(self, x):
         """(this chip's part of the layer's result, the call's tally,
         the chunks it ran and had)."""
+        slots = math.prod(x.shape[:-1]) * self.top_k
+        kernel = takes_grouped_kernel(
+            slots // _chunks(slots, self.slots_at_a_time), *self.w1.shape)
+        if _scope.current() is not None:
+            self._took_kernel[0] = int(kernel)
         return apply(
             "sparse_moe",
             functools.partial(sparse_moe, top_k=self.top_k,
@@ -549,6 +616,7 @@ class SparseMoEBlock(Layer):
                               norm_eps=self.norm_eps,
                               scoring=self.scoring,
                               train_router=self.train_router,
-                              slots_at_a_time=self.slots_at_a_time),
+                              slots_at_a_time=self.slots_at_a_time,
+                              grouped_kernel=kernel),
             x, self.gate.weight, self.w1, self.w3, self.w2,
             bias=self.expert_bias)

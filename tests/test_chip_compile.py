@@ -578,6 +578,63 @@ def test_sparse_moe_grouped_products_compile(chip):
     assert compiled.memory_analysis().temp_size_in_bytes <= 1_748_259_328
 
 
+# rows a chunk, held experts, hidden width, an expert's width
+SPARSE_CELLS = {"lfm2": (8192, 8, 2048, 1536),
+                "kimi_linear": (8192, 8, 2304, 1024),
+                "laguna": (16384, 32, 2048, 512),
+                "moonlight": (8192, 8, 2048, 1408),
+                "mellum2": (16384, 8, 2304, 896)}
+
+
+@pytest.mark.parametrize("cell", list(SPARSE_CELLS))
+def test_grouped_matmul_kernels_compile_at_the_cells_shapes(chip, cell):
+    """``grouped_dot`` and its ``jax.vjp`` at a sparse cell's chunk, up
+    ([M, H] x [G, H, I]) and down ([M, I] x [G, I, H]), with the
+    committed tile rule: the three kernels, no ``ragged-dot``, and
+    what Mosaic or the VMEM limit refuses fails here."""
+    from paddle_tpu.ops.pallas import grouped_matmul as GM
+    m, g, h, i = SPARSE_CELLS[cell]
+
+    def both(x, w, sizes, dy):
+        y, back = jax.vjp(
+            lambda x, w: GM.grouped_dot(x, w, sizes, interpret=False), x, w)
+        return (y,) + back(dy)
+
+    for k, n in ((h, i), (i, h)):
+        text = chip.compile(both, chip((m, k), BF16), chip((g, k, n), BF16),
+                            chip((g,), jnp.int32),
+                            chip((m, n), BF16)).as_text()
+        for name in ("fwd", "dx", "dw"):
+            assert _kernel_calls(text, f"grouped_matmul_{name}") == 1
+        assert "ragged-dot" not in text
+
+
+def test_expert_rows_by_the_kernels_hold_no_ragged_dot(chip, monkeypatch):
+    """A chunk of Mellum2's cell (16384 slots, 8 experts 2304 -> 896)
+    through ``_expert_rows`` and its ``jax.vjp`` on the kernel path:
+    three products forward and a rows' and a weights' gradient for
+    each, every one a kernel of ``ops/pallas/grouped_matmul.py``, no
+    ``ragged-dot`` custom call, and no select over the chunk's rows."""
+    from paddle_tpu.incubate.distributed.models.moe import _expert_rows
+    m, g, h, i = SPARSE_CELLS["mellum2"]
+    # the kernels compiled, not interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def both(rows, w, w1, w3, w2, sizes, mine, dy):
+        y, back = jax.vjp(
+            lambda *a: _expert_rows(*a, sizes, mine, True),
+            rows, w, w1, w3, w2)
+        return (y,) + back(dy)
+
+    text = chip.compile(
+        both, chip((m, h), BF16), chip((m,), F32), chip((g, h, i), BF16),
+        chip((g, h, i), BF16), chip((g, i, h), BF16), chip((g,), jnp.int32),
+        chip((m,), jnp.bool_), chip((m, h), F32)).as_text()
+    for name in ("fwd", "dx", "dw"):
+        assert _kernel_calls(text, f"grouped_matmul_{name}") == 3
+    assert "ragged-dot" not in text
+
+
 def test_fused_adamw_master_weights_compiles(chip):
     n = 124_475_904 // 1024 * 1024          # GPT-124M's parameters, flat
     spec = FO.UpdateSpec(kind="adamw", decay=0.01, use_master=True)

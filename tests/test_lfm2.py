@@ -323,6 +323,51 @@ def test_block_gradients_reach_router_and_experts_not_the_bias(
             assert not np.asarray(got).any()
 
 
+@pytest.mark.parametrize("routing", [
+    "one_chunk", "partial_last_chunk", "groups_cut_at_chunk_edges",
+    "nothing_routed_here"])
+def test_kernel_products_are_the_ragged_dot_products(routing, monkeypatch):
+    """``sparse_moe``'s value and every gradient with a chunk's
+    products made by ``ops/pallas/grouped_matmul.py`` (interpreted) are
+    those with ``jax.lax.ragged_dot``, with one live chunk, with
+    several, with groups cut at chunk edges and with none live: the
+    kernels' zeros past the last group stand where the selects
+    stood."""
+    from paddle_tpu.incubate.distributed.models import moe
+    from paddle_tpu.ops.pallas import grouped_matmul
+    slots_at_a_time, held_n, bias, chunks = ROUTINGS[routing]
+    bias = bias() if bias else \
+        0.3 * np.random.default_rng(9).standard_normal(ROUTER).astype("f4")
+    block, _ = _block_and_leaves(4, held_n, bias)
+    leaves = [jnp.asarray(_tokens())] + [
+        p._read() for p in (block.gate.weight, block.w1, block.w3, block.w2)]
+    real, calls = grouped_matmul.grouped_dot, []
+    monkeypatch.setattr(
+        grouped_matmul, "grouped_dot", lambda rows, *a, **kw: calls.append(
+            rows.shape) or real(rows, *a, **kw, interpret=True))
+
+    def both(kernel):
+        def loss(*a):
+            out, tally, ran = moe.sparse_moe(
+                *a, bias=jnp.asarray(bias), top_k=TOP_K, expert_offset=4,
+                slots_at_a_time=slots_at_a_time, grouped_kernel=kernel)
+            return jnp.sum(out ** 2), (out, ran)
+
+        (_, (out, ran)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*leaves)
+        return (out, *grads), ran
+
+    want, ran = both(False)
+    assert not calls
+    got, _ = both(True)
+    assert int(ran[0]) == chunks[0] if chunks else int(ran[0]) > 1
+    # forward, the chunk's recompute, and a gradient for each product
+    assert len(calls) == 6 and len(set(calls)) == 2
+    for g, w in zip(got, want):
+        close(g, w)
+        assert np.isfinite(np.asarray(g)).all()
+
+
 @pytest.mark.parametrize("pull", [-10.0, 0.0, 0.6])
 def test_run_share_is_the_chunks_that_hold_a_slot_routed_here(
         pull, monkeypatch):

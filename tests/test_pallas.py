@@ -487,6 +487,209 @@ def test_rotate_calls_the_kernel_in_a_tpu_program_at_head_width_128_alone(
 
 
 # --------------------------------------------------------------------------
+# grouped matrix products (ISSUE 46: the held experts' three products)
+# --------------------------------------------------------------------------
+import functools
+
+from paddle_tpu.ops.pallas import grouped_matmul as gmm
+
+# rows a chunk, held experts, hidden width, an expert's width
+SPARSE_CELLS = {"lfm2": (8192, 8, 2048, 1536),
+                "kimi_linear": (8192, 8, 2304, 1024),
+                "laguna": (16384, 32, 2048, 512),
+                "moonlight": (8192, 8, 2048, 1408),
+                "mellum2": (16384, 8, 2304, 896)}
+# group sizes over 512 rows (4 row tiles of 128), and whether the rows
+# past them hold NaN
+GROUPINGS = {
+    "tail_rows_hold_nan": ([100, 60, 130, 50], True),
+    "empty_group_in_the_middle": ([200, 0, 0, 212], False),
+    "one_group_holds_every_row": ([0, 512, 0, 0], False),
+    "group_edges_inside_row_tiles": ([3, 250, 5, 254], False),
+    "nothing_routed": ([0, 0, 0, 0], True),
+    "edges_on_tile_edges_and_a_tail": ([128, 256, 0, 0], True),
+}
+PRODUCTS = ["result", "rows_gradient", "weights_gradient"]
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_products(dtype, m, k, n, sizes, nan, tiling=None):
+    """(the kernels', ``ragged_dot``'s) result, rows' gradient and
+    weights' gradient, the cotangent's rows past the last group zeroed
+    for ``ragged_dot`` and, with ``nan``, NaN for the kernels, as are
+    ``rows``' there."""
+    dtype = jnp.dtype(dtype)
+    kx, kw, kdy = jax.random.split(jax.random.PRNGKey(46), 3)
+    x = jax.random.normal(kx, (m, k), dtype)
+    dy = jax.random.normal(kdy, (m, n), dtype)
+    w = jax.random.normal(kw, (len(sizes), k, n), dtype) * k ** -0.5
+    groups = jnp.asarray(sizes, jnp.int32)
+    routed = (jnp.arange(m) < sum(sizes))[:, None]
+    want, back = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, groups), x, w)
+    want = (want,) + back(jnp.where(routed, dy, 0))
+    if nan:
+        x, dy = jnp.where(routed, x, jnp.nan), jnp.where(routed, dy, jnp.nan)
+    if tiling is None:
+        got, back = jax.vjp(
+            lambda x, w: gmm.grouped_dot(x, w, groups, interpret=True), x, w)
+        got = (got,) + back(dy)
+    else:
+        by_rows, by_group = (
+            gmm._plan(groups, m=m, tm=tiling[0], tail=tail)
+            for tail in (True, False))
+        got = (gmm._rows_call(x, w, by_rows, turned=False, interpret=True,
+                              tiling=tiling),
+               gmm._rows_call(dy, w, by_rows, turned=True, interpret=True,
+                              tiling=tiling),
+               gmm._weights_call(x, dy, by_group, interpret=True,
+                                 tiling=tiling))
+    return dict(zip(PRODUCTS, zip(got, want))), sum(sizes)
+
+
+def _assert_products_agree(got, want):
+    """To a unit in the last place of the largest value: the kernels
+    and ``ragged_dot`` sum the same float32 products in another order
+    and round once."""
+    assert got.dtype == want.dtype and got.shape == want.shape
+    unit = 2.0 ** -7 if got.dtype == jnp.bfloat16 else 1e-5
+    got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= unit * max(np.abs(want).max(), 1e-30)
+
+
+@pytest.mark.parametrize("product", PRODUCTS)
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("k,n", [(2304, 896), (2048, 1408), (2048, 512),
+                                 (2048, 1536)])
+def test_grouped_dot_is_ragged_dot_at_the_cells_widths(k, n, dtype, product):
+    """The five cells' (hidden, expert) widths, 7 and 11 lane tiles of
+    N among them, over 128 rows in 4 uneven groups that leave a tail."""
+    pairs, routed = _grouped_products(dtype, 128, k, n, (30, 0, 51, 20),
+                                      False)
+    got, want = pairs[product]
+    if product != "weights_gradient":
+        want = want.at[routed:].set(0)
+    _assert_products_agree(got, want)
+
+
+@pytest.mark.parametrize("product", PRODUCTS)
+@pytest.mark.parametrize("grouping", list(GROUPINGS))
+def test_grouped_dot_by_grouping(grouping, product):
+    """Groups that sum to less than the rows (the rows past them read
+    exactly 0 in the result and the rows' gradient and add 0 to the
+    weights', NaN in ``rows`` and the cotangent there or not), an empty
+    group in the middle, one group holding every row, group edges
+    inside a row tile and on its edge."""
+    sizes, nan = GROUPINGS[grouping]
+    pairs, routed = _grouped_products("float32", 512, 128, 256, tuple(sizes),
+                                      nan)
+    got, want = pairs[product]
+    if product != "weights_gradient":
+        assert not np.asarray(got[routed:], np.float32).any()
+        want = want.at[routed:].set(0)
+    _assert_products_agree(got, want)
+    if product == "weights_gradient":
+        for g, size in enumerate(sizes):
+            if not size:
+                assert not np.asarray(got[g], np.float32).any()
+
+
+@pytest.mark.parametrize("product", PRODUCTS)
+def test_grouped_dot_sums_cut_widths_in_float32(product):
+    """K and N in tiles of 128: the row kernels' accumulator over K
+    tiles, each (group, K tile, N tile) of the weights' gradient its
+    own sum over the group's row tiles."""
+    pairs, routed = _grouped_products(
+        "bfloat16", 512, 256, 384, (100, 60, 130, 50), True,
+        (128, 128, 128))
+    got, want = pairs[product]
+    if product != "weights_gradient":
+        want = want.at[routed:].set(0)
+    _assert_products_agree(got, want)
+
+
+@pytest.mark.parametrize("cell", list(SPARSE_CELLS))
+def test_grouped_tiles_divide_the_cells_widths_and_fit_the_budget(cell):
+    """The rule's ``tk`` and ``tn`` are multiples of 128 that divide the
+    width they cut, a step's VMEM is within the stated budget, the row
+    tile divides the chunk, and an expert width of 896 or 1408 is taken
+    whole: the point of the kernels."""
+    m, g, h, i = SPARSE_CELLS[cell]
+    for k, n in ((h, i), (i, h)):
+        assert gmm.takes(m, g, k, n)
+        for kind in ("fwd", "dx", "dw"):
+            tm, tk, tn = gmm.tiles(kind, m, g, k, n)
+            cut_k, cut_n = (n, k) if kind == "dx" else (k, n)
+            assert tm == 256
+            assert cut_k % tk == 0 and tk % 128 == 0
+            assert cut_n % tn == 0 and tn % 128 == 0
+            assert gmm.vmem_bytes(kind, tm, tk, tn) <= gmm._BUDGET
+            if i in (896, 1408):
+                assert i in (tk, tn)
+    assert not gmm.takes(80, 4, 32, 48)
+
+
+def test_grouped_dot_names_its_kernels_and_refuses_mismatched_operands():
+    x, w = _rand((256, 128)), _rand((2, 128, 256))
+    groups = jnp.asarray([100, 100], jnp.int32)
+    text = str(jax.make_jaxpr(lambda x, w, dy: jax.vjp(
+        lambda x, w: gmm.grouped_dot(x, w, groups, interpret=True),
+        x, w)[1](dy))(x, w, _rand((256, 256))))
+    for name in ("grouped_matmul_fwd", "grouped_matmul_dx",
+                 "grouped_matmul_dw"):
+        assert text.count(f"name={name}") == 1
+    with pytest.raises(ValueError, match="grouped_dot"):
+        gmm.grouped_dot(x, w.astype(jnp.bfloat16), groups, interpret=True)
+    with pytest.raises(ValueError, match="grouped_dot"):
+        gmm.grouped_dot(x, w[:, :64], groups, interpret=True)
+
+
+@pytest.mark.parametrize("width,taken", [(48, 0), (128, 1)])
+def test_sparse_block_takes_the_kernels_in_a_tpu_program_alone(
+        monkeypatch, width, taken):
+    """``SparseMoEBlock`` chooses on the extents, the backend and
+    whether a program is being captured: at multiples of 128 in a
+    program captured on a TPU a chunk's three products go through
+    ``grouped_dot``; at any other extent, off the TPU and in per-op
+    dispatch they are ``jax.lax.ragged_dot``'s, and
+    ``moe.grouped_kernel{layer}`` says which the captured program
+    took."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core import scope
+    from paddle_tpu.incubate.distributed.models import moe
+    from paddle_tpu.observability import metrics
+    layer = f"kernel_choice_{width}"
+    # the block's ring and gauges outlive it: other files' tests, in
+    # the same process, read every layer's
+    reg = metrics.registry()
+    monkeypatch.setattr(moe, "_calls_of", dict(moe._calls_of))
+    monkeypatch.setattr(reg, "_metrics", dict(reg._metrics))
+    block = moe.SparseMoEBlock(128, width, 8, 2, expert_offset=2,
+                               experts_held=4, name=layer)
+    x = paddle.Tensor(_rand((128, 128), seed=5))
+
+    def gauge():
+        return metrics.snapshot()["moe"]["grouped_kernel"][f"layer={layer}"]
+
+    seen, real = [], gmm.grouped_dot
+    monkeypatch.setattr(gmm, "grouped_dot", lambda r, *a, **kw: seen.append(
+        r.shape) or real(r, *a, **kw, interpret=True))
+    plain = block(x)[0]
+    with scope.capture():               # captured, off the TPU
+        block(x)
+    assert gauge() == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    block(x)                            # on it, per-op dispatch
+    assert not seen and gauge() == 0
+    with scope.capture():
+        steered = block(x)[0]
+    assert len(seen) == 3 * taken and gauge() == taken
+    monkeypatch.undo()
+    np.testing.assert_allclose(steered._data, plain._data, atol=1e-5,
+                               rtol=1e-5)
+
+
+# --------------------------------------------------------------------------
 # ragged paged attention (ISSUE 3: multi-page compacted-grid serving kernel)
 # --------------------------------------------------------------------------
 from paddle_tpu.ops.pallas import paged_attention as pga
