@@ -24,7 +24,7 @@ import time
 
 import numpy as np
 
-# GPT-124M as bench.py and benchmarks/serving_bench.py build it
+# GPT-124M (GPT-2 small's widths, vocabulary padded to a multiple of 128)
 WIDTH = dict(vocab_size=50304, hidden_size=768, num_layers=12, num_heads=12)
 BATCH, SEQ, TRAIN_STEPS = 8, 1024, 4
 PROMPT_LENS = (37, 150, 333, 512, 901, 1200)
@@ -100,8 +100,9 @@ def _static_text(static_fn, args):
 
 # ----------------------------------------------------------------- train
 def _train_model(width, seq, **cfg_kw):
-    """GPT as bench.py trains it: AMP O2 bf16 with master weights,
-    recompute, AdamW — and the ``jit.to_static`` step over it."""
+    """GPT as the gpt2-medium cell trains it (perf/models/): AMP O2 bf16
+    with master weights, recompute, AdamW — and the ``jit.to_static``
+    step over it."""
     import paddle_tpu as paddle
     import paddle_tpu.amp as amp
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM

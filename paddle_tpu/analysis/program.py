@@ -40,7 +40,7 @@ Entry points: :func:`audit_jaxpr` (one ClosedJaxpr),
 :func:`audit_executable` (a built ``jit._Executable``; also computes
 the static peak estimate), :func:`audit_jitted` (trace a callable with
 example args and audit — for raw ``jax.jit`` sites), and
-:func:`audit_counts` (process-level per-code tally for bench records).
+:func:`audit_counts` (process-level per-code tally).
 All are mode-gated by ``PDTPU_ANALYSIS`` and never raise except through
 the standard ``report`` gate in error mode.
 """
@@ -795,10 +795,9 @@ class AuditResult:
 def flat_eqn_count(jaxpr) -> int:
     """Total equation count of a jaxpr INCLUDING every call-like
     sub-jaxpr (pjit, remat/checkpoint, scan, custom_vjp, ...) — the
-    denominator-independent size measure ``calibrate.
-    measure_remat_fraction`` uses: a remat region's recomputed forward
-    lives in a ``remat``-primitive sub-jaxpr, invisible to a top-level
-    count."""
+    denominator-independent size measure a remat A/B needs: a remat
+    region's recomputed forward lives in a ``remat``-primitive
+    sub-jaxpr, invisible to a top-level count."""
     from jax import core as _jcore  # noqa: F401  (import parity)
     total = 0
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)  # ClosedJaxpr -> Jaxpr
@@ -814,14 +813,14 @@ def flat_eqn_count(jaxpr) -> int:
     return total
 
 
-# process-level per-code tally (bench round records; regression sentinel)
+# process-level per-code tally
 _audit_counts: dict[str, int] = {}
 
 
 def audit_counts(reset: bool = False) -> dict[str, int]:
     """Per-code finding counts accumulated by every audit since the last
-    reset — bench.py snapshots these into the round record so the
-    regression sentinel treats new findings like a perf regression."""
+    reset: a caller that compiles a set of programs reads here which
+    PDT codes their audits raised, and how often."""
     out = dict(sorted(_audit_counts.items()))
     if reset:
         _audit_counts.clear()
@@ -894,7 +893,7 @@ def audit_executable(exe, *, where: str = "", fn=None
     exe.schedule_hash = res.schedule_hash
     # flattened program size, stashed before the jaxpr is released:
     # remat A/Bs read it off cached executables (the recompute fraction
-    # is extra eqns / baseline eqns — see calibrate.py)
+    # is extra eqns / baseline eqns)
     try:
         exe.jaxpr_eqn_count = flat_eqn_count(closed)
     except Exception:

@@ -175,13 +175,12 @@ def _apply(name: str, fn: Callable, *args, **kwargs):
     #
     # In eager: run the plain forward now; jax.vjp happens at backward
     # time from the saved input values (autograd.run_backward).
-    # Measured (benchmarks/eager_bench.py): eager jax.vjp-per-op costs
-    # ~10x a plain dispatch, so grad-enabled forwards that never reach a
-    # backward (eval loops, branch probes) must not pay it. The trade: a
-    # backwarded op re-runs its primal inside jax.vjp (fwd executes
-    # twice); measured fwd+bwd cost moves ~4.7ms -> ~5.5ms per 256x256
-    # linear on CPU, and eager is dispatch-bound.  A capture that records
-    # nodes and never runs a backward traces linearisations XLA deletes.
+    # An eager jax.vjp per op costs many times a plain dispatch, so
+    # grad-enabled forwards that never reach a backward (eval loops,
+    # branch probes) must not pay it. The trade: a backwarded op re-runs
+    # its primal inside jax.vjp (fwd executes twice), and eager is
+    # dispatch-bound.  A capture that records nodes and never runs a
+    # backward traces linearisations XLA deletes.
     capturing = _scope.current() is not None
     diff_vals = [vals[i] for i in diff_idx]
     try:

@@ -113,8 +113,7 @@ define_flag("serving_kv_quant", False,
             "pages store int8 with per-page scale side-pools "
             "(quantization.kv_quantize), dequantized inside the ragged "
             "paged-attention kernel's DMA loop — KV bytes per resident "
-            "sequence drop >2x (serving_bench recomputes the roofline "
-            "from the quantized bytes) at token-identical greedy "
+            "sequence drop >2x at token-identical greedy "
             "outputs on the serving parity suite. Default off; "
             "PDTPU_SERVING_KV_QUANT=1 (or engine kwarg kv_quant) "
             "enables, and the off state is bitwise-identical to the "
@@ -320,11 +319,11 @@ define_flag("train_glue_fusion", False,
             "so every (residual add, pre-norm) pair — and the final "
             "norm — runs as ONE fused fwd/bwd Pallas dispatch; BERT's "
             "post-LN pairs fuse in place. Train-mode only (eval/serving "
-            "keep the unfused path and its numerics). Default off: the "
-            "standalone Pallas LN measured as a fusion BARRIER "
-            "in-context (+6 ms/step on the GPT-124M bench, see "
+            "keep the unfused path and its numerics). Default off: a "
+            "custom call is a fusion BARRIER and the standalone Pallas "
+            "LN lost time in context for that reason (see "
             "nn/functional/norm.py) — the fused glue path ships dark "
-            "until the TPU round prices it end-to-end. Numerics "
+            "until a cell prices it end to end. Numerics "
             "differ from the unfused chain by norm-formula ulps "
             "(two-pass variance vs "
             "E[x^2]-E[x]^2), so this is an A/B knob, not a "

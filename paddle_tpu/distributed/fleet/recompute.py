@@ -179,9 +179,9 @@ def _transformer_saveable(prim, *a, **k):
     act_out, tagged via ``jax.ad_checkpoint.checkpoint_name`` in
     F.layer_norm and F.gelu): the backward reads the saved normed
     activations and GELU outputs instead of re-running the reductions
-    and transcendentals. MEASURED SLOWER than dots_and_kernels on the
-    GPT-124M bench (97.96 vs ~94 ms/step, r5 anatomy — the saved GELU
-    residuals cost more HBM than their recompute) — this is a memory/
+    and transcendentals. It was slower than dots_and_kernels where
+    rounds 1-5 read it (GPT-124M width: the saved GELU residuals
+    cost more HBM traffic than their recompute) — this is a memory/
     recompute KNOB, not a default. Called once per jaxpr eqn, so the
     underlying policy object is built once."""
     global _NAMED_SAVEABLE

@@ -415,8 +415,8 @@ class Model:
         scan launch (``jit.WindowRunner``) with inputs pre-staged on
         device and per-step scheduler LRs threaded through the window
         (``optimizer.lr_window``). Per-step host dispatch over a
-        network-attached chip otherwise dominates the step time; see
-        BASELINE.md. Callbacks and metrics observe every step, after
+        network-attached chip otherwise dominates the step time.
+        Callbacks and metrics observe every step, after
         its window completes; epoch tails shorter than K (and
         ``accumulate_grad_batches > 1`` runs) use the per-batch path.
 

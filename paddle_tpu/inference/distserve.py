@@ -46,7 +46,7 @@ Observability: every handoff runs under a ``serving.handoff`` tracing
 span, emits a ``serving.handoff`` event (rid/bytes/ms) into the ring,
 and feeds the coordinator registry's ``serving.handoff_ms`` histogram
 and ``serving.handoff_bytes``/``serving.handoffs``/``serving.requeues``
-counters — serving_bench's ``disagg`` row reads them.
+counters.
 """
 from __future__ import annotations
 

@@ -141,8 +141,7 @@ Observability (ISSUE 8; ``paddle_tpu.observability``):
   backstop — dump the ring as a JSON flight record
   (``PDTPU_FLIGHT_DIR``), so the postmortem starts from the last N
   events.  Clean runs dump nothing; ``PDTPU_METRICS=off`` restores
-  the pre-observability engine bitwise (serving_bench's
-  ``metrics_overhead`` row pins the on state at <= 3% tokens/sec).
+  the pre-observability engine bitwise.
 * SLO GUARDRAILS & STALL WATCHDOG (ISSUE 14) — ``slo=`` arms
   declarative objectives (``observability/slo.py``) over the engine's
   own timeline histograms, evaluated at step boundaries over sliding
@@ -302,7 +301,7 @@ class _Request:
         # prefill_tokens_requested counts each request's demand ONCE:
         # re-admissions (preempt resume, worker-lost / replica-lost
         # requeue via add_request(requeue=True)) must not re-count it,
-        # or the shared_prefix/disagg bench's prefill_saved_frac
+        # or a shared-prefix/disagg report's prefill_saved_frac
         # denominator inflates with retry traffic while
         # prefill_tokens_computed keeps metering the actual recompute
         self.requested_counted = False

@@ -2,8 +2,8 @@
 
 The reference ships vision models in ``python/paddle/vision/models`` and
 leaves LLMs to PaddleNLP; this framework's flagship trainables live here so
-benchmarks (BASELINE.md configs 3-5) and the driver entry hooks have a
-canonical model family to exercise.
+the cells of ``BENCHMARK.json`` (adapters in ``perf/models/``) and the driver
+entry hooks have a canonical model family to exercise.
 """
 from . import gpt  # noqa: F401
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401

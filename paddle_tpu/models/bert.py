@@ -220,8 +220,8 @@ class BertForPretraining(Layer):
             # masked positions (ties keep ascending index order);
             # un-masked filler slots keep label IGNORE. The hidden-state
             # selection is a one-hot MATMUL, not a gather: on TPU the
-            # gather's backward is a scatter-add over [B, S, H] (measured
-            # +12 ms/step on the b16/s512 bench), while the one-hot
+            # gather's backward is a scatter-add over [B, S, H] (slower
+            # where rounds 1-5 read it), while the one-hot
             # contraction's backward is another matmul on the MXU.
             flags = ops.cast(mlm_labels != self.IGNORE, "int32")
             flag_k, pos = ops.topk(flags, k, axis=-1)

@@ -18,12 +18,12 @@ from ...core.tensor import Tensor
 
 def _pallas_norms():
     """Fused Pallas norm kernels — OPT-IN via
-    ``PDTPU_NORM_BACKEND=pallas``. Measured in-context (r5 step
-    anatomy, GPT-124M b8 x s1024): the Pallas LN custom call is a
-    fusion BARRIER — its input and output must materialize in HBM — and
-    costs ~6 ms/step over the jnp formulation, which XLA fuses into the
-    neighboring residual-add/cast chains (full step 100.4 ms with
-    Pallas LN, 94.4 ms with XLA LN, 87.1 ms with LN deleted). The same
+    ``PDTPU_NORM_BACKEND=pallas``. Read in context in rounds 1-5, at
+    GPT-124M width (no cell holds the figures: a cell that turns it on
+    would): the Pallas LN custom call is a fusion BARRIER — its input
+    and output must materialize in HBM — and the step was slower with
+    it than with the jnp formulation, which XLA fuses into the
+    neighboring residual-add/cast chains. The same
     isolated-vs-in-context trap as the flash-attention block autotune:
     the kernel wins alone and loses inside the step."""
     import os

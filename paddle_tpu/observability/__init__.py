@@ -61,14 +61,6 @@ Pieces
   record + Chrome trace, emits ``watchdog.stall``, and (for the
   engine) injects a coded ``EngineStallError`` (PDT-E020) into the
   stalled dispatch instead of letting ``step()`` hang forever.
-* ``regress``   — bench-history regression sentinel (ISSUE 14):
-  ``python -m paddle_tpu.observability.regress`` judges the newest
-  ``BENCH_*``/``MULTICHIP_*`` round against noise-aware median/MAD
-  baselines over the prior rounds (tolerating the truncated records
-  real history contains, excluding ``cached`` stale subtrees), prints
-  a stable sorted report and exits nonzero on regression; ``bench.py``
-  calls :func:`regress.check_record` so every new round self-reports
-  ``regressions: [...]`` in its JSON tail.
 * ``aggregate`` — fleet-wide metrics (ISSUE 12):
   :func:`fleet_snapshot` publishes/gathers every rank's registry
   snapshot through the rendezvous ``TCPStore`` (straggler-tolerant
@@ -172,7 +164,6 @@ from .aggregate import fleet_snapshot  # noqa: F401
 from . import slo  # noqa: F401
 from .slo import SLOEngine, SLOSpec, parse_slo  # noqa: F401
 from . import watchdog  # noqa: F401
-from . import regress  # noqa: F401
 
 # events.dump is the flight recorder; keep a namespaced alias so call
 # sites read as what they do: flight.dump(...)
@@ -186,5 +177,5 @@ __all__ = [
     "RegistryCounters", "StepTimer", "device_peak_flops",
     "tracing", "span", "traced", "export_trace", "render_trace",
     "aggregate", "fleet_snapshot",
-    "slo", "SLOEngine", "SLOSpec", "parse_slo", "watchdog", "regress",
+    "slo", "SLOEngine", "SLOSpec", "parse_slo", "watchdog",
 ]

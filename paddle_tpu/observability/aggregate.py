@@ -31,9 +31,8 @@ Gating: with ``PDTPU_METRICS=off`` :func:`fleet_snapshot` returns
 ``{}`` without touching the store — the flag's cheap-no-op contract.
 
 Single-controller note: one SPMD host is one rank; ``fleet_snapshot()``
-with no store degenerates to the local snapshot (used by the
-``hybrid_bench`` ``gpt_3d`` row), and multi-host jobs pass the
-launcher's store + ``world_size``/``rank``.
+with no store degenerates to the local snapshot, and multi-host jobs
+pass the launcher's store + ``world_size``/``rank``.
 """
 from __future__ import annotations
 

@@ -5,9 +5,9 @@ Design constraints (ISSUE 8 tentpole, part 1):
 * ALWAYS-ON: the serving/training hot loops record through these on
   every step, so a record call is a flag check, a lock, and an int add.
   With ``PDTPU_METRICS=off`` every record call returns after ONE dict
-  lookup — the off state restores pre-observability behavior (and the
-  ``metrics_overhead`` bench row quantifies the on state: <= 3%
-  tokens/sec on the serving workload).
+  lookup — the off state restores pre-observability behavior (the on
+  state's cost on the chip is PERF.md's reading, PR 40: ~1.5 us a
+  compiled call, nothing end to end).
 * Metrics whose values back a USER-VISIBLE contract (the serving
   engine's ``stats`` snapshot) are created with ``always=True`` and
   record regardless of the flag — ``stats`` returned those numbers
@@ -63,9 +63,9 @@ def percentile_from_counts(buckets, counts, count, q) -> float:
     upper edge of the bucket holding the q-th observation (the fixed
     log-spaced buckets make this stable across runs).  ONE home for
     the math — :meth:`Histogram.percentile`, the SLO engine's windowed
-    evaluation (``observability/slo.py``) and serving_bench's
-    ``_tl_pct`` all call here, so bench columns and runtime guardrails
-    can never disagree on what a p99 is.  The overflow bucket has no
+    evaluation (``observability/slo.py``) and every report that quotes
+    a percentile call here, so a report and a runtime guardrail can
+    never disagree on what a p99 is.  The overflow bucket has no
     finite upper edge, so a percentile landing there is ``inf``; an
     empty histogram reads 0.0."""
     if not count or not buckets:

@@ -20,8 +20,7 @@ preempted/requeued -> retired) as it schedules; the timelines object
   ``finish_reason``.
 
 Every hook early-returns when ``PDTPU_METRICS=off``; with it on, a hook
-is a dict lookup, a clock read and a histogram observe — measured by
-the ``metrics_overhead`` serving-bench row.
+is a dict lookup, a clock read and a histogram observe.
 
 :class:`RegistryCounters` is the adapter that re-backs the engine's
 ``stats`` dict onto registry counters: same keys, same int values, same

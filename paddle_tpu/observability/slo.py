@@ -36,8 +36,8 @@ multi-window burn-rate alerting:
   ``engine.render_prometheus()`` exposes budget state to scrapes.
 
 Percentile math is :func:`metrics.percentile_from_counts` — the SAME
-implementation serving_bench's report columns use, so the guardrail
-and the benchmark can never disagree on what a p99 is.
+implementation :meth:`Histogram.percentile` reports through, so the
+guardrail and a report can never disagree on what a p99 is.
 
 Everything is gated on ``PDTPU_METRICS``: with metrics off the
 histograms carry no data and ``maybe_evaluate``/``status`` return

@@ -34,8 +34,9 @@ differ by a few percent because XLA fuses/schedules the kernel
 differently in context (e.g. the GPT-124M step runs fastest with
 (256,512) although the isolated fwd+bwd chain ranks (512,1024) first).
 The cache stores VALUES, so an end-to-end-measured winner can be pinned
-by writing it into the cache file — bench.py ships pinned winners for
-its two model shapes in benchmarks/measured/autotune.json.
+by writing it into the cache file (``autotune.json`` under
+``PDTPU_CACHE_DIR``); the repo ships none, and no cell of
+``BENCHMARK.json`` turns the tuner on.
 
 Disabled by default (the reference's autotune is also opt-in); enable
 with ``paddle_tpu.incubate.autotune.set_config({"kernel": {"enable":
@@ -116,7 +117,7 @@ def autotune(op: str, signature: str, candidates: Sequence,
     Returns the winning candidate (cached on later calls).
 
     ``measure``: optional ``cand -> seconds`` that owns its own timing
-    (e.g. the dispatch-free scan-slope of benchmarks/calibrate.py —
+    (e.g. a dispatch-free scan-slope, as flash_attention._scan_slope —
     wall-timing individual dispatches over a network-attached chip is
     jitter-dominated and picks wrong winners). When given, ``run`` is
     not used. ``validate``: optional ``cand -> None`` called on each

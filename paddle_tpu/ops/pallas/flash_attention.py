@@ -935,7 +935,7 @@ def _tuned_entry(entry, candidates, qt, kt, vt, causal, make_runner,
     autotune entries. Under a trace (tracer inputs) only cache HITS
     apply — the shapes are static so the key is known; the measuring
     sweep runs when inputs are concrete (first eager call, or an
-    explicit warmup like bench.py's). On a sweep where every candidate
+    explicit warmup call). On a sweep where every candidate
     failed or timed below resolution, fall back to the measured
     defaults rather than crashing the call (nothing is cached, so a
     later quieter run can still tune)."""

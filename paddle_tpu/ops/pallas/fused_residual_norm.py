@@ -2,7 +2,7 @@
 
 The training step's per-layer glue chain — residual add feeding a
 pre/post-norm — sits between the flash and matmul kernels as separate
-dispatches (calibrate.kernel_breakdown's glue share).  Each kernel here
+dispatches (the step's glue share).  Each kernel here
 runs one row-blocked pass computing BOTH the residual sum and its
 normalized value, saving fp32 stats for a fused backward that replays
 the exact tile walk (the ``flash_attention_bwd_jnp`` discipline):
@@ -21,10 +21,10 @@ bitwise vs interpret mode.  Row block is an autotune entry
 (``fused_residual_norm_rows`` — ``pick_glue_rows``).
 
 Wired into the GPT/LLaMA/BERT blocks behind the ``train_glue_fusion``
-flag (default OFF: the standalone Pallas LN measured as a fusion
-BARRIER in-context — +6 ms/step on the GPT-124M bench, see
-nn/functional/norm.py — so the fused glue path ships dark until the
-TPU round prices it end-to-end).
+flag (default OFF: a custom call is a fusion BARRIER, and the
+standalone Pallas LN lost time in context for that reason, see
+nn/functional/norm.py — so the fused glue path ships dark until a
+cell prices it end to end).
 """
 from __future__ import annotations
 

@@ -42,8 +42,8 @@ Capability analog of the reference's paged/block KV serving kernels
   side-pools [Hk, P, page_size] (``quantization.kv_quantize``) are
   windowed alongside each data page and the dequant happens in VMEM
   on the fetched block — attention reads a QUARTER of the fp32 KV
-  bytes per step, which is the serving roofline term
-  (benchmarks/serving_bench.py), and no float page ever exists in HBM.
+  bytes per step, which is the serving roofline term, and no float
+  page ever exists in HBM.
 
 Public entries: ``paged_decode_attention`` (one token per sequence —
 the ``models.generate(kv_cache='paged')`` path, API-compatible with the

@@ -6,9 +6,9 @@ Capability analog of the reference's weight-only GEMMs
 by PAPERS.md #3 ("Operator Fusion for LLM Inference"): the int8->float
 dequantization must FUSE into the consuming matmul instead of
 materializing a float weight tensor in HBM.  Weight bytes are the
-serving roofline at decode (benchmarks/serving_bench.py computes the
-HBM floor from exactly those bytes) — reading W as int8 quarters the
-dominant term.
+serving roofline at decode (the HBM floor of a decode step is those
+bytes over the bandwidth) — reading W as int8 quarters the dominant
+term.
 
 Key algebraic point: per-OUT-CHANNEL scales commute with the K
 reduction (``sum_k x[m,k] * (q[k,n] * s[n]) == s[n] * sum_k x[m,k] *

@@ -292,8 +292,8 @@ def check_tiny_equivalence():
 if __name__ == "__main__":
     import os
     import sys
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))))
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=32")

@@ -3,8 +3,7 @@
 schedule extraction + the store-backed runtime verifier, eqn-level
 provenance of the PDT22x/23x passes, the jit-capture wiring (audit-once,
 ``hbm.static_peak_bytes`` gauge, PDT242 shape-fork sharing the
-``compile.retrace`` vocabulary), and the per-code audit-counts plumbing
-the bench round record snapshots."""
+``compile.retrace`` vocabulary), and the per-code audit-counts tally."""
 import warnings
 
 import jax

@@ -113,3 +113,38 @@ def test_no_file_ordering_grows_back_under_tests():
     with open(os.path.join(here, "conftest.py")) as fh:
         named = re.findall(r"""["']test_\w+\.py["']""", fh.read())
     assert not named, f"tests/conftest.py lists test files: {named}"
+
+
+def test_no_second_measurement_stack_grows_back():
+    """Speed is measured in one place: ``perf/`` runs the cells of
+    ``BENCHMARK.json`` and the driver keeps ``PERF_LEDGER.jsonl``
+    (ROADMAP D4, closed by PR 47).  No code under ``paddle_tpu/`` or
+    ``tests/`` (``tests/perf`` is the benchmark's own) or at the root,
+    nor the two documents that describe the tree as it is, imports or
+    names ``bench.py``, ``benchmarks/`` or ``observability.regress``,
+    and the root holds no round record."""
+    import re
+    here = os.path.dirname(os.path.abspath(__file__))
+    root = os.path.dirname(here)
+    gone = re.compile(r"""(?<!\w)bench\.py|benchmarks/|["']benchmarks["']"""
+                      r"""|observability\.regress|import regress\b""")
+    paths = [os.path.join(root, f) for f in sorted(os.listdir(root))
+             if f.endswith(".py") or f in ("README.md", "COVERAGE.md")]
+    for top in (os.path.join(root, "paddle_tpu"), here):
+        for d, dirs, files in os.walk(top):
+            dirs[:] = [x for x in dirs if x != "__pycache__"
+                       and os.path.join(d, x) != os.path.join(here, "perf")]
+            paths += [os.path.join(d, f) for f in sorted(files)
+                      if f.endswith(".py")]
+    paths.remove(os.path.abspath(__file__))  # the guard names what it forbids
+    named = []
+    for path in paths:
+        with open(path) as fh:
+            for n, line in enumerate(fh, 1):
+                if gone.search(line):
+                    named.append(f"{os.path.relpath(path, root)}:{n}")
+    assert not named, f"the old measurement stack is named in {named}"
+    records = [f for f in os.listdir(root)
+               if re.fullmatch(r"(BENCH|MULTICHIP)_r\d+\.json", f)]
+    assert not records, f"round records at the root: {records}"
+    assert not os.path.exists(os.path.join(root, "benchmarks"))

@@ -530,33 +530,3 @@ def test_import_advances_auto_rid(gpt, refs):
     src.run()
     dst.run()
     _assert_pool_conserved(dst)
-
-
-# ====================================================== bench smoke ==
-
-def test_serving_bench_rows_smoke(gpt):
-    """The tp2/disagg serving_bench rows run on the CPU mesh with the
-    suite's tiny geometry and report sane accounting (absolute times
-    are TPU claims; the gates here are outputs_equal, byte counts and
-    pool conservation)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import serving_bench as sb
-    cfg = gpt.cfg
-    row = sb._measure_tp(cfg, gpt, 819.0, 2, slots=2, prompt_len=10,
-                         new_tokens=5, page_size=8, decode_window=4,
-                         prefill_chunk=8, q_block=2, max_seq_len=32,
-                         warm=False)
-    assert row["outputs_equal"] and row["pages_leaked"] == 0
-    assert row["roofline_ms"] < row["roofline_ms_1dev"]
-    row = sb._measure_disagg(cfg, gpt, slots=2, prompt_len=10,
-                             new_tokens=6, storm_prompt=20,
-                             storm_new=2, n_latency=2, n_storm=3,
-                             page_size=8, decode_window=4,
-                             prefill_chunk=8, max_seq_len=32,
-                             q_block=2, warm=False)
-    assert row["handoffs"] >= 2
-    assert row["transfer_bytes"] > 0
-    assert row["pages_leaked"] == 0

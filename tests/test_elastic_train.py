@@ -719,34 +719,6 @@ def test_store_timeout_error_coded():
 
 
 # --------------------------------------------------------------------------
-# bench: the hybrid_bench recovery column computes with sane accounting
-# --------------------------------------------------------------------------
-
-def test_recovery_bench_column_smoke():
-    """The ISSUE-15 ``recovery`` column of benchmarks/hybrid_bench.py:
-    injected rank_dead -> buddy restore, with time-to-resume and
-    snapshot-overhead accounting populated."""
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    try:
-        import hybrid_bench as hb
-    finally:
-        sys.path.pop(0)
-    row = hb.measure_recovery()
-    assert row["recovered"] and row["completed"]
-    assert row["restore_source"] == "buddy"
-    # the dying rank checks its fault BEFORE snapshotting, so a death
-    # ON a cadence boundary restores the previous generation: newest
-    # snapshot strictly below the death step
-    assert row["restored_step"] == (row["death_at_step"] - 1) \
-        // row["snapshot_every"] * row["snapshot_every"]
-    assert row["recovery_ms"] > 0
-    assert row["snapshots"] >= 1 and row["snapshot_ms_mean"] > 0
-    assert row["drill_wall_s"] < 60
-
-
-# --------------------------------------------------------------------------
 # unit: batch-granular reshard reconstructs the exact remaining stream
 # --------------------------------------------------------------------------
 

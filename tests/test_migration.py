@@ -513,28 +513,3 @@ def test_drain_under_storm_demand_counted_once(gpt):
     assert rd.stats["migrations"] >= 1
     assert req_d == req_c                    # warm moves re-prefill 0
     _fleet_pool_conserved(rd)
-
-
-# ======================================================== benches ==
-
-def test_serving_bench_migration_smoke(gpt):
-    """The serving_bench ``migration`` columns on the CPU tiny model:
-    migrate-drain beats (or at worst matches, on this tiny workload)
-    the cold wait on drain latency, pages actually ship, prefill
-    tokens are saved, and the streams gate bitwise (absolute times are
-    TPU claims)."""
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import serving_bench as sb
-    cfg = gpt.cfg
-    # ``KW``'s geometry (two slots: the row's default of four was a set
-    # of programs of its own, 30 s of tier-1)
-    row = sb._measure_migration(cfg, gpt, slots=2, prompt_len=16,
-                                new_tokens=6, n_requests=3, page_size=8,
-                                decode_window=4, prefill_chunk=8,
-                                max_seq_len=32, q_block=2, warm=False)
-    assert row["outputs_equal"]
-    assert row["migrated_pages"] >= 1
-    assert row["pages_leaked"] == 0
-    assert row["drain_ms_migrate"] > 0.0 and row["drain_ms_wait"] > 0.0

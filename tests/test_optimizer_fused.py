@@ -370,18 +370,58 @@ def test_captured_fused_matches_eager_fused():
     assert cont < eager[0]
 
 
-def test_hlo_update_op_reduction_10x():
+_ARITH = {"add", "sub", "mul", "div", "sqrt", "rsqrt", "max", "min", "pow",
+          "integer_pow", "neg", "sign", "abs", "square"}
+
+
+def _bert_base_shapes(hidden, layers, vocab, seq):
+    """BERT-base's parameter set, one entry a tensor (its structure is
+    what counts: the op counts do not depend on the widths)."""
+    h, i4 = hidden, 4 * hidden
+    shapes = [(vocab, h), (seq, h), (2, h), (h,), (h,)]  # embeddings + LN
+    for _ in range(layers):
+        shapes += [(h, h), (h,)] * 4                 # q/k/v/out
+        shapes += [(h,), (h,)]                       # attn LN
+        shapes += [(h, i4), (i4,), (i4, h), (h,)]    # ffn
+        shapes += [(h,), (h,)]                       # ffn LN
+    return shapes + [(h, h), (h,), (h,), (h,), (h, 2), (2,)]  # pooler/heads
+
+
+def _update_arith_ops(shapes, kind, fused):
+    """Arithmetic equations, sub-jaxprs included, of the captured
+    optimizer-only step over ``shapes``."""
+    import jax
+    from paddle_tpu.analysis.program import all_eqns
+    st.set_flags({"fused_opt": fused})
+    rng = np.random.default_rng(0)
+    params = [pt.Parameter(rng.normal(size=s).astype("float32") * 0.02)
+              for s in shapes]
+    o = {"adamw": opt.AdamW, "momentum": opt.Momentum}[kind](
+        learning_rate=1e-3, parameters=params)
+    for p in params:
+        p.grad = pt.to_tensor(
+            rng.integers(-2, 3, p.shape).astype("float32"))
+
+    @pt.jit.to_static
+    def upd():
+        o.step()
+        o.clear_grad(set_to_zero=True)
+        return params[0]
+
+    upd()
+    exe = list(upd._cache.values())[0]
+    jaxpr = jax.make_jaxpr(exe._pure)(*[t._read() for t in exe.capt_state])
+    return sum(eqn.primitive.name in _ARITH for eqn, _ in all_eqns(jaxpr))
+
+
+@pytest.mark.parametrize("kind", ["adamw", "momentum"])
+def test_hlo_update_op_reduction_10x(kind):
     """Acceptance: traced-step update-op count drops >= 10x at a
     BERT-base-structured param set (size-independent)."""
-    import os
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
-    import optimizer_bench as ob
-    shapes = ob.bert_base_shapes(hidden=16, layers=2, vocab=64, seq=16)
-    _, arith_fused = ob.hlo_op_counts(shapes, "adamw", fused=True)
-    _, arith_pp = ob.hlo_op_counts(shapes, "adamw", fused=False)
-    assert arith_pp / max(arith_fused, 1) >= 10.0
+    shapes = _bert_base_shapes(hidden=16, layers=2, vocab=64, seq=16)
+    fused = _update_arith_ops(shapes, kind, fused=True)
+    per_param = _update_arith_ops(shapes, kind, fused=False)
+    assert per_param / max(fused, 1) >= 10.0, (per_param, fused)
 
 
 # ---------------------------------------------------------------- amp --

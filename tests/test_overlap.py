@@ -8,8 +8,7 @@ Gates:
 - ``no_sync`` pauses the scheduler (gradient accumulation);
 - comm_ms / overlap_frac accounting reaches the observability registry;
 - the pipeline's pp_overlap_p2p reorder changes the schedule, not the
-  values;
-- the gpt_3d bench row computes with sane accounting on the CPU mesh.
+  values.
 """
 import numpy as np
 import pytest
@@ -217,43 +216,6 @@ def test_topology_process_mesh_bridge():
     assert mesh2.dim_names == ["dp", "pp"]
     g = hcg.get_data_parallel_comm_group()
     assert g.nranks == 2 and g.ranks == [0, 4]
-
-
-# slow: 120-140 s, the longest case outside tests/perf, for the columns
-# of a bench row (benchmarks/hybrid_bench.py, ROADMAP D4).  Tier-1 runs what
-# the row times (the 1F1B GPT step: test_pipeline_schedules::
-# test_gpt_pipe_1f1b_trains; the overlap telemetry:
-# test_overlap_bitwise_vs_serialized_per_param above) and NOT the row's own
-# code
-@pytest.mark.slow
-def test_gpt_3d_bench_row_smoke():
-    """CPU-mesh accounting smoke of the gpt_3d row: topology recorded,
-    scaling + overlap fields present, overlap_frac within [0, 1]."""
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "benchmarks", "hybrid_bench.py")
-    spec = importlib.util.spec_from_file_location("hybrid_bench_smoke",
-                                                  path)
-    hb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(hb)
-    from paddle_tpu.models.gpt import GPTConfig
-
-    cfg = GPTConfig(vocab_size=64, hidden_size=16, num_layers=2,
-                    num_heads=2, max_seq_len=32, dropout=0.0)
-    row = hb._measure_gpt_3d(cfg, dp=2, pp=2, mp=1, batch_per_dp=2,
-                             seq=8, num_microbatches=2, steps=1,
-                             warmup=1, overlap_steps=1)
-    assert row["metric"] == "gpt_3d_train_tokens_per_sec"
-    assert row["chips"] == 4
-    assert row["topology"]["dp"] == 2 and row["topology"]["pp"] == 2
-    assert row["value"] > 0 and row["tokens_per_sec_1dev"] > 0
-    assert row["scaling_x"] > 0
-    ov = row["overlap"]
-    assert ov["buckets"] >= 1 and ov["comm_ms"] > 0
-    assert 0.0 <= ov["overlap_frac"] <= 1.0
-    assert row["pp_overlap_p2p"] is True
 
 
 def test_dryrun_multichip_pipeline():

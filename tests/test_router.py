@@ -541,29 +541,3 @@ def test_router_requeue_demand_counted_once(gpt):
     for a, b in zip(rids_c, rids_f):
         np.testing.assert_array_equal(done_c[a].sequence,
                                       done_f[b].sequence)
-
-
-# ======================================================== benches ==
-
-def test_serving_bench_fleet_smoke(gpt):
-    """The serving_bench ``fleet`` row on the CPU tiny model: affinity
-    measurably beats round-robin on cache-hit tokens, the replica-kill
-    recovery is lossless and bitwise, and no survivor leaks pages
-    (absolute times are TPU claims)."""
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks"))
-    import serving_bench as sb
-    cfg = gpt.cfg
-    row = sb._measure_fleet(cfg, gpt, slots=2, prompt_len=16,
-                            new_tokens=5, shared_groups=2,
-                            group_size=4, n_light=2, light_new=3,
-                            page_size=8, decode_window=4,
-                            prefill_chunk=8, max_seq_len=32,
-                            q_block=2, warm=False)
-    assert row["cache_hit_frac_affinity"] > row["cache_hit_frac_rr"]
-    assert row["outputs_equal"]
-    assert row["pages_leaked"] == 0
-    assert row["requeued"] >= 1 and row["deaths"] == 1
-    assert row["recover_ms"] > 0.0
-    assert row["goodput_fleet4"] == 1.0

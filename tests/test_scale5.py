@@ -101,7 +101,7 @@ def test_gpt13b_aot_lowering_fits_v5e():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
-        [sys.executable, os.path.join(root, "benchmarks",
+        [sys.executable, os.path.join(root, "tests", "aot",
                                       "aot_gpt13b.py")],
         env=env, cwd=root, capture_output=True, text=True, timeout=1500)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
@@ -125,7 +125,7 @@ def test_gpt13b_capture_path_aot_lowering():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(
-        [sys.executable, os.path.join(root, "benchmarks",
+        [sys.executable, os.path.join(root, "tests", "aot",
                                       "aot_capture_13b.py")],
         env=env, cwd=root, capture_output=True, text=True, timeout=2400)
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]

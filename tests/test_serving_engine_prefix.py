@@ -174,35 +174,3 @@ def test_engine_cache_evict_drill_bitwise(gpt):
         _assert_pool_conserved(eng)
     finally:
         faults.clear()
-
-
-# slow: 26 s for a bench row's accounting (benchmarks/serving_bench.py,
-# ROADMAP D4) at a geometry of its own (the row takes no ``q_block``, so it
-# cannot run ``_engine``'s programs).  Tier-1 runs the engine path under it
-# (test_engine_prefix_cache_shared_prefix_bitwise above) and NOT the row's
-# own code
-@pytest.mark.slow
-def test_serving_bench_shared_prefix_accounting(gpt):
-    """CPU tiny-model smoke for the serving_bench ``shared_prefix``
-    row: the accounting must show prefill tokens computed < tokens
-    requested at a high prefix-hit rate, zero leaked pages, and a
-    sane saved fraction (absolute times are TPU-only claims)."""
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(__file__), os.pardir,
-                        "benchmarks", "serving_bench.py")
-    spec = importlib.util.spec_from_file_location(
-        "serving_bench_smoke", path)
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
-    row = sb._measure_shared_prefix(
-        gpt.cfg, gpt, slots=2, max_seq_len=64, shared_len=12,
-        tail_range=(2, 7), new_tokens=4, n_requests=6, hit_every=3,
-        page_size=4, decode_window=4, prefill_chunk=8, warm=False)
-    assert (row["prefill_tokens_computed"]
-            < row["prefill_tokens_requested"])
-    assert row["prefill_saved_frac"] > 0
-    assert row["cache_hits"] >= 2 and row["cache_hit_tokens"] >= 2 * 12
-    assert row["pages_leaked"] == 0
-    assert row["ttft_ms_avg"] > 0 and row["ttft_ms_avg_nocache"] > 0

@@ -1,12 +1,10 @@
-"""SLO guardrails, stall watchdog and the regression sentinel
-(ISSUE 14): shared percentile math, burn-rate window math on a fake
-clock, SLO pass/breach on slot-contention traffic through the session
+"""SLO guardrails and stall watchdog (ISSUE 14): shared percentile
+math, burn-rate window math on a fake clock, SLO pass/breach on slot-contention traffic through the session
 tiny GPT, the ``engine_stall`` drill (coded ``EngineStallError`` within
 the deadline, exactly one flight dump holding thread stacks and the
 victim's timeline, zero dumps + nothing armed on clean runs,
-co-residents bitwise), flight-dump keep-last-K retention, metrics-off
-no-op parity, and the regress CLI (golden report, nonzero exit on an
-injected 20% regression, tolerant loading of the real r01-r05 files).
+co-residents bitwise), flight-dump keep-last-K retention and
+metrics-off no-op parity.
 
 Engine tests reuse the session ``serving_gpt`` and the exact geometry
 the serving suite already compiled (max_slots=2/page_size=8/...), so
@@ -29,8 +27,6 @@ from paddle_tpu.observability.metrics import (LATENCY_BUCKETS_MS,
                                               percentile_from_counts)
 from paddle_tpu.observability.slo import SLOEngine, SLOSpec, parse_slo
 from paddle_tpu.resilience import faults
-
-_REPO = os.path.join(os.path.dirname(__file__), os.pardir)
 
 # the geometry every serving suite compiles against (conftest comment)
 _KW = dict(max_slots=2, page_size=8, max_seq_len=32, decode_window=4,
@@ -56,7 +52,7 @@ def _prompts(seed=0, sizes=(5, 9)):
 
 
 # ==========================================================================
-# shared percentile math (satellite: _tl_pct dedupe)
+# shared percentile math
 # ==========================================================================
 
 def test_histogram_percentile(metrics_on):
@@ -73,27 +69,6 @@ def test_histogram_percentile(metrics_on):
     # the module function is the same math over raw state
     assert percentile_from_counts(h.buckets, h.counts, h.count,
                                   0.5) == h.percentile(0.5)
-
-
-def test_bench_tl_pct_uses_shared_percentile(gpt, metrics_on):
-    """serving_bench's ``_tl_pct``/``_tl_mean`` must agree with the
-    live histogram's own ``percentile()``/``mean`` — one home for the
-    math (byte-identical bench columns are the satellite's claim)."""
-    import importlib.util
-    path = os.path.join(_REPO, "benchmarks", "serving_bench.py")
-    spec = importlib.util.spec_from_file_location(
-        "serving_bench_slo_smoke", path)
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
-    eng = ContinuousBatchingEngine(gpt, **_KW)
-    for p in _prompts():
-        eng.add_request(p, 6)
-    eng.run()
-    h_ttft = eng._registry.histogram("serving.ttft_ms")
-    assert h_ttft.count > 0
-    for q in (0.5, 0.95, 0.99):
-        assert sb._tl_pct(eng, "ttft_ms", q) == h_ttft.percentile(q)
-    assert sb._tl_mean(eng, "ttft_ms") == pytest.approx(h_ttft.mean)
 
 
 # ==========================================================================
@@ -423,110 +398,3 @@ def test_metrics_off_guardrails_noop(gpt, tmp_path, monkeypatch):
         np.testing.assert_array_equal(done_ref[a].sequence,
                                       done[b].sequence)
     assert os.listdir(tmp_path) == []
-
-
-# ==========================================================================
-# regression sentinel
-# ==========================================================================
-
-def _write_history(tmp_path):
-    """A five-round history of the driver's record shape: r01 and r04
-    came back unparsed, as the first and fourth rounds of this repo's
-    own history did; r02, r03 and r05 improve round over round."""
-    parsed = {}
-    for r, (value, step_ms, mfu) in {
-            2: (75030.5, 109.18, 0.3276), 3: (75672.5, 108.25, 0.3304),
-            5: (90056.8, 90.96, 0.3932)}.items():
-        parsed[r] = {
-            "metric": "gpt124m_train_tokens_per_sec_per_chip",
-            "value": value, "unit": "tokens/sec",
-            "vs_baseline": round(mfu / 0.4, 3),
-            "extra": {"step_time_ms": step_ms, "mfu": mfu}}
-    for r in range(1, 6):
-        json.dump({"n": r, "rc": 0, "tail": "", "parsed": parsed.get(r)},
-                  open(os.path.join(tmp_path, f"BENCH_r{r:02d}.json"),
-                       "w"))
-    return parsed[5]
-
-
-def test_regress_history_with_unparsed_rounds_passes(tmp_path, capsys):
-    """Unparsed rounds (r01, r04) must be tolerated (skipped, not
-    fatal); the judged r05 round is an improvement, so the CLI exits
-    0."""
-    from paddle_tpu.observability import regress
-    _write_history(tmp_path)
-    rc = regress.main([str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "# BENCH r01 skipped" in out
-    assert "# BENCH r04 skipped" in out
-    assert "OK         BENCH.value" in out
-    assert "REGRESSION" not in out
-    assert out.strip().endswith("regressions: none")
-
-
-def test_regress_flags_injected_regression(tmp_path, capsys):
-    """A synthetic 20% tok/s regression appended as r06 is flagged
-    (nonzero exit) while every other metric stays clean."""
-    from paddle_tpu.observability import regress
-    bad = dict(_write_history(tmp_path))
-    bad["value"] = round(bad["value"] * 0.8, 1)
-    json.dump({"n": 6, "parsed": bad, "tail": "", "rc": 0},
-              open(os.path.join(tmp_path, "BENCH_r06.json"), "w"))
-    rc = regress.main([str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "REGRESSION BENCH.value" in out
-    assert out.strip().endswith("regressions: BENCH.value")
-    # vs_baseline/step_time/mfu were not scaled: they stay OK
-    assert "REGRESSION BENCH.vs_baseline" not in out
-    assert "REGRESSION BENCH.extra.step_time_ms" not in out
-
-
-def test_regress_golden_report(tmp_path, capsys):
-    """Stable sorted text over a synthetic history — the golden the
-    CLI contract is pinned to (like render_prometheus)."""
-    from paddle_tpu.observability import regress
-    vals = [100.0, 102.0, 98.0, 101.0]
-    for i, v in enumerate(vals, start=1):
-        json.dump({"n": i, "rc": 0, "tail": "", "parsed": {
-            "metric": "m", "value": v, "unit": "tokens/sec",
-            "extra": {"step_time_ms": 1000.0 / v, "mfu": v / 400.0}}},
-            open(os.path.join(tmp_path, f"BENCH_r{i:02d}.json"), "w"))
-    json.dump({"n": 5, "rc": 0, "tail": "", "parsed": {
-        "metric": "m", "value": 80.0, "unit": "tokens/sec",
-        "extra": {"step_time_ms": 12.5, "mfu": 0.2}}},
-        open(os.path.join(tmp_path, "BENCH_r05.json"), "w"))
-    rc = regress.main([str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert out == (
-        "# BENCH: judging r05 against 4 prior round(s)\n"
-        "REGRESSION BENCH.extra.mfu latest=0.2 baseline=0.25125 "
-        "mad=0.0025 z=+13.83\n"
-        "REGRESSION BENCH.extra.step_time_ms latest=12.5 "
-        "baseline=9.9505 mad=0.0980392 z=+17.54\n"
-        "REGRESSION BENCH.value latest=80 baseline=100.5 mad=1 "
-        "z=+13.83\n"
-        "regressions: BENCH.extra.mfu, BENCH.extra.step_time_ms, "
-        "BENCH.value\n")
-
-
-def test_regress_check_record_and_stale_subtrees(tmp_path):
-    """bench.py's hook: the in-flight record is judged against the
-    on-disk history; ``cached`` subtrees are stale re-reports and
-    never feed baselines or judgment."""
-    from paddle_tpu.observability import regress
-    for i, v in enumerate((100.0, 101.0, 99.0), start=1):
-        json.dump({"n": i, "rc": 0, "tail": "", "parsed": {
-            "metric": "m", "value": v,
-            "extra": {"sub": {"cached": True, "value": 5.0}}}},
-            open(os.path.join(tmp_path, f"BENCH_r{i:02d}.json"), "w"))
-    clean = {"metric": "m", "value": 100.5,
-             "extra": {"sub": {"cached": True, "value": 1.0}}}
-    assert regress.check_record(clean, str(tmp_path)) == []
-    bad = dict(clean, value=60.0)
-    assert regress.check_record(bad, str(tmp_path)) == ["BENCH.value"]
-    # the cached subtree's 5.0 -> 1.0 "drop" was never judged
-    report, _ = regress.analyze(str(tmp_path), extra_latest=bad)
-    assert "extra.sub" not in report

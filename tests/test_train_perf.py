@@ -1,5 +1,5 @@
 """ISSUE-19 training-perf acceptance: selective remat (bitwise policy
-family + static-peak drop + headroom walk), fused residual/norm glue
+family + static-peak drop, and its growth with the batch), fused residual/norm glue
 kernels (kernel-vs-twin bitwise parity fwd AND bwd, model-level wiring),
 and the double-buffered input pipeline (bitwise loss trajectory +
 overlap metrics).
@@ -14,9 +14,6 @@ family.  The eager per-op tape sits OUTSIDE the family (its backward
 accumulates cotangents in per-op order, ~1e-10 relative off the
 region vjp) and is compared at the test_models.py tolerance instead.
 """
-import os
-import sys
-
 import numpy as np
 import pytest
 
@@ -200,6 +197,34 @@ def test_model_prepare_remat_flags_blocks():
             0.1, parameters=plain.parameters()), remat=True)
 
 
+def _captured_step_peak(batch, **cfg_kw):
+    """``static_peak_bytes`` of one captured GPT train step (forward,
+    backward, SGD) at ``batch`` rows of ``max_seq_len`` tokens: the
+    number the ``hbm.static_peak_bytes{fn}`` gauge exports."""
+    paddle.seed(0)
+    cfg = _gpt_cfg(vocab_size=128, hidden_size=64, num_heads=4,
+                   use_flash_attention=False, **cfg_kw)
+    m = GPTForCausalLM(cfg)
+    m.train()
+    opt = paddle.optimizer.SGD(0.01, parameters=m.parameters())
+
+    @paddle.jit.to_static(full_graph=True)
+    def step(i, l):
+        loss = m(i, l)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    rng = np.random.default_rng(0)
+    ids, lab = (paddle.to_tensor(rng.integers(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len)).astype(np.int32))
+        for _ in range(2))
+    step(ids, lab)
+    exe = next(iter(step._cache.values()))
+    return int(exe.static_peak_bytes)
+
+
 def test_remat_static_peak_drop():
     """The acceptance gauge: on a multi-layer GPT block stack the
     captured train step's ``static_peak_bytes`` drops >= 25% with remat
@@ -208,56 +233,21 @@ def test_remat_static_peak_drop():
     stacks can go the OTHER way (nothing upstream to free); the saving
     is a multi-layer property, which is why this config has 4 layers."""
     def peak(remat):
-        paddle.seed(0)
-        cfg = _gpt_cfg(vocab_size=128, hidden_size=64, num_layers=4,
-                       num_heads=4, max_seq_len=128,
-                       use_flash_attention=False, recompute=remat,
-                       recompute_policy="dots_and_kernels_saveable")
-        m = GPTForCausalLM(cfg)
-        m.train()
-        opt = paddle.optimizer.SGD(0.01, parameters=m.parameters())
-
-        @paddle.jit.to_static(full_graph=True)
-        def step(i, l):
-            loss = m(i, l)
-            loss.backward()
-            opt.step()
-            opt.clear_grad()
-            return loss
-
-        rng = np.random.default_rng(0)
-        ids = paddle.to_tensor(rng.integers(
-            0, cfg.vocab_size, (4, 128)).astype(np.int32))
-        lab = paddle.to_tensor(rng.integers(
-            0, cfg.vocab_size, (4, 128)).astype(np.int32))
-        step(ids, lab)
-        exe = next(iter(step._cache.values()))
-        return int(exe.static_peak_bytes)
+        return _captured_step_peak(
+            4, num_layers=4, max_seq_len=128, recompute=remat,
+            recompute_policy="dots_and_kernels_saveable")
 
     p_off, p_on = peak(False), peak(True)
     assert p_on < 0.75 * p_off, (p_off, p_on)
 
 
-def test_train_batch_headroom_walk():
-    """calibrate.train_batch_headroom walks batch sizes against the
-    static-peak gauge: rows are monotone in peak, the fit verdicts
-    honor the budget, and remat raises (or holds) max_batch_fits."""
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
-    import calibrate
-
-    out = calibrate.train_batch_headroom(
-        budget_gb=1.0, hidden=64, layers=2, heads=4, vocab=128,
-        seq=32, batches=(1, 2))     # two sizes order the peaks as three do
-    rows = out["rows"]
-    assert rows and all(r["static_peak_bytes"] > 0 for r in rows)
-    peaks = [r["static_peak_bytes"] for r in rows]
-    assert peaks == sorted(peaks)
-    budget = 1.0 * 2 ** 30
-    for r in rows:
-        assert r["fits"] == (r["static_peak_bytes"] <= budget)
-    assert out["max_batch_fits"] == max(
-        (r["batch"] for r in rows if r["fits"]), default=0)
+def test_static_peak_bytes_monotone_in_batch():
+    """The ``static_peak_bytes`` a captured train step's executable
+    carries (what a walk over batch sizes against an HBM budget reads)
+    is positive and grows with the batch."""
+    p1, p2 = (_captured_step_peak(bs, num_layers=2, max_seq_len=32)
+              for bs in (1, 2))
+    assert 0 < p1 < p2, (p1, p2)
 
 
 # ==========================================================================
@@ -400,17 +390,39 @@ def test_glue_fusion_model_parity_and_training(family):
 
 
 def test_glue_fusion_drops_dispatches():
-    """The calibration probe's op-hook count: the fused train forward
-    dispatches fewer ops per layer, with the glue subset (add/norm ops)
-    down by 2 per layer (4 glue dispatches -> 2)."""
-    sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "benchmarks"))
-    import calibrate
+    """Counted through ``core.dispatch._profile_hook``, one eager TRAIN
+    forward at one and at two layers (the difference leaves the
+    embedding and the final norm out): the fused forward dispatches
+    fewer ops a layer, and at least 2 fewer of the glue ops (add and
+    norm: 4 -> 2).  Goes with ``train_glue_fusion`` (ROADMAP D2)."""
+    from paddle_tpu.core import dispatch
+    from paddle_tpu.models.gpt import GPTModel
+    glue_ops = ("add", "layer_norm", "rms_norm", "fused_residual_norm")
+    old = _flag("train_glue_fusion")
 
-    out = calibrate.measure_train_glue_dispatches()
-    assert out["fused_per_layer"] < out["unfused_per_layer"]
-    assert (out["glue_unfused_per_layer"]
-            - out["glue_fused_per_layer"]) >= 2
+    def per_layer(fused):
+        counts = []
+        for layers in (1, 2):
+            paddle.seed(0)
+            m = GPTModel(_gpt_cfg(num_layers=layers,
+                                  use_flash_attention=False))
+            m.train()
+            seen = []
+            dispatch._profile_hook = lambda name, t0, t1: seen.append(name)
+            try:
+                paddle.set_flags({"train_glue_fusion": fused})
+                with paddle.no_grad():
+                    m(paddle.to_tensor(np.zeros((2, 16), np.int32)))
+            finally:
+                dispatch._profile_hook = None
+                paddle.set_flags({"train_glue_fusion": old})
+            counts.append((len(seen),
+                           sum(n in glue_ops for n in seen)))
+        return (counts[1][0] - counts[0][0], counts[1][1] - counts[0][1])
+
+    (ops, glue), (ops_fused, glue_fused) = per_layer(False), per_layer(True)
+    assert ops_fused < ops, (ops, ops_fused)
+    assert glue - glue_fused >= 2, (glue, glue_fused)
 
 
 # ==========================================================================
